@@ -17,6 +17,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (bench_accuracy, bench_blocksweep, bench_breakdown,
                             bench_e2e, bench_flash_prefill,
                             bench_kernel_decode, bench_paged,

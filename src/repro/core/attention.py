@@ -9,6 +9,8 @@ heads that share a KV head become the M dimension of a real matmul — MHA
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -243,6 +245,9 @@ def decode_append_attention(
     dense/paged choice follows the cache type — the serving engine swaps the
     decode state for a paged one and the model code never changes.
 
+    Under :class:`use_splitkv` the paged flush runs per chip as well
+    (``dist.splitkv.splitkv_paged_flush``).
+
     The speculative contexts hook in here: under :class:`use_draft` the
     append is residual-only (``qcache.draft_append``) and the attention read
     dequantizes at the truncated draft bit-width; under :class:`masked_append`
@@ -251,8 +256,17 @@ def decode_append_attention(
     if _SPEC["draft_bits"] is not None:
         cache = qcache.draft_append(cache, k_new, v_new)
     elif isinstance(cache, PagedQuantKVCache):
+        flush = {}
+        if _SPLITKV["mesh"] is not None:
+            from repro.dist import splitkv as _sk
+
+            flush["flush_op"] = functools.partial(
+                _sk.splitkv_paged_flush, mesh=_SPLITKV["mesh"],
+                axis=_SPLITKV["axis"], page_affine=_SPLITKV["page_affine"],
+            )
         cache = qcache.paged_append_decode(
-            cache, k_new, v_new, quant_impl=quant_impl, mask=_SPEC["mask"]
+            cache, k_new, v_new, quant_impl=quant_impl, mask=_SPEC["mask"],
+            **flush,
         )
     else:
         cache = qcache.append_decode(

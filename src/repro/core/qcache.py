@@ -458,6 +458,7 @@ def paged_append_decode(
     *,
     quant_impl: str = "auto",
     mask=None,
+    flush_op=rf_ops.paged_residual_flush,
 ) -> PagedQuantKVCache:
     """Paged per-token append: write the new token row into the dense
     residual, and — gated behind ``lax.cond`` exactly like the dense
@@ -474,6 +475,9 @@ def paged_append_decode(
     residual, occupancy, and their pool pages bitwise unchanged — a frozen
     lane is never ``full``, so any concurrent flush routes its destination to
     the lane's own scratch page (the standard non-flushing destination).
+
+    ``flush_op`` is the flush (``residual_flush.ops.paged_residual_flush``'s
+    signature); pools on a mesh pass ``dist.splitkv.splitkv_paged_flush``.
     """
     b = cache.k_res.shape[0]
     nb_max = cache.page_table.shape[1]
@@ -496,7 +500,7 @@ def paged_append_decode(
             vw = vs = vz = None
         else:
             kw, ks, kz, vw, vs, vz = p
-        out = rf_ops.paged_residual_flush(
+        out = flush_op(
             kw, ks, kz, vw, vs, vz, k_res, v_res,
             full.astype(jnp.int32), dest,
             bits=cache.bits, block_n=cache.block_n, k_gran=cache.k_gran,

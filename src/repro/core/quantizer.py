@@ -19,6 +19,7 @@ from __future__ import annotations
 from typing import Literal
 
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core import layout
 
@@ -111,6 +112,21 @@ def dequantize_block(
     return (q.astype(jnp.float32) * s + z).astype(dtype)
 
 
+def _held_at_own_precision(x: jnp.ndarray) -> jnp.ndarray:
+    """``x`` as f32 holding only values of ``x``'s own dtype.
+
+    XLA may skip the rounding of a bf16 producer whose consumer widens it
+    again (excess precision, on by default on TPU), so a bare
+    ``astype(float32)`` can quantize values the cache never held; the Pallas
+    kernels read the rounded operand.  ``reduce_precision`` keeps the
+    rounding, and the codes bitwise equal to the kernels'."""
+    xf = x.astype(jnp.float32)
+    if jnp.issubdtype(x.dtype, jnp.floating) and jnp.finfo(x.dtype).bits < 32:
+        fi = jnp.finfo(x.dtype)
+        xf = lax.reduce_precision(xf, exponent_bits=fi.nexp, mantissa_bits=fi.nmant)
+    return xf
+
+
 def quantize_and_pack(
     x: jnp.ndarray,
     bits: int,
@@ -123,6 +139,7 @@ def quantize_and_pack(
 
     x: [..., block_n, d] -> words int32[..., block_n // R, d].
     """
+    x = _held_at_own_precision(x)
     scale, zero = quant_params(x, bits, granularity, group=group, param_dtype=param_dtype)
     q = quantize_block(x, scale, zero, bits, granularity, group=group)
     return layout.pack_strided(q, bits), scale, zero

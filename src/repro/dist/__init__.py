@@ -11,20 +11,7 @@ Modules:
   * :mod:`repro.dist.splitkv`     — sequence-parallel decode across a mesh
     axis with the logsumexp partials merge (FlashDecoding across chips),
     for both the dense block-sharded and paged table-walk-sharded layouts.
-
-Compat: older jax (< 0.6) has no ``jax.set_mesh``; ``Mesh`` itself is the
-context manager that installs the active mesh.  The launchers and tests use
-the modern spelling, so install a minimal shim when it is missing.
 """
 from __future__ import annotations
 
-import jax
-
-if not hasattr(jax, "set_mesh"):  # pragma: no cover - depends on jax version
-    def _set_mesh_compat(mesh):
-        """``with jax.set_mesh(m):`` == ``with m:`` on legacy jax."""
-        return mesh
-
-    jax.set_mesh = _set_mesh_compat
-
-from repro.dist import sharding, splitkv, state_specs  # noqa: E402,F401
+from repro.dist import sharding, splitkv, state_specs  # noqa: F401

@@ -29,13 +29,8 @@ an earlier dim of the same leaf is dropped, and with no active mesh
 :func:`constrain` is a no-op — so the same model code runs on a laptop CPU,
 an 8-device fake mesh, and a multi-pod slice unchanged.
 
-Mesh-context caveat: the active mesh may be installed either via native
-``jax.set_mesh`` (jax >= 0.6, published through ``get_abstract_mesh``) or
-via the legacy ``with mesh:`` context (``thread_resources``);
-:func:`_active_mesh` probes both, and ``repro.dist.__init__`` shims
-``jax.set_mesh`` onto legacy jax so callers can use the modern spelling
-everywhere.  Missing either probe would silently drop every sharding
-constraint.
+The active mesh is the one installed by ``with jax.set_mesh(mesh):``
+(published through ``jax.sharding.get_abstract_mesh``).
 
 Layout/spec background: docs/ARCHITECTURE.md §6.
 """
@@ -51,30 +46,10 @@ from repro.models.params import P
 
 
 def _active_mesh():
-    """The mesh installed by ``with jax.set_mesh(mesh):`` (or ``with mesh:``
-    on legacy jax), or None outside any mesh context.
-
-    Checks both generations of the API: native ``set_mesh`` (jax >= 0.6)
-    publishes an abstract mesh via ``get_abstract_mesh``; the legacy
-    ``Mesh.__enter__`` context fills ``thread_resources``.  Missing either
-    probe would silently drop every sharding constraint."""
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        try:
-            m = get_am()
-            if m is not None and getattr(m, "axis_names", ()) and not m.empty:
-                return m
-        except Exception:  # pragma: no cover - API drift
-            pass
-    try:
-        from jax._src import mesh as _mesh_lib
-
-        m = _mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:  # pragma: no cover - private-API drift
-        pass
-    return None
+    """The mesh installed by ``with jax.set_mesh(mesh):``, or None outside
+    any mesh context."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def _axis_entry(entry, mesh, dim_size: int, used: set):

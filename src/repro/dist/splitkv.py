@@ -27,9 +27,7 @@ allocation).  Queries, residuals, and occupancy counters are replicated.
 
 Mesh axes are *physical* names here (normally ``"data"``) — the logical-axis
 indirection of dist.sharding applies to parameters, not to this explicitly
-shard_mapped path.  The mesh is passed in explicitly; callers entering it as
-a context use ``jax.set_mesh``, which ``repro.dist.__init__`` shims onto
-legacy jax (< 0.6) where ``Mesh`` itself is the context manager.
+shard_mapped path.  The mesh is passed in explicitly.
 
 Merge math and diagrams: docs/ARCHITECTURE.md §5.  Wired in through
 :class:`repro.core.attention.use_splitkv`, which the launchers enter around
@@ -38,17 +36,20 @@ split-KV decode step.
 
 Paged twin: :func:`splitkv_paged_decode_attention` shards the page-table
 *walk* (not the pools) for PagedQuantKVCache states — see its docstring and
-docs/ARCHITECTURE.md §7.
+docs/ARCHITECTURE.md §7.  :func:`splitkv_paged_flush` runs the decode
+flush on the same pools: Mosaic kernels are never partitioned by the
+compiler, so every kernel of a step on a mesh runs under ``shard_map``.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as PS
 
 from repro.kernels.bitdecode import ops as bd_ops
 from repro.kernels.paged_bitdecode import ops as pg_ops
+from repro.kernels.residual_flush import ops as rf_ops
 
 
 def merge_collective(o, lse, axis: str):
@@ -152,9 +153,9 @@ def splitkv_decode_attention(
         )
         return merge_collective(o, lse, axis)
 
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh, in_specs=tuple(in_specs), out_specs=rep,
-        check_rep=False,
+        check_vma=False,
     )(*operands)
     return inverse_query_transform(out)
 
@@ -275,7 +276,71 @@ def splitkv_paged_decode_attention(
         )
         return merge_collective(o, lse, axis)
 
-    out = shard_map(
-        local, mesh=mesh, in_specs=in_specs, out_specs=rep, check_rep=False,
+    out = jax.shard_map(
+        local, mesh=mesh, in_specs=in_specs, out_specs=rep, check_vma=False,
     )(*operands)
     return inverse_query_transform(out)
+
+
+def splitkv_paged_flush(
+    kw_pool, k_scale_pool, k_zero_pool, vw_pool, v_scale_pool, v_zero_pool,
+    k_res, v_res, full, dest_page,
+    *,
+    mesh,
+    axis: str = "data",
+    page_affine: bool = False,
+    **flush_kw,
+):
+    """The paged residual flush (``residual_flush.ops.paged_residual_flush``,
+    same arguments and result) for pools that live on ``mesh``.
+
+    Replicated pools: every chip commits the same pages.  Page-affine pools
+    (leading page axis sharded along ``axis``): each chip commits only the
+    flushing rows whose destination page it stores.  Its other rows are
+    parked, not flushed, on one local page that none of its flushing rows
+    writes (at most B of the B + 1 candidates ``[0, B]`` are taken); a
+    parked row writes back the page it read, so the page keeps its content.
+    """
+    rep = PS()
+    pool = PS(axis) if page_affine else rep
+    pools = [kw_pool, k_scale_pool, k_zero_pool, vw_pool, v_scale_pool,
+             v_zero_pool]
+    have = [x is not None for x in pools]
+    operands = [x for x in pools if x is not None]
+    operands += [k_res] + ([v_res] if v_res is not None else [])
+    n_pools = sum(have)
+    b = full.shape[0]
+    if page_affine and kw_pool.shape[0] // mesh.shape[axis] <= b:
+        raise ValueError(
+            f"page_affine flush needs more than {b} pages per shard, got "
+            f"{kw_pool.shape[0] // mesh.shape[axis]}"
+        )
+
+    def local(*args):
+        pl_ = list(args[:n_pools])
+        res = list(args[n_pools:-2])
+        fl, dst = args[-2], args[-1]
+        if page_affine:
+            pp = pl_[0].shape[0]
+            idx = lax.axis_index(axis)
+            mine = (fl != 0) & (dst // pp == idx)
+            dst = dst - idx * pp
+            cand = jnp.arange(b + 1, dtype=jnp.int32)
+            taken = jnp.any((dst[None, :] == cand[:, None]) & mine[None, :], axis=1)
+            park = jnp.argmin(taken).astype(jnp.int32)
+            fl = mine.astype(jnp.int32)
+            dst = jnp.where(mine, dst, park)
+        it = iter(pl_)
+        full_pools = [next(it) if h else None for h in have]
+        k_r, v_r = res[0], (res[1] if len(res) > 1 else None)
+        out = rf_ops.paged_residual_flush(*full_pools, k_r, v_r, fl, dst,
+                                          **flush_kw)
+        return tuple(x for x in out if x is not None)
+
+    in_specs = (pool,) * n_pools + (rep,) * (len(operands) - n_pools) + (rep, rep)
+    out = jax.shard_map(
+        local, mesh=mesh, in_specs=in_specs, out_specs=(pool,) * n_pools,
+        check_vma=False,
+    )(*operands, full, dest_page)
+    it = iter(out)
+    return tuple(next(it) if h else None for h in have)

@@ -27,7 +27,7 @@ any padding needed to honor a split (e.g. the block axis when
 ``nb % axis_size != 0``) happens in dist.splitkv at call time instead.
 
 Specs are consumed via ``jax.device_put`` / shardings built under
-``jax.set_mesh`` — shimmed onto legacy jax by ``repro.dist.__init__``.
+``jax.set_mesh``.
 """
 from __future__ import annotations
 

@@ -54,10 +54,10 @@ from repro.core import layout
 
 MASK_VALUE = -1e37
 
-try:  # jax >= 0.7 renamed TPUCompilerParams
-    _CompilerParams = pltpu.CompilerParams
-except AttributeError:  # pragma: no cover
-    _CompilerParams = pltpu.TPUCompilerParams
+# Packed blocks whose metadata rows one grid step fetches: the TPU tiles the
+# last two block dims, and a single row (1, d) of a [.., nb, d] parameter
+# array is no legal tile.  16 rows is one bf16 (16, 128) tile.
+META_ROWS = 16
 
 
 def _unpack(w, bits):
@@ -108,13 +108,18 @@ def dequant_tile(wq, scale, zero, k_gran):
 
 
 def finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr):
+    """Write the carries' normalized output and logsumexp.  The carries are
+    ``(g, .)`` (one head) or ``(H, g, .)`` (all heads of a step); ``lse_ref``
+    keeps a unit lane axis so its block stays a legal TPU tile."""
     # guard l=0 (all tokens masked — e.g. a split whose block range lies
     # beyond pack_blocks, or an empty split-KV shard): output zeros with
     # lse ~ -inf so merge_partials / the cross-chip merge weights it out
     # exactly
     l = jnp.maximum(l_scr[...], 1e-30)
-    o_ref[0, 0, 0] = (acc_scr[...] / l[:, :1]).astype(o_ref.dtype)
-    lse_ref[0, 0, 0] = m_scr[:, 0] + jnp.log(l[:, 0])
+    o = acc_scr[...] / l[..., :1]
+    o_ref[...] = o.reshape(o_ref.shape).astype(o_ref.dtype)
+    lse = m_scr[..., :1] + jnp.log(l[..., :1])
+    lse_ref[...] = lse.reshape(lse_ref.shape)
 
 
 def init_carries(m_scr, l_scr, acc_scr):
@@ -141,6 +146,14 @@ def merge_partials(o_parts, lse_parts, *, return_lse: bool = True):
     if not return_lse:
         return out
     return out, m + jnp.log(den)
+
+
+def _pick_row(tile_ref, r):
+    """Row ``r`` (traced) of a ``(1, 1, rows, d)`` metadata block, as f32
+    ``(d,)``: a masked sum over the rows, with no dynamic sublane slice."""
+    tile = tile_ref[0, 0].astype(jnp.float32)
+    rows = lax.broadcasted_iota(jnp.int32, tile.shape, 0)
+    return jnp.sum(jnp.where(rows == r, tile, 0.0), axis=0)
 
 
 def _body(
@@ -170,6 +183,7 @@ def _body(
     k_gran,
     shared_kv,
     d_v,
+    meta_rows,
 ):
     b = pl.program_id(0)
     s = pl.program_id(2)
@@ -187,12 +201,15 @@ def _body(
     def _packed_block():
         kw = kw_ref[0, 0, 0]  # (npr, d_k) int32
         kq = _unpack(kw, bits)  # (block_n, d_k) — VPU
-        k_hat = dequant_tile(kq, ks_ref[0, 0, 0], kz_ref[0, 0, 0], k_gran)
+        r = jj % meta_rows  # row of this block within the metadata tile
+        k_hat = dequant_tile(kq, _pick_row(ks_ref, r), _pick_row(kz_ref, r),
+                             k_gran)
         if shared_kv:
             v_hat = k_hat[:, :d_v]
         else:
             vq = _unpack(vw_ref[0, 0, 0], bits)
-            v_hat = dequant_tile(vq, vs_ref[0, 0, 0], vz_ref[0, 0, 0], "tensor")
+            v_hat = dequant_tile(vq, _pick_row(vs_ref, r), _pick_row(vz_ref, r),
+                                 "tensor")
         update(k_hat, v_hat)
 
     # residual tail belongs to the LAST split only; every split finalizes
@@ -268,12 +285,25 @@ def bitdecode_attention_pallas(
         # DMA an in-range (ignored) block
         return jnp.minimum(s * bps + j, nb - 1)
 
+    # metadata moves in tiles of meta_rows blocks (refetched only when the
+    # walk crosses a tile); pad the block axis to whole tiles
+    meta_rows = nb if nb <= META_ROWS else META_ROWS
+    nb_meta = -(-nb // meta_rows) * meta_rows
+
+    def meta(x):
+        if x is None or nb_meta == nb:
+            return x
+        return jnp.pad(x, ((0, 0), (0, 0), (0, nb_meta - nb), (0, 0)))
+
+    k_scale, k_zero, v_scale, v_zero = map(meta, (k_scale, k_zero, v_scale, v_zero))
+
     q_spec = pl.BlockSpec((1, 1, g, d_k), lambda i, hh, s, j, *_: (i, hh, 0, 0))
     kw_spec = pl.BlockSpec(
         (1, 1, 1, npr, d_k), lambda i, hh, s, j, *_: (i, hh, blk(s, j), 0, 0)
     )
-    kp_shape = (1, 1, 1, d_k) if k_gran == "channel" else (1, 1, 1, block_n)
-    kp_spec = pl.BlockSpec(kp_shape, lambda i, hh, s, j, *_: (i, hh, blk(s, j), 0))
+    kp_shape = (1, 1, meta_rows, d_k if k_gran == "channel" else block_n)
+    kp_spec = pl.BlockSpec(
+        kp_shape, lambda i, hh, s, j, *_: (i, hh, blk(s, j) // meta_rows, 0))
     kres_spec = pl.BlockSpec((1, 1, res_n, d_k), lambda i, hh, s, j, *_: (i, hh, 0, 0))
 
     in_specs = [q_spec, kw_spec, kp_spec, kp_spec]
@@ -283,7 +313,8 @@ def bitdecode_attention_pallas(
             (1, 1, 1, npr, d_v), lambda i, hh, s, j, *_: (i, hh, blk(s, j), 0, 0)
         )
         vp_spec = pl.BlockSpec(
-            (1, 1, 1, block_n), lambda i, hh, s, j, *_: (i, hh, blk(s, j), 0)
+            (1, 1, meta_rows, block_n),
+            lambda i, hh, s, j, *_: (i, hh, blk(s, j) // meta_rows, 0),
         )
         vres_spec = pl.BlockSpec(
             (1, 1, res_n, d_v), lambda i, hh, s, j, *_: (i, hh, 0, 0)
@@ -298,11 +329,11 @@ def bitdecode_attention_pallas(
 
     out_specs = [
         pl.BlockSpec((1, 1, 1, g, d_v), lambda i, hh, s, j, *_: (s, i, hh, 0, 0)),
-        pl.BlockSpec((1, 1, 1, g), lambda i, hh, s, j, *_: (s, i, hh, 0)),
+        pl.BlockSpec((1, 1, 1, g, 1), lambda i, hh, s, j, *_: (s, i, hh, 0, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((num_splits, b, h, g, d_v), jnp.float32),
-        jax.ShapeDtypeStruct((num_splits, b, h, g), jnp.float32),
+        jax.ShapeDtypeStruct((num_splits, b, h, g, 1), jnp.float32),
     ]
     scratch = [
         pltpu.VMEM((g, 128), jnp.float32),
@@ -327,14 +358,16 @@ def bitdecode_attention_pallas(
         k_gran=k_gran,
         shared_kv=shared_kv,
         d_v=d_v,
+        meta_rows=meta_rows,
     )
     out, lse = pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        name="bitdecode",
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
     )(pack_blocks.astype(jnp.int32), res_len.astype(jnp.int32), *operands)
-    return out, lse
+    return out, lse[..., 0]

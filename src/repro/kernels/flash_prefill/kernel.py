@@ -1,11 +1,10 @@
 """Pallas TPU kernel: causal flash attention for prefill/training forward.
 
-This is the train/prefill counterpart of the bitdecode kernel, closing the
-dominant roofline gap identified in §Perf cells B/C: the XLA attention path
-materializes every f32 score tile to HBM (S·block·heads per step), which the
-dry-run shows is 10-20x the rest of the program's traffic.  Here score tiles
-live entirely in VMEM: HBM traffic collapses to Q/K/V/O once per block pair
-(K/V re-streamed per q-block — the flash tradeoff).
+This is the train/prefill counterpart of the bitdecode kernel: the XLA
+attention path materializes every f32 score tile to HBM (S·block·heads per
+step).  Here score tiles live entirely in VMEM: HBM traffic collapses to
+Q/K/V/O once per block pair (K/V re-streamed per q-block — the flash
+tradeoff).
 
 Grid = (B, H_q, nq, nk), nk innermost with online-softmax carries in VMEM.
 GQA is handled in the BlockSpec index maps (q head h reads kv head h // g) —
@@ -22,8 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.bitdecode.kernel import _CompilerParams
 
 MASK_VALUE = -1e37
 
@@ -72,7 +69,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0, 0] = (acc_scr[...] / l[:, :1]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[:, 0] + jnp.log(l[:, 0])
+        # carries are lane-replicated (bq, 128); the transpose turns the
+        # per-row lse into one lane-dense (1, bq) row
+        lse_ref[0, 0] = (m_scr[...] + jnp.log(l)).T[:1, :]
 
 
 @functools.partial(
@@ -102,7 +101,7 @@ def flash_prefill_pallas(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda bi, h, i, j: (bi, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda bi, h, i, j: (bi, h, i)),
+            pl.BlockSpec((1, 1, 1, bq), lambda bi, h, i, j: (bi, h, 0, i)),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),
@@ -119,11 +118,12 @@ def flash_prefill_pallas(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, s_pad, d), jnp.bfloat16),
-            jax.ShapeDtypeStruct((b, hq, s_pad), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, 1, s_pad), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        name="flash_prefill",
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
     )(q, k, v)
-    return out, lse
+    return out, lse[:, :, 0]

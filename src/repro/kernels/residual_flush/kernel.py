@@ -14,8 +14,10 @@ in one pass per ``(batch, head)``:
   3. the packed words + params are written *directly into the cache* via
      ``input_output_aliases``: the packed arrays are donated, the output
      BlockSpec index map reads the per-sequence destination block
-     ``dest_block[b]`` from scalar prefetch, and only that one block is
-     touched — no whole-cache copy, no select.
+     ``dest_block[b]`` from scalar prefetch, and only that one block of
+     words is touched — no whole-cache copy.  A block's param row alone is
+     no legal TPU tile, so the params move as the head's whole ``(nb, p)``
+     table and the row is replaced by an in-register select.
 
 Per-sequence gating: ``full[b]`` (scalar prefetch) marks sequences whose
 residual just filled.  Programs for non-full sequences copy their (aliased)
@@ -25,13 +27,14 @@ the whole kernel invocation in ``lax.cond(any(full), ...)`` and skips it
 entirely on the per-token hot path.
 
 Constraints (TPU, non-interpret): ``d % 128 == 0`` (the aliased cache cannot
-be lane-padded in place — ops.py falls back to the XLA path otherwise) and
+be lane-padded in place — the kernel raises otherwise) and
 ``block_n % (32 // bits) == 0`` (layout invariant).
 
 The paged variant (:func:`paged_residual_flush_pallas`) commits through a
-page table instead: the destination is a *pool page* index (``dest_page[b]``,
-scalar prefetch) into the shared ``[P, H, ...]`` pools rather than a block of
-sequence ``b``'s own cache.  Same aliasing trick, one extra invariant: the
+page table instead, one grid step per sequence covering every KV head
+(blocks ``(1, H, ...)``, whose params form a legal tile): the destination
+is a *pool page* index (``dest_page[b]``, scalar prefetch) into the shared
+``[P, H, ...]`` pools rather than a block of sequence ``b``'s own cache.  Same aliasing trick, one extra invariant: the
 per-sequence destinations must be pairwise distinct, because two grid rows
 writing the same pool page would race.  Callers guarantee it by routing
 non-flushing sequences to a reserved per-slot scratch page (pages
@@ -44,15 +47,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.kv_quant.kernel import quant_block_tile
-
-try:  # jax >= 0.7 renamed TPUCompilerParams
-    _CompilerParams = pltpu.CompilerParams
-except AttributeError:  # pragma: no cover
-    _CompilerParams = pltpu.TPUCompilerParams
 
 
 def aliased_minor_dims(d_k, d_v, block_n, k_gran, shared_kv) -> list[int]:
@@ -85,6 +84,13 @@ def _body(
          kw_out, ks_out, kz_out, vw_out, vs_out, vz_out) = refs
     b = pl.program_id(0)
     full = full_ref[b] != 0
+    nb = ks_in.shape[2]
+    dest = jnp.minimum(dest_ref[b], nb - 1)
+
+    def put_row(out_ref, in_ref, row):
+        # replace row `dest` of the head's (nb, p) param table
+        rows = lax.broadcasted_iota(jnp.int32, in_ref.shape[2:], 0)
+        out_ref[0, 0] = jnp.where(rows == dest, row[None, :], in_ref[0, 0])
 
     @pl.when(full)
     def _flush():
@@ -93,28 +99,28 @@ def _body(
             k, bits=bits, granularity=k_gran, param_dtype=param_dtype
         )
         kw_out[0, 0, 0] = w
-        ks_out[0, 0, 0] = s
-        kz_out[0, 0, 0] = z
+        put_row(ks_out, ks_in, s)
+        put_row(kz_out, kz_in, z)
         if not shared_kv:
             v = vres_ref[0, 0].astype(jnp.float32)
             wv, sv, zv = quant_block_tile(
                 v, bits=bits, granularity="tensor", param_dtype=param_dtype
             )
             vw_out[0, 0, 0] = wv
-            vs_out[0, 0, 0] = sv
-            vz_out[0, 0, 0] = zv
+            put_row(vs_out, vs_in, sv)
+            put_row(vz_out, vz_in, zv)
 
     @pl.when(jnp.logical_not(full))
     def _keep():
         # the output VMEM block must be written every grid step (it is DMA'd
         # back over the aliased cache block); restore the fetched input
         kw_out[0, 0, 0] = kw_in[0, 0, 0]
-        ks_out[0, 0, 0] = ks_in[0, 0, 0]
-        kz_out[0, 0, 0] = kz_in[0, 0, 0]
+        ks_out[...] = ks_in[...]
+        kz_out[...] = kz_in[...]
         if not shared_kv:
             vw_out[0, 0, 0] = vw_in[0, 0, 0]
-            vs_out[0, 0, 0] = vs_in[0, 0, 0]
-            vz_out[0, 0, 0] = vz_in[0, 0, 0]
+            vs_out[...] = vs_in[...]
+            vz_out[...] = vz_in[...]
 
 
 @functools.partial(
@@ -168,8 +174,8 @@ def residual_flush_pallas(
     w_spec = pl.BlockSpec(
         (1, 1, 1, npr, d_k), lambda i, j, f, dr: (i, j, dst(i, j, f, dr), 0, 0)
     )
-    kp_shape = (1, 1, 1, d_k) if k_gran == "channel" else (1, 1, 1, block_n)
-    kp_spec = pl.BlockSpec(kp_shape, lambda i, j, f, dr: (i, j, dst(i, j, f, dr), 0))
+    kp_shape = (1, 1, nb, d_k if k_gran == "channel" else block_n)
+    kp_spec = pl.BlockSpec(kp_shape, lambda i, j, f, dr: (i, j, 0, 0))
     kres_spec = pl.BlockSpec((1, 1, block_n, d_k), lambda i, j, f, dr: (i, j, 0, 0))
 
     in_specs = [kres_spec]
@@ -184,9 +190,7 @@ def residual_flush_pallas(
         vw_spec = pl.BlockSpec(
             (1, 1, 1, npr, d_v), lambda i, j, f, dr: (i, j, dst(i, j, f, dr), 0, 0)
         )
-        vp_spec = pl.BlockSpec(
-            (1, 1, 1, block_n), lambda i, j, f, dr: (i, j, dst(i, j, f, dr), 0)
-        )
+        vp_spec = pl.BlockSpec((1, 1, nb, block_n), lambda i, j, f, dr: (i, j, 0, 0))
         in_specs += [vres_spec]
         operands += [v_res]
         out_specs += [vw_spec, vp_spec, vp_spec]
@@ -225,7 +229,8 @@ def residual_flush_pallas(
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        name="residual_flush",
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
     )(full.astype(jnp.int32), dest_block.astype(jnp.int32), *operands)
@@ -256,33 +261,33 @@ def _paged_body(
 
     @pl.when(full)
     def _flush():
-        k = kres_ref[0, 0].astype(jnp.float32)  # (block_n, d_k)
+        k = kres_ref[0].astype(jnp.float32)  # (H, block_n, d_k)
         w, s, z = quant_block_tile(
             k, bits=bits, granularity=k_gran, param_dtype=param_dtype
         )
-        kw_out[0, 0] = w
-        ks_out[0, 0] = s
-        kz_out[0, 0] = z
+        kw_out[0] = w
+        ks_out[0] = s
+        kz_out[0] = z
         if not shared_kv:
-            v = vres_ref[0, 0].astype(jnp.float32)
+            v = vres_ref[0].astype(jnp.float32)
             wv, sv, zv = quant_block_tile(
                 v, bits=bits, granularity="tensor", param_dtype=param_dtype
             )
-            vw_out[0, 0] = wv
-            vs_out[0, 0] = sv
-            vz_out[0, 0] = zv
+            vw_out[0] = wv
+            vs_out[0] = sv
+            vz_out[0] = zv
 
     @pl.when(jnp.logical_not(full))
     def _keep():
         # pool page dest_page[b] is this sequence's private scratch page (the
         # caller's injectivity contract); restore the fetched input block
-        kw_out[0, 0] = kw_in[0, 0]
-        ks_out[0, 0] = ks_in[0, 0]
-        kz_out[0, 0] = kz_in[0, 0]
+        kw_out[...] = kw_in[...]
+        ks_out[...] = ks_in[...]
+        kz_out[...] = kz_in[...]
         if not shared_kv:
-            vw_out[0, 0] = vw_in[0, 0]
-            vs_out[0, 0] = vs_in[0, 0]
-            vz_out[0, 0] = vz_in[0, 0]
+            vw_out[...] = vw_in[...]
+            vs_out[...] = vs_in[...]
+            vz_out[...] = vz_in[...]
 
 
 @functools.partial(
@@ -329,16 +334,20 @@ def paged_residual_flush_pallas(
                 "multiples of 128 on TPU — use impl='xla' for this shape"
             )
 
-    def dst(i, j, full_ref, dest_ref):
-        # clamp keeps the DMA in range; callers never pass out-of-pool pages
-        return jnp.minimum(dest_ref[i], n_pages - 1)
+    def pool_spec(*minor):
+        # page dest_page[b] of every head; the clamp keeps the DMA in range
+        # (callers never pass out-of-pool pages)
+        return pl.BlockSpec(
+            (1, h, *minor),
+            lambda i, f, dr: (jnp.minimum(dr[i], n_pages - 1), 0) + (0,) * len(minor),
+        )
 
-    w_spec = pl.BlockSpec(
-        (1, 1, npr, d_k), lambda i, j, f, dr: (dst(i, j, f, dr), j, 0, 0)
-    )
-    kp_shape = (1, 1, d_k) if k_gran == "channel" else (1, 1, block_n)
-    kp_spec = pl.BlockSpec(kp_shape, lambda i, j, f, dr: (dst(i, j, f, dr), j, 0))
-    kres_spec = pl.BlockSpec((1, 1, block_n, d_k), lambda i, j, f, dr: (i, j, 0, 0))
+    def res_spec(d):
+        return pl.BlockSpec((1, h, block_n, d), lambda i, f, dr: (i, 0, 0, 0))
+
+    w_spec = pool_spec(npr, d_k)
+    kp_spec = pool_spec(d_k if k_gran == "channel" else block_n)
+    kres_spec = res_spec(d_k)
 
     if shared_kv:
         pool_specs = [w_spec, kp_spec, kp_spec]
@@ -348,14 +357,9 @@ def paged_residual_flush_pallas(
         n_lead = 3  # full, dest_page, k_res precede the aliased pools
     else:
         d_v = vw_pool.shape[-1]
-        vw_spec = pl.BlockSpec(
-            (1, 1, npr, d_v), lambda i, j, f, dr: (dst(i, j, f, dr), j, 0, 0)
-        )
-        vp_spec = pl.BlockSpec(
-            (1, 1, block_n), lambda i, j, f, dr: (dst(i, j, f, dr), j, 0)
-        )
-        vres_spec = pl.BlockSpec(
-            (1, 1, block_n, d_v), lambda i, j, f, dr: (i, j, 0, 0))
+        vw_spec = pool_spec(npr, d_v)
+        vp_spec = pool_spec(block_n)
+        vres_spec = res_spec(d_v)
         pool_specs = [w_spec, kp_spec, kp_spec, vw_spec, vp_spec, vp_spec]
         pools = [kw_pool, k_scale_pool, k_zero_pool, vw_pool, v_scale_pool,
                  v_zero_pool]
@@ -370,7 +374,7 @@ def paged_residual_flush_pallas(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h),
+        grid=(b,),
         in_specs=in_specs,
         out_specs=pool_specs,
     )
@@ -384,9 +388,8 @@ def paged_residual_flush_pallas(
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel")
-        ),
+        name="paged_residual_flush",
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
     )(full.astype(jnp.int32), dest_page.astype(jnp.int32), *operands)
     if shared_kv:
         kw_pool, k_scale_pool, k_zero_pool = out

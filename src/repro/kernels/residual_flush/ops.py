@@ -35,17 +35,12 @@ def residual_flush(
 
     impl: 'pallas' (single fused kernel, in-place via aliasing; interpret
     mode off-TPU), 'xla' (the select-based reference oracle), or 'auto'
-    (pallas on TPU when the head dim is lane-aligned, xla otherwise — the
-    aliased cache cannot be lane-padded in place, unlike quantize_kv's
-    operand copy).
+    (pallas on TPU, xla elsewhere).  The aliased cache cannot be lane-padded
+    in place, so on TPU a head dim that is not lane-aligned raises; pass
+    ``impl='xla'`` to run such a shape through the reference.
     """
     if impl == "auto":
-        minor = _kernel.aliased_minor_dims(
-            kw.shape[-1], None if shared_kv else vw.shape[-1],
-            block_n, k_gran, shared_kv,
-        )
-        lane_ok = not any(m % 128 for m in minor)
-        impl = "pallas" if jax.default_backend() == "tpu" and lane_ok else "xla"
+        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl == "pallas":
         interpret = jax.default_backend() != "tpu"
         return _kernel.residual_flush_pallas(
@@ -93,17 +88,12 @@ def paged_residual_flush(
     requests — serve/pages.py).  ``shared_kv`` is the MLA latent-pool mode
     (no V-side pools; V operands are ``None``).
 
-    impl: 'pallas' | 'xla' | 'auto' (pallas on TPU when the pool minor dims
-    are lane-aligned, xla otherwise — the aliased pools cannot be lane-padded
-    in place, exactly like the dense flush).
+    impl: 'pallas' | 'xla' | 'auto' (pallas on TPU, xla elsewhere; a pool
+    minor dim that is not lane-aligned raises on TPU, exactly like the dense
+    flush).
     """
     if impl == "auto":
-        minor = _kernel.aliased_minor_dims(
-            kw_pool.shape[-1], None if shared_kv else vw_pool.shape[-1],
-            block_n, k_gran, shared_kv,
-        )
-        lane_ok = not any(m % 128 for m in minor)
-        impl = "pallas" if jax.default_backend() == "tpu" and lane_ok else "xla"
+        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl == "pallas":
         return _kernel.paged_residual_flush_pallas(
             kw_pool, k_scale_pool, k_zero_pool, vw_pool, v_scale_pool,

@@ -11,10 +11,20 @@ from __future__ import annotations
 import jax
 
 
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], *, devices=None):
+    """A mesh with Auto axes: the models place activations through sharding
+    constraints (dist.sharding) and leave the rest to the compiler, the
+    sharding model every program in this repo is written for."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_elastic_mesh(*, model_parallel: int = 16):
@@ -26,7 +36,7 @@ def make_elastic_mesh(*, model_parallel: int = 16):
     model = min(model_parallel, n)
     while n % model:
         model -= 1
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def pick_batch_axes(mesh, global_batch: int) -> tuple:

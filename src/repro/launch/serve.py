@@ -42,8 +42,10 @@ import pathlib
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import get_config, smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.zoo import build_model
 from repro.serve.engine import Request, ServeEngine
 
@@ -53,6 +55,18 @@ FAMILY_ARCHS = {
     "hybrid": "zamba2-7b",
     "xlstm": "xlstm-1.3b",
 }
+
+
+def build_engine(cfg, *, seed: int = 0, **engine_kwargs) -> ServeEngine:
+    """Build ``cfg``'s model with seeded random weights and a
+    :class:`ServeEngine` over it (``engine_kwargs`` go to the engine).  With
+    a ``mesh``, the weights are replicated over it once, up front."""
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(seed))
+    mesh = engine_kwargs.get("mesh")
+    if mesh is not None:
+        params = jax.device_put(params, NamedSharding(mesh, PartitionSpec()))
+    return ServeEngine(model, params, **engine_kwargs)
 
 
 def main():
@@ -131,12 +145,11 @@ def main():
             ap.error("one of --arch / --family is required")
         args.arch = FAMILY_ARCHS[args.family]
 
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = cfg.with_(kv_bits=args.kv_bits)
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    engine = ServeEngine(
-        model, params, slots=args.slots, max_seq=args.max_seq,
+    engine = build_engine(
+        cfg, slots=args.slots, max_seq=args.max_seq,
         paged=False if args.dense else None, n_pages=args.pages,
         splitkv=args.splitkv, share_prefix=not args.no_prefix_sharing,
         reserve_policy=args.reserve_policy,

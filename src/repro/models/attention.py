@@ -24,11 +24,15 @@ def _hq(cfg) -> int:
 
 def attn_def(cfg) -> dict:
     d, hq, hkv, hd = cfg.d_model, _hq(cfg), cfg.n_kv_heads, cfg.head_dim
+    # fan-in scaling over the contracted dims (d in, hq*hd out); the default
+    # reads the second-to-last dim, a head count here, which left random
+    # attention logits in the hundreds and the softmax one-hot
+    s_in, s_out = d**-0.5, (hq * hd) ** -0.5
     defs = {
-        "wq": P((d, hq, hd), ("embed", "heads", "head_dim")),
-        "wk": P((d, hkv, hd), ("embed", "kv_heads", "head_dim")),
-        "wv": P((d, hkv, hd), ("embed", "kv_heads", "head_dim")),
-        "wo": P((hq, hd, d), ("heads", "head_dim", "embed")),
+        "wq": P((d, hq, hd), ("embed", "heads", "head_dim"), scale=s_in),
+        "wk": P((d, hkv, hd), ("embed", "kv_heads", "head_dim"), scale=s_in),
+        "wv": P((d, hkv, hd), ("embed", "kv_heads", "head_dim"), scale=s_in),
+        "wo": P((hq, hd, d), ("heads", "head_dim", "embed"), scale=s_out),
     }
     if cfg.attn_bias:
         defs["bq"] = P((hq, hd), ("heads", "head_dim"), "zeros", jnp.float32)

@@ -11,6 +11,7 @@ From that single source of truth we derive:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -48,7 +49,10 @@ def shape_tree(defs):
     )
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def _init_leaf(p: P, key):
+    # jitted so the f32 draw fuses into the cast: a stacked full-width leaf
+    # never exists in f32 on the device
     if p.init == "zeros":
         return jnp.zeros(p.shape, p.dtype)
     if p.init == "ones":

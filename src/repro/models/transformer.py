@@ -216,18 +216,21 @@ class DecoderLM:
     # ------------------------------------------------------------ prefill
 
     def _block_prefill(self, p, kind, x, positions, max_seq, lengths=None,
-                       block_align=None, prior=None, prior_len=None):
+                       block_align=None, prior=None, prior_len=None,
+                       quant_impl="auto"):
         cfg = self.cfg
         h = layers.apply_norm(cfg.norm, p["ln1"], x, plus_one=cfg.rms_plus_one)
         if cfg.mixer == "mla":
             a, cache = mla.mla_prefill_cache(
                 p["attn"], cfg, h, positions, max_seq, lengths=lengths,
                 block_align=block_align, prior=prior, prior_len=prior_len,
+                quant_impl=quant_impl,
             )
         else:
             a, cache = mattn.attn_prefill_cache(
                 p["attn"], cfg, h, positions, max_seq, lengths=lengths,
                 block_align=block_align, prior=prior, prior_len=prior_len,
+                quant_impl=quant_impl,
             )
         if cfg.parallel_residual:
             f = layers.mlp(p["mlp"], h, cfg.act) if kind == "mlp" else 0.0
@@ -240,7 +243,8 @@ class DecoderLM:
         return x, cache
 
     def prefill(self, params, batch, max_seq: int, *, lengths=None,
-                block_align=None, prior=None, prior_len=None):
+                block_align=None, prior=None, prior_len=None,
+                quant_impl="auto"):
         """Process the prompt, build quantized caches, return (last_logits, state).
 
         ``lengths`` ([B] int32, optional): the batch is ragged — same-bucket
@@ -263,7 +267,8 @@ class DecoderLM:
         Token positions (RoPE) are offset by ``prior_len`` so the suffix lands
         at its unshared global positions; the returned caches hold *suffix*
         content only and ``pos`` counts ``prior_len + lengths``.  Requires a
-        token-only front (no vision / M-RoPE).
+        token-only front (no vision / M-RoPE).  ``quant_impl`` selects the
+        cache-quantization kernel (``kernels/kv_quant``).
         """
         cfg = self.cfg
         if prior is not None:
@@ -287,7 +292,7 @@ class DecoderLM:
                 def body(x, lp, _kind=kind):
                     x, cache = self._block_prefill(
                         lp, _kind, x, positions, max_seq, cache_lengths,
-                        block_align
+                        block_align, quant_impl=quant_impl,
                     )
                     return x, cache
 
@@ -298,6 +303,7 @@ class DecoderLM:
                     x, cache = self._block_prefill(
                         lp, _kind, x, positions, max_seq, cache_lengths,
                         block_align, prior=(kp, None), prior_len=prior_len,
+                        quant_impl=quant_impl,
                     )
                     return x, cache
 
@@ -310,6 +316,7 @@ class DecoderLM:
                     x, cache = self._block_prefill(
                         lp, _kind, x, positions, max_seq, cache_lengths,
                         block_align, prior=(kp, vp), prior_len=prior_len,
+                        quant_impl=quant_impl,
                     )
                     return x, cache
 

@@ -299,6 +299,14 @@ class ServeEngine:
         self.slots = slots
         self.max_seq = max_seq
         self.eos_id = eos_id
+        if mesh is not None:
+            # the host updates the sharded state with eager ops (prefill
+            # adoption, COW, table pushes); only Auto-typed mesh axes allow
+            # that outside a mesh context
+            mesh = jax.sharding.Mesh(
+                mesh.devices, mesh.axis_names,
+                axis_types=(jax.sharding.AxisType.Auto,) * len(mesh.axis_names),
+            )
         self.mesh = mesh
         self.splitkv_axis = splitkv_axis
         self.splitkv = splitkv
@@ -517,30 +525,47 @@ class ServeEngine:
                 np.arange(slots, dtype=np.int32)[:, None], (slots, nb_max)
             ).copy()
             self._table_dirty = False
+
+            def on_mesh(fn):
+                # the compiler never partitions a Mosaic kernel: on a mesh
+                # the prefill runs whole on every chip, under shard_map
+                if mesh is None:
+                    return fn
+                return jax.shard_map(
+                    fn, mesh=mesh, in_specs=jax.sharding.PartitionSpec(),
+                    out_specs=jax.sharding.PartitionSpec(), check_vma=False,
+                )
+
             # one jitted bucketed prefill; jit cache keys on the padded
             # token shape = (slots, bucket_len) -> one compile per bucket
             # (per exact length for exact_prefill families)
             if spec.exact_prefill:
-                self._prefill = jax.jit(
+                self._prefill = jax.jit(on_mesh(
                     lambda p, toks: model.prefill(p, {"tokens": toks},
                                                   toks.shape[1])
-                )
+                ))
             else:
-                self._prefill = jax.jit(
+                self._prefill = jax.jit(on_mesh(
                     lambda p, toks, lengths: model.prefill(
-                        p, {"tokens": toks}, toks.shape[1], lengths=lengths
+                        p, {"tokens": toks}, toks.shape[1], lengths=lengths,
+                        quant_impl=quant_impl,
                     )
-                )
+                ))
             # shared-prefix suffix prefill: dequantizes the prior pages from
             # the pools and attends them from the divergent suffix; the jit
             # cache keys on (bucket_len, padded prior blocks) — prior width
             # is bucketed to powers of two to bound compile count
-            def _suffix_prefill(p, caches, toks, lengths, pages, prior_len):
-                prior = [qcache.dequant_prior(c, pages) for c in caches]
+            @on_mesh
+            def _prefill_over_prior(p, toks, lengths, prior, prior_len):
                 return model.prefill(
                     p, {"tokens": toks}, toks.shape[1],
                     lengths=lengths, prior=prior, prior_len=prior_len,
+                    quant_impl=quant_impl,
                 )
+
+            def _suffix_prefill(p, caches, toks, lengths, pages, prior_len):
+                prior = [qcache.dequant_prior(c, pages) for c in caches]
+                return _prefill_over_prior(p, toks, lengths, prior, prior_len)
 
             self._prefill_shared = jax.jit(_suffix_prefill)
         else:

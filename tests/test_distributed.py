@@ -15,12 +15,13 @@ SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np, functools
     from jax.sharding import NamedSharding, PartitionSpec as PS
+    from repro.launch.mesh import make_mesh
 
     # ---------------- split-KV decode vs oracle ----------------
     from repro.core import qcache, attention as catt
     from repro.dist.splitkv import splitkv_decode_attention
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     B, H, D, BLOCK, NBLK = 1, 2, 128, 128, 8
     S = NBLK * BLOCK + 37
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
@@ -121,7 +122,7 @@ SCRIPT = textwrap.dedent(
     PER_CHIP = 4
     shard_bytes = {}
     for n_ax in (4, 8):
-        msh = jax.make_mesh((n_ax,), ("data",))  # data-only: bytes differ
+        msh = make_mesh((n_ax,), ("data",))  # data-only: bytes differ
         # only through the page dim, not a heads (model) split
         specs = decode_state_specs(modelm, msh, global_batch=4, seq_ax="data",
                                    paged=True, n_pages=PER_CHIP * n_ax,
@@ -146,7 +147,7 @@ SCRIPT = textwrap.dedent(
     cfgs = smoke_config("llama3-8b").with_(kv_bits=4, kv_block=32)
     models = build_model(cfgs)
     prms = models.init(jax.random.PRNGKey(0))
-    smesh = jax.make_mesh((8,), ("data",))
+    smesh = make_mesh((8,), ("data",))
     rng = np.random.default_rng(7)
     pa = rng.integers(0, cfgs.vocab, 32 + 8).astype(np.int32)
     pb = pa[:8].copy()  # strict mid-block prefix -> spec-tail COW
@@ -219,15 +220,14 @@ SCRIPT = textwrap.dedent(
     print("OK train run 8dev", float(metrics["loss"]))
 
     # ---------------- gradient compression with error feedback --------
-    from jax.experimental.shard_map import shard_map
     from repro.optim.grad_compress import compress_allreduce
 
-    pmesh = jax.make_mesh((2, 4), ("pod", "data"))
+    pmesh = make_mesh((2, 4), ("pod", "data"))
     g = jax.random.normal(jax.random.PRNGKey(1), (2, 64), jnp.float32)
 
     @functools.partial(
-        shard_map, mesh=pmesh, in_specs=(PS("pod"), PS("pod")),
-        out_specs=(PS("pod"), PS("pod")), check_rep=False)
+        jax.shard_map, mesh=pmesh, in_specs=(PS("pod"), PS("pod")),
+        out_specs=(PS("pod"), PS("pod")), check_vma=False)
     def red(gs, es):
         r, e = compress_allreduce(gs[0], es[0], "pod")
         return r[None], e[None]

@@ -183,7 +183,7 @@ def test_staggered_flush_across_batch():
 
 
 def _collect_prims(jaxpr, into):
-    import jax.core as jc
+    from jax.extend import core as jc
 
     for e in jaxpr.eqns:
         into.add(e.primitive.name)

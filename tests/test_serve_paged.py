@@ -230,7 +230,7 @@ def test_paged_engine_matches_dense_oracle(small_model):
 # --------------------------------------------------------------------------
 
 def _collect_prims(jaxpr, into):
-    import jax.core as jc
+    from jax.extend import core as jc
 
     for e in jaxpr.eqns:
         into.add(e.primitive.name)
