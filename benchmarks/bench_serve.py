@@ -1,6 +1,6 @@
 """Serving-throughput sweeps for the paged continuous-batching engine.
 
-Five sweeps, all appending to BENCH_serve.json so future PRs track them:
+Seven sweeps, all appending to BENCH_serve.json so future PRs track them:
 
 * **offered load** (default): requests arrive on a virtual clock (the
   measured engine wall time) at a configured rate with a prompt-length mix;
@@ -39,17 +39,14 @@ Five sweeps, all appending to BENCH_serve.json so future PRs track them:
   computed vs. saved, retained-hit and retained-reclaim counts with
   retention off and on, over an ample and a deliberately tight pool, plus
   the §14 bitwise oracles (cold first round, retained-hit == live-hit).
-* **pool gauges** (``--pool-gauges``): host-side micro-bench of the
-  allocator's gauge refresh — ``gauge_mode="incremental"`` vs ``"full"``
-  microseconds per reserve/alloc/free round-trip.
 
 Telemetry (docs/OBSERVABILITY.md): every offered-load cell reports TTFT and
 TPOT percentiles (split latency series — queueing shows up in TTFT, steady
 decode in TPOT) and the host-stall fraction (share of each cycle NOT spent
 waiting on the device).  ``--phase-breakdown`` adds the per-phase seconds
-(schedule / prefill / decode_dispatch / device_wait / advance) to each
-record; ``--trace-out PATH`` traces the first cell and writes a Chrome
-``trace_event`` JSON openable in Perfetto.
+(schedule / prefill / decode_dispatch / device_wait / logits_pull / sample
+/ advance) to each record; ``--trace-out PATH`` traces the first cell and
+writes a Chrome ``trace_event`` JSON openable in Perfetto.
 
 CPU smoke scale by default; the same sweeps run unchanged on TPU.
 """
@@ -751,53 +748,6 @@ def run_tenant_churn_sweep(*, n_tenants=3, rounds=2, max_new=26, slots=2,
     return records
 
 
-def run_pool_gauge_bench(*, n_pages=258, n_scratch=2, iters=5000,
-                         out_path: Path | None = None):
-    """Host-side micro-bench of ``PagePool._update_gauges``: a pure-python
-    reserve/alloc/free round-trip per iteration (three gauge refreshes)
-    under ``gauge_mode="incremental"`` (cached instrument handles,
-    skip-if-unchanged) vs. ``"full"`` (re-resolve every gauge by name,
-    re-set all five).  The allocator runs on the host inside every decode
-    cycle, so this overhead lands directly on the schedule phase."""
-    import time as _time
-
-    from repro.serve import PagePool
-    from repro.serve.telemetry import MetricsRegistry
-
-    results = {}
-    for mode in ("incremental", "full"):
-        pool = PagePool(n_pages, n_scratch=n_scratch,
-                        metrics=MetricsRegistry(), gauge_mode=mode)
-        # warm-up so both modes measure steady state, not first-touch
-        for _ in range(100):
-            pool.reserve(1)
-            pool.free(pool.alloc())
-        t0 = _time.perf_counter()
-        for _ in range(iters):
-            pool.reserve(1)
-            pool.free(pool.alloc())
-        results[mode] = (_time.perf_counter() - t0) / iters
-    rec = {
-        "iters": iters,
-        "n_pages": n_pages,
-        "incremental_us_per_op": round(results["incremental"] * 1e6, 3),
-        "full_us_per_op": round(results["full"] * 1e6, 3),
-        "speedup": round(results["full"] / max(results["incremental"], 1e-12),
-                         3),
-    }
-    emit(
-        "serve.pool_gauges", results["incremental"] * 1e6,
-        f"full_us={rec['full_us_per_op']};speedup={rec['speedup']}",
-    )
-    out_path = _BENCH_SERVE if out_path is None else out_path
-    _append(out_path, {
-        "backend": jax.default_backend(),
-        "sweep": "pool_gauges",
-        "records": [rec],
-    })
-    return [rec]
-
-
 def run():
     run_serve_sweep(phase_breakdown=True)
     run_shared_prefix_sweep()
@@ -806,7 +756,6 @@ def run():
     run_spec_decode_sweep()
     run_async_sweep()
     run_tenant_churn_sweep()
-    run_pool_gauge_bench()
 
 
 if __name__ == "__main__":
@@ -832,13 +781,11 @@ if __name__ == "__main__":
                     help="run only the tenant-churn sweep (rotating "
                          "sessions over shared system prompts, retention "
                          "off vs on, ample vs tight pool)")
-    ap.add_argument("--pool-gauges", action="store_true",
-                    help="run only the PagePool gauge-mode micro-bench "
-                         "(incremental vs full _update_gauges)")
     ap.add_argument("--phase-breakdown", action="store_true",
                     help="add per-phase seconds (schedule/prefill/"
-                         "decode_dispatch/device_wait/advance) to every "
-                         "offered-load record (docs/OBSERVABILITY.md)")
+                         "decode_dispatch/device_wait/logits_pull/sample/"
+                         "advance) to every offered-load record "
+                         "(docs/OBSERVABILITY.md)")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="trace the first offered-load cell and write a "
                          "Chrome trace_event JSON (open in Perfetto)")
@@ -853,8 +800,6 @@ if __name__ == "__main__":
         run_async_sweep()
     elif args.tenant_churn:
         run_tenant_churn_sweep()
-    elif args.pool_gauges:
-        run_pool_gauge_bench()
     elif args.family is not None:
         run_family_sweep(
             families=tuple(args.family) if args.family else

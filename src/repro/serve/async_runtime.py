@@ -246,7 +246,7 @@ class AsyncRunner:
         model = engine.model
         impl, quant_impl = engine._impl, engine._quant_impl
 
-        def _astep(p, s, t):
+        def serve_async_decode_step(p, s, t):
             logits, st = model.decode_step(
                 p, s, t, impl=impl, quant_impl=quant_impl
             )
@@ -256,7 +256,7 @@ class AsyncRunner:
             return nxt, finite, nxt[:, None], st
 
         self._tokens_dev = jnp.zeros((engine.slots, 1), jnp.int32)
-        self._astep = jax.jit(_astep, donate_argnums=(1, 2))
+        self._astep = jax.jit(serve_async_decode_step, donate_argnums=(1, 2))
         # AOT compile against the engine's real decode-state avals: the
         # executable is warm before the first request arrives, and the
         # steady-state loop never retraces
@@ -267,15 +267,18 @@ class AsyncRunner:
         # feed-override helpers (fixed shapes -> one compile each):
         # host-known token overrides merge by mask; admission firsts scatter
         # by row->slot index, padded rows pointing out of bounds (dropped)
-        self._merge = jax.jit(
-            lambda t, mask, vals: jnp.where(mask[:, None], vals[:, None], t)
-        )
-        self._scatter_rows = jax.jit(
-            lambda t, sidx, vals: t.at[sidx, 0].set(vals)
-        )
-        self._scatter_one = jax.jit(
-            lambda t, slot, val: t.at[slot, 0].set(val)
-        )
+        def serve_feed_merge(t, mask, vals):
+            return jnp.where(mask[:, None], vals[:, None], t)
+
+        def serve_feed_scatter_rows(t, sidx, vals):
+            return t.at[sidx, 0].set(vals)
+
+        def serve_feed_scatter_one(t, slot, val):
+            return t.at[slot, 0].set(val)
+
+        self._merge = jax.jit(serve_feed_merge)
+        self._scatter_rows = jax.jit(serve_feed_scatter_rows)
+        self._scatter_one = jax.jit(serve_feed_scatter_one)
 
     # ----------------------------------------------------------- liveness
 
@@ -331,14 +334,7 @@ class AsyncRunner:
     # ------------------------------------------------------- the cycle
 
     def step(self) -> bool:
-        eng = self.eng
-        t0 = time.perf_counter()
-        eng._cycle += 1
-        eng._cycle_worked = False
-        try:
-            return self._step_once(t0)
-        finally:
-            eng._finish_cycle(t0)
+        return self.eng._run_cycle(self._step_once)
 
     def _step_once(self, t0: float) -> bool:
         eng = self.eng
@@ -514,7 +510,7 @@ class AsyncRunner:
 
             affine = getattr(eng, "page_affine", False)
 
-            def _astep_sk(p, s, t):
+            def serve_async_decode_step_splitkv(p, s, t):
                 with catt.use_splitkv(mesh, axis, page_affine=affine):
                     logits, st = model.decode_step(
                         p, s, t, impl=impl, quant_impl=quant_impl
@@ -523,7 +519,8 @@ class AsyncRunner:
                 nxt = jnp.argmax(row, axis=-1).astype(jnp.int32)
                 return nxt, jnp.isfinite(row).all(axis=-1), nxt[:, None], st
 
-            self._astep_sk = jax.jit(_astep_sk, donate_argnums=(1, 2))
+            self._astep_sk = jax.jit(serve_async_decode_step_splitkv,
+                                     donate_argnums=(1, 2))
         return self._astep_sk
 
     # -------------------------------------------------- consumption side

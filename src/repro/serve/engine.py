@@ -85,13 +85,18 @@ failures at the named sites (`repro.serve.faults`) for chaos tests.
 lifecycle counter lives in a shared :class:`MetricsRegistry` (the ``stats``
 property keeps the historical dict view), each cycle is decomposed into
 timed phases — ``schedule``, ``prefill``, ``decode_dispatch``,
-``device_wait`` (an explicit ``jax.block_until_ready`` boundary), and
-``advance`` — feeding per-phase histograms plus the derived
-``host_stall_fraction`` / ``device_idle_gap_s`` metrics, and token
-latencies split into TTFT (submission → first token, queue wait included)
-and TPOT (inter-token) series.  ``trace=True`` additionally records a
-structured event log (request lifecycle spans, COW / preemption /
-speculative / audit / fault instants, per-phase complete events) that
+``device_wait`` (an explicit ``jax.block_until_ready`` boundary),
+``logits_pull`` (the device→host copy of the logits rows), ``sample``
+(argmax and the finiteness guard) and ``advance`` — feeding per-phase
+histograms plus the derived ``host_stall_fraction``, and token latencies
+split into TTFT (submission → first token, queue wait included) and TPOT
+(inter-token) series.  Every cycle and every phase block is also a
+``jax.profiler.TraceAnnotation`` span (``serve.cycle``, ``serve.<phase>``;
+``serve.prefill`` carries the ``tokens`` it was asked for and the tokens
+it ``computed``), so a profiler trace names each stretch of device idle
+time by the engine phase the host was in.  ``trace=True`` additionally
+records a structured event log (request lifecycle spans, COW / preemption
+/ speculative / audit / fault instants, per-phase complete events) that
 exports as JSONL or Chrome ``trace_event`` JSON for Perfetto.  All of it is
 host-side observation only — enabling telemetry never changes a computed
 token (the bitwise-parity suites run with tracing on).
@@ -103,6 +108,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import attention as catt
 from repro.core import qcache
@@ -126,8 +132,15 @@ PHASE_METRICS = {
     "prefill": "phase_prefill_s",
     "decode_dispatch": "phase_decode_dispatch_s",
     "device_wait": "phase_device_wait_s",
+    "logits_pull": "phase_logits_pull_s",
+    "sample": "phase_sample_s",
     "advance": "phase_advance_s",
 }
+
+#: profiler span of each phase and of the whole cycle (docs/OBSERVABILITY.md
+#: "Profiler spans"); built once, not per block
+PHASE_SPANS = {name: f"serve.{name}" for name in PHASE_METRICS}
+CYCLE_SPAN = "serve.cycle"
 
 #: timing-derived ``summary()`` keys — everything a determinism comparison
 #: must strip before asserting two runs equal (tests/test_serve_pressure.py)
@@ -144,6 +157,10 @@ TIMING_SUMMARY_KEYS = frozenset({
 STAT_COUNTERS = (
     "decoded_tokens", "steps", "prefill_calls", "splitkv_steps",
     "prefill_tokens", "prefill_tokens_saved", "cow_copies",
+    # tokens the prefill programs computed (slots x bucket length; the
+    # prompt length in the exact-length shim), of which prefill_tokens were
+    # asked for
+    "prefill_tokens_computed",
     # retirement breakdown (each request counts in at most one):
     # budget_retired = hit max_new_tokens without EOS
     "budget_retired", "preempted", "preempt_remat_tokens",
@@ -169,21 +186,26 @@ def _percentile(xs: list[float], q: float) -> float:
 class _PhaseTimer:
     """Accumulating timer for one named cycle phase: elapsed wall time adds
     into the engine's per-cycle accumulator (several with-blocks of the same
-    phase within a cycle sum), and with tracing on, each block additionally
-    emits one Chrome complete event on the engine track."""
+    phase within a cycle sum), each block is one profiler span
+    ``serve.<phase>`` carrying ``meta`` as its stats, and with tracing on,
+    each block additionally emits one Chrome complete event on the engine
+    track."""
 
-    __slots__ = ("engine", "name", "t0")
+    __slots__ = ("engine", "name", "span", "t0")
 
-    def __init__(self, engine, name: str):
+    def __init__(self, engine, name: str, **meta):
         self.engine = engine
         self.name = name
+        self.span = TraceAnnotation(PHASE_SPANS[name], **meta)
 
     def __enter__(self):
+        self.span.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dt = time.perf_counter() - self.t0
+        self.span.__exit__(*exc)
         acc = self.engine._phase_acc
         acc[self.name] = acc.get(self.name, 0.0) + dt
         tracer = self.engine.tracer
@@ -332,7 +354,6 @@ class ServeEngine:
         for hist in PHASE_METRICS.values():
             self.metrics.histogram(hist)
         self.metrics.histogram("cycle_s")
-        self.metrics.histogram("device_idle_gap_s")
         # async runtime: wall time the dispatch pipeline sat empty while
         # work remained (the overlap-aware host-stall numerator, §13)
         self.metrics.histogram("device_starved_s")
@@ -398,12 +419,13 @@ class ServeEngine:
         self._impl = impl
         self._quant_impl = quant_impl
         # one jitted decode step (static shapes) shared by every family, and
-        # the host-side next-token buffer (one device->host pull per cycle)
-        self._step = jax.jit(
-            lambda p, s, t: model.decode_step(
-                p, s, t, impl=impl, quant_impl=quant_impl
-            )
-        )
+        # the host-side next-token buffer (one device->host pull per cycle);
+        # every program is a named function, so a profile names it
+        # jit_serve_<...>
+        def serve_decode_step(p, s, t):
+            return model.decode_step(p, s, t, impl=impl, quant_impl=quant_impl)
+
+        self._step = jax.jit(serve_decode_step)
         self._step_splitkv = None
         if mesh is not None and splitkv_axis not in getattr(mesh, "axis_names", ()):
             raise ValueError(
@@ -413,13 +435,13 @@ class ServeEngine:
         if mesh is not None and splitkv != "never":
             _affine = bool(page_affine)
 
-            def _split_step(p, s, t):
+            def serve_decode_step_splitkv(p, s, t):
                 with catt.use_splitkv(mesh, splitkv_axis,
                                       page_affine=_affine):
                     return model.decode_step(
                         p, s, t, impl=impl, quant_impl=quant_impl
                     )
-            self._step_splitkv = jax.jit(_split_step)
+            self._step_splitkv = jax.jit(serve_decode_step_splitkv)
 
         self.tokens = np.zeros((slots, 1), np.int32)
         self._occupancy: list[float] = []
@@ -540,17 +562,15 @@ class ServeEngine:
             # token shape = (slots, bucket_len) -> one compile per bucket
             # (per exact length for exact_prefill families)
             if spec.exact_prefill:
-                self._prefill = jax.jit(on_mesh(
-                    lambda p, toks: model.prefill(p, {"tokens": toks},
-                                                  toks.shape[1])
-                ))
+                def serve_prefill(p, toks):
+                    return model.prefill(p, {"tokens": toks}, toks.shape[1])
             else:
-                self._prefill = jax.jit(on_mesh(
-                    lambda p, toks, lengths: model.prefill(
+                def serve_prefill(p, toks, lengths):
+                    return model.prefill(
                         p, {"tokens": toks}, toks.shape[1], lengths=lengths,
                         quant_impl=quant_impl,
                     )
-                ))
+            self._prefill = jax.jit(on_mesh(serve_prefill))
             # shared-prefix suffix prefill: dequantizes the prior pages from
             # the pools and attends them from the divergent suffix; the jit
             # cache keys on (bucket_len, padded prior blocks) — prior width
@@ -563,11 +583,12 @@ class ServeEngine:
                     quant_impl=quant_impl,
                 )
 
-            def _suffix_prefill(p, caches, toks, lengths, pages, prior_len):
+            def serve_prefill_shared(p, caches, toks, lengths, pages,
+                                     prior_len):
                 prior = [qcache.dequant_prior(c, pages) for c in caches]
                 return _prefill_over_prior(p, toks, lengths, prior, prior_len)
 
-            self._prefill_shared = jax.jit(_suffix_prefill)
+            self._prefill_shared = jax.jit(serve_prefill_shared)
         else:
             # exact-length shim: dense state, per-request prefill, no pool
             self.pool = None
@@ -579,9 +600,11 @@ class ServeEngine:
                 strict=strict, clock=self.clock, metrics=self.metrics,
             )
             self.state = model.init_decode_state(slots, max_seq)
-            self._prefill = jax.jit(
-                lambda p, b: model.prefill(p, b, self.max_seq)
-            )
+
+            def serve_prefill(p, batch):
+                return model.prefill(p, batch, max_seq)
+
+            self._prefill = jax.jit(serve_prefill)
 
         # --- async overlapped runtime (docs/SERVING.md §13) ---------------
         self.async_runtime = bool(async_runtime)
@@ -607,9 +630,11 @@ class ServeEngine:
         interface, now a read-only view of the metrics registry)."""
         return {k: int(self.metrics.value(k)) for k in STAT_COUNTERS}
 
-    def _phase(self, name: str) -> _PhaseTimer:
-        """Timer for one cycle phase (``with self._phase("schedule"): ...``)."""
-        return _PhaseTimer(self, name)
+    def _phase(self, name: str, **meta) -> _PhaseTimer:
+        """Timer and profiler span for one cycle phase
+        (``with self._phase("schedule"): ...``); ``meta`` becomes the
+        span's stats."""
+        return _PhaseTimer(self, name, **meta)
 
     def _on_fault(self, site: str, cycle: int, uid) -> None:
         """``FaultPlan.on_fire`` hook: count and trace every injected fault."""
@@ -776,13 +801,20 @@ class ServeEngine:
             return self._runner.step()
         if self.spec_k > 1:
             return self._step_spec()
+        return self._run_cycle(self._step_once)
+
+    def _run_cycle(self, body) -> bool:
+        """Run one cycle body (``body(t0) -> worked``) of any runtime inside
+        its ``serve.cycle`` profiler span, then the cycle-boundary
+        bookkeeping on every exit path."""
         t0 = time.perf_counter()
         self._cycle += 1
         self._cycle_worked = False
-        try:
-            return self._step_once(t0)
-        finally:
-            self._finish_cycle(t0)
+        with TraceAnnotation(CYCLE_SPAN, cycle=self._cycle):
+            try:
+                return body(t0)
+            finally:
+                self._finish_cycle(t0)
 
     def _step_once(self, t0: float) -> bool:
         with self._phase("schedule"):
@@ -826,12 +858,15 @@ class ServeEngine:
                 self.params, self.state, jnp.asarray(self.tokens)
             )
         # one host sync per cycle: the explicit block_until_ready boundary
-        # separates waiting on device compute from the host work around it
-        # (the phase breakdown is how host-stall fraction gets measured)
+        # separates waiting on device compute from the host work after it —
+        # the pull of the logits rows, the sampling and guard over them, and
+        # the token accounting (the phase breakdown is how host-stall
+        # fraction gets measured)
         with self._phase("device_wait"):
             logits = jax.block_until_ready(logits)
+        with self._phase("logits_pull"):
             rows = np.array(np.asarray(logits)[:, 0])
-        with self._phase("advance"):
+        with self._phase("sample"):
             if self.faults is not None:
                 for slot, req in list(self.sched.active.items()):
                     if self.faults.fires(
@@ -848,6 +883,7 @@ class ServeEngine:
                         bad[slot] = "non-finite logits row"
                     elif not 0 <= int(nxt[slot]) < rows.shape[-1]:
                         bad[slot] = f"invalid next token id {int(nxt[slot])}"
+        with self._phase("advance"):
             self.metrics.inc("steps")
             if self.paged:
                 # occupancy at the cycle peak — post-admission, pre-release:
@@ -861,11 +897,11 @@ class ServeEngine:
         return True
 
     def _finish_cycle(self, t0: float) -> None:
-        """Cycle-boundary bookkeeping, run on every exit path of
-        :meth:`step` / :meth:`_step_spec`: fold the per-phase accumulator
-        into the registry histograms, derive the device-idle gap, advance
-        the first-work -> last-work window behind the ``wall_s`` fallback,
-        and service the periodic metrics sink."""
+        """Cycle-boundary bookkeeping, run on every exit path of a cycle
+        (:meth:`_run_cycle`): fold the per-phase accumulator into the
+        registry histograms, advance the first-work -> last-work window
+        behind the ``wall_s`` fallback, and service the periodic metrics
+        sink."""
         now = time.perf_counter()
         cycle_s = now - t0
         acc, self._phase_acc = self._phase_acc, {}
@@ -874,11 +910,6 @@ class ServeEngine:
         for name, hist in PHASE_METRICS.items():
             if name in acc:
                 m.observe(hist, acc[name])
-        # the device is busy (at most) while the host waits on it or runs a
-        # prefill; the rest of the cycle is host-side gap the async runtime
-        # (ROADMAP) exists to overlap away
-        busy = acc.get("device_wait", 0.0) + acc.get("prefill", 0.0)
-        m.observe("device_idle_gap_s", max(0.0, cycle_s - busy))
         if self._cycle_worked:
             if self._work_t0 is None:
                 self._work_t0 = t0
@@ -918,13 +949,7 @@ class ServeEngine:
 
         Two host syncs per cycle regardless of ``spec_k`` — the latency win
         on the memory-bound decode this paper targets."""
-        t0 = time.perf_counter()
-        self._cycle += 1
-        self._cycle_worked = False
-        try:
-            return self._step_spec_once(t0)
-        finally:
-            self._finish_cycle(t0)
+        return self._run_cycle(self._step_spec_once)
 
     def _step_spec_once(self, t0: float) -> bool:
         with self._phase("schedule"):
@@ -1452,7 +1477,11 @@ class ServeEngine:
                 self._note_admissions(groups)
         lazy: dict[int, tuple] = {}
         for bucket_len, reqs in groups.items():
-            with self._phase("prefill"):
+            # every prefill program computes all slots x bucket_len tokens
+            tokens = sum(r.suffix_len(self.block_n) for r in reqs)
+            computed = self.slots * bucket_len
+            self.metrics.inc("prefill_tokens_computed", computed)
+            with self._phase("prefill", tokens=tokens, computed=computed):
                 lazy.update(self._prefill_bucket(
                     bucket_len, reqs, defer_first=defer_first
                 ))
@@ -1674,7 +1703,9 @@ class ServeEngine:
         lazy: dict[int, tuple] = {}
         for reqs in groups.values():
             for req in reqs:
-                with self._phase("prefill"):
+                self.metrics.inc("prefill_tokens_computed", req.prompt_len)
+                with self._phase("prefill", tokens=req.prompt_len,
+                                 computed=req.prompt_len):
                     lazy.update(
                         self._fill_slot(req, defer_first=defer_first)
                     )

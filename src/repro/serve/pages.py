@@ -98,8 +98,7 @@ class PagePool:
     LRU retained tier, and optional page-affine sharding."""
 
     def __init__(self, n_pages: int, *, n_scratch: int, page_bytes: int = 0,
-                 metrics=None, shards: int = 1,
-                 gauge_mode: str = "incremental"):
+                 metrics=None, shards: int = 1):
         """``page_bytes`` is the per-family byte size of one page across
         every paged layer-cache (the engine measures it from the allocated
         pools), so occupancy can be reported in bytes — a hybrid page covers
@@ -111,11 +110,7 @@ class PagePool:
 
         ``shards`` splits the free list into that many contiguous page
         ranges for page-affine allocation (see module docstring); scratch
-        pages must fit inside shard 0.  ``gauge_mode`` is ``"incremental"``
-        (cached instrument handles, only changed gauges written — the hot
-        path) or ``"full"`` (every gauge recomputed and re-set through the
-        registry on every mutation — the pre-retention behaviour, kept for
-        the bench_serve before/after comparison)."""
+        pages must fit inside shard 0."""
         if n_pages <= n_scratch:
             raise ValueError(
                 f"n_pages={n_pages} must exceed n_scratch={n_scratch}"
@@ -130,13 +125,10 @@ class PagePool:
                 f"n_scratch={n_scratch} must fit inside shard 0 "
                 f"({n_pages // shards} pages/shard)"
             )
-        if gauge_mode not in ("incremental", "full"):
-            raise ValueError(f"unknown gauge_mode {gauge_mode!r}")
         self.n_pages = n_pages
         self.n_scratch = n_scratch
         self.page_bytes = page_bytes
         self.shards = shards
-        self.gauge_mode = gauge_mode
         pps = n_pages // shards
         self._pages_per_shard = pps
         self._shard_free: list[deque[int]] = [
@@ -183,19 +175,10 @@ class PagePool:
 
     def _update_gauges(self) -> None:
         """Refresh the registry gauges after an accounting mutation (the
-        gauges' high-water marks record peak commitment between samples).
-        ``incremental`` mode writes through cached instrument handles and
-        skips gauges whose value did not change; ``full`` mode re-resolves
-        every instrument by name and re-sets all of them."""
-        m = self.metrics
-        if m is None:
-            return
-        if self.gauge_mode == "full":
-            m.set_gauge("pool_pages_used", self.n_used)
-            m.set_gauge("pool_pages_reserved", self.reserved)
-            m.set_gauge("pool_pages_committed", self.committed)
-            m.set_gauge("pool_occupancy", self.occupancy)
-            m.set_gauge("pool_pages_retained", self.n_retained)
+        gauges' high-water marks record peak commitment between samples):
+        writes through cached instrument handles and skips gauges whose
+        value did not change."""
+        if self.metrics is None:
             return
         vals = (float(self.n_used), float(self.reserved),
                 float(self.committed), self.occupancy,

@@ -77,7 +77,7 @@ def make_draft_fn(model, *, spec_k: int, spec_bits: int,
     if steps < 1:
         raise ValueError(f"spec_k={spec_k} needs no draft pass (k >= 2)")
 
-    def draft(params, state, tok0):
+    def serve_spec_draft(params, state, tok0):
         st = dict(state)
         if "caches" in st:
             st["caches"] = [qcache.widen_residual(c, steps) for c in st["caches"]]
@@ -95,7 +95,7 @@ def make_draft_fn(model, *, spec_k: int, spec_bits: int,
         _, toks = lax.scan(body, (st, tok0), None, length=steps)
         return jnp.moveaxis(toks, 0, 1)  # [B, steps]
 
-    return jax.jit(draft)
+    return jax.jit(serve_spec_draft)
 
 
 def make_verify_fn(model, spec, *, impl: str = "auto",
@@ -127,7 +127,7 @@ def make_verify_fn(model, spec, *, impl: str = "auto",
     """
     side = tuple(spec.side_state) if spec is not None else ()
 
-    def verify(params, state, feeds, limit, forced):
+    def serve_spec_verify(params, state, feeds, limit, forced):
         k = feeds.shape[1]
         feeds_t = jnp.moveaxis(feeds, 0, 1)  # [K, B]
         nxt_t = jnp.moveaxis(
@@ -160,4 +160,4 @@ def make_verify_fn(model, spec, *, impl: str = "auto",
             st,
         )
 
-    return jax.jit(verify)
+    return jax.jit(serve_spec_verify)

@@ -23,8 +23,9 @@ execution:
   ``queue`` after a preemption requeue — every span carries the request
   uid;
 * *engine phase spans*: one complete event per cycle phase (``schedule``,
-  ``prefill``, ``decode_dispatch``, ``device_wait``, ``advance`` —
-  serve/engine.py's phase-timing breakdown);
+  ``prefill``, ``decode_dispatch``, ``device_wait``, ``logits_pull``,
+  ``sample``, ``advance`` — serve/engine.py's phase-timing breakdown, whose
+  phases are also ``serve.<phase>`` profiler spans);
 * *point events*: ``submit``, ``cow``, ``preempt``, ``replay_done``,
   ``spec_verify``, ``audit``, ``fault``, ``rejected`` and the terminal
   phase markers (``done`` / ``preempted`` / ``expired`` / ``cancelled`` /
@@ -43,8 +44,9 @@ referenced request uid resolving to a submitted request.
 
 The tracer costs one dict append per event when enabled and **nothing when
 disabled**: the engine holds ``tracer = None`` and every call site is
-guarded, so a production run pays only the perf_counter reads of the
-always-on phase-timing breakdown.
+guarded, so a production run pays only the perf_counter reads and the
+profiler spans (about a microsecond each with no profiler attached) of
+the always-on phase-timing breakdown.
 """
 from __future__ import annotations
 

@@ -170,17 +170,23 @@ def test_force_reclaim_is_lru_ordered_and_bounded():
     assert pool.reclaim_retained(1) == 0  # empty tier is a safe no-op
 
 
-def test_incremental_and_full_gauge_modes_agree():
-    for mode in ("incremental", "full"):
-        metrics = MetricsRegistry()
-        pool = _retaining_pool(8, 2, metrics=metrics, gauge_mode=mode)
-        pool.reserve(3)
-        pages = [pool.alloc() for _ in range(2)]
-        pool.free(pages[0])
-        assert metrics.value("pool_pages_used") == 2
-        assert metrics.value("pool_pages_retained") == 1
-        assert metrics.value("pool_pages_reserved") == 1
-        assert metrics.value("pool_pages_committed") == 3
+def test_pool_gauges_follow_every_mutation():
+    metrics = MetricsRegistry()
+    pool = _retaining_pool(8, 2, metrics=metrics)
+    pool.reserve(3)
+    pages = [pool.alloc() for _ in range(2)]
+    pool.free(pages[0])
+    assert metrics.value("pool_pages_used") == 2
+    assert metrics.value("pool_pages_retained") == 1
+    assert metrics.value("pool_pages_reserved") == 1
+    assert metrics.value("pool_pages_committed") == 3
+    assert metrics.value("pool_occupancy") == pytest.approx(2 / 6)
+    # a gauge whose value did not change is skipped, not left stale
+    assert pool.reclaim_retained(1) == 1
+    assert metrics.value("pool_pages_used") == 1
+    assert metrics.value("pool_pages_retained") == 0
+    assert metrics.value("pool_pages_reserved") == 1
+    assert metrics.value("pool_pages_committed") == 2
 
 
 # --------------------------------------------------------------------------

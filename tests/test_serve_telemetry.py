@@ -27,10 +27,17 @@ from repro.serve import (
     audit_engine,
     validate_events,
 )
-from repro.serve.engine import PHASE_METRICS, STAT_COUNTERS
+from repro.serve.engine import (
+    CYCLE_SPAN,
+    PHASE_METRICS,
+    PHASE_SPANS,
+    STAT_COUNTERS,
+)
 from repro.serve.telemetry import Histogram
 
 BLOCK = 32
+#: phases only the sync cycle runs: it pulls the logits rows to the host
+SYNC_ONLY_PHASES = {"logits_pull", "sample"}
 
 
 @pytest.fixture(scope="module")
@@ -268,10 +275,12 @@ def test_engine_trace_schema_valid_and_lifecycle_complete(small_model,
     for span in ("queue", "prefill", "decode"):
         assert req_names.count(span) == 2 * len(reqs), span  # B + E each
     assert req_names.count("done") == len(reqs)
-    # per-cycle phase records are present for every phase
+    # per-cycle phase records are present for every phase the runtime runs
+    # (the async runtime keeps the logits on the device: no pull, no sample)
     phase_names = {e["name"] for e in engine.tracer.events
                    if e["cat"] == "engine"}
-    assert set(PHASE_METRICS) <= phase_names
+    ran = set(PHASE_METRICS) - (SYNC_ONLY_PHASES if async_runtime else set())
+    assert ran <= phase_names
     assert engine.tracer.open_spans() == []
     assert audit_engine(engine).ok
 
@@ -282,7 +291,9 @@ def test_engine_phase_breakdown_and_host_stall(small_model, async_runtime):
     sub-intervals of their cycles (they sum to at most the cycle total,
     whichever runtime attributed them — under the async runtime device_wait
     moves to the consumption boundary), the stall fraction is a fraction,
-    and the idle-gap series matches the cycle series sample for sample."""
+    and the logits pull and the sampling are phases of the sync cycle alone:
+    once per decode step there, never under the async runtime, which keeps
+    argmax and the finiteness guard on the device."""
     cfg, model, params = small_model
     engine = _run(model, params, _workload(cfg, n=3), trace=True,
                   async_runtime=async_runtime)
@@ -294,8 +305,77 @@ def test_engine_phase_breakdown_and_host_stall(small_model, async_runtime):
     assert sum(v for k, v in phases.items() if k != "cycle") \
         <= phases["cycle"] * 1.05
     assert 0.0 <= s["host_stall_fraction"] <= 1.0
-    assert engine.metrics.hist("device_idle_gap_s").n \
-        == engine.metrics.hist("cycle_s").n
+    recorded = {name: engine.metrics.hist(h).n
+                for name, h in PHASE_METRICS.items()}
+    steps = engine.stats["steps"]
+    assert steps > 0
+    for name in SYNC_ONLY_PHASES:
+        assert recorded[name] == (0 if async_runtime else steps), name
+        assert (phases[name] == 0.0) == async_runtime, name
+
+
+def _profiled_run(tmp_path, model, params, reqs):
+    """Serve ``reqs`` under the profiler (Python tracer off) and return the
+    engine and the ``serve.*`` / ``PjitFunction`` host events of the trace,
+    each as (name, start_ns, end_ns, stats)."""
+    from jax.profiler import ProfileData
+
+    engine = ServeEngine(model, params, slots=2, max_seq=128)
+    for r in reqs:
+        engine.submit(r)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        engine.run()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    events = [
+        (e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+        for plane in data.planes if plane.name == "/host:CPU"
+        for line in plane.lines for e in line.events
+        if e.name.startswith(("serve.", "PjitFunction("))
+    ]
+    return engine, events
+
+
+def test_engine_phases_are_profiler_spans(tmp_path, small_model):
+    """Every cycle and phase is a span on the profiler's clock: the sync
+    phases nest in their ``serve.cycle``, the decode program runs inside
+    ``serve.decode_dispatch`` under its own name, and the ``serve.prefill``
+    stats add up to the registry's prefill counters."""
+    cfg, model, params = small_model
+    engine, events = _profiled_run(tmp_path, model, params,
+                                   _workload(cfg, n=3))
+
+    def named(name):
+        return [e for e in events if e[0] == name]
+
+    def inside(ev, spans):
+        return any(a <= ev[1] and ev[2] <= b for _, a, b, _ in spans)
+
+    cycles = named(CYCLE_SPAN)
+    assert len(cycles) == engine._cycle
+    assert sorted(c[3]["cycle"] for c in cycles) == list(
+        range(1, engine._cycle + 1))
+    for name, span in PHASE_SPANS.items():
+        got = named(span)
+        assert got, name
+        assert all(inside(e, cycles) for e in got), name
+    assert len(named("serve.logits_pull")) == engine.stats["steps"]
+    dispatch = named("serve.decode_dispatch")
+    programs = named("PjitFunction(serve_decode_step)")
+    assert len(dispatch) == engine.stats["steps"]
+    assert all(inside(e, dispatch) for e in programs)
+    assert all(any(d[1] <= p[1] and p[2] <= d[2] for p in programs)
+               for d in dispatch)
+    assert not any("lambda" in e[0] for e in events)
+    prefills = named("serve.prefill")
+    assert len(prefills) == engine.stats["prefill_calls"]
+    assert sum(e[3]["tokens"] for e in prefills) \
+        == engine.stats["prefill_tokens"] > 0
+    assert sum(e[3]["computed"] for e in prefills) \
+        == engine.stats["prefill_tokens_computed"] \
+        > engine.stats["prefill_tokens"]
 
 
 def test_ttft_tpot_split_latency_series(small_model):
