@@ -1,17 +1,19 @@
 """Async overlapped serving runtime (docs/SERVING.md §13).
 
 The synchronous :class:`~repro.serve.engine.ServeEngine` cycle stops the
-world once per decoded token: dispatch the jitted step, ``block_until_ready``,
-pull the logits row, argmax on host, do all the scheduling bookkeeping, then
-dispatch again — the device idles through every host phase, which is exactly
-the ``host_stall_fraction`` PR 8's phase breakdown measures.  This module
+world once per decoded token: dispatch the jitted step and the greedy
+selection behind it, ``block_until_ready``, pull the ``[slots]`` token ids
+and finite flags, do all the scheduling bookkeeping, then dispatch again —
+the device idles through every host phase, which is exactly the
+``host_stall_fraction`` the engine's phase breakdown measures.  This module
 restructures that loop around three ideas (the MaxText/JetStream offline
 inference pattern):
 
 * **Device-resident token feed, bounded in-flight window.**  The overlapped
   decode step computes the next-token argmax (and a per-row finite flag) *on
-  device* and feeds it straight back into the next dispatch — no host round
-  trip on the critical path.  Dispatched steps enter a FIFO of at most
+  device* — the sync cycle's own ``serve_select_tokens`` body, fused into
+  the decode program — and feeds it straight back into the next dispatch:
+  no host round trip on the critical path.  Dispatched steps enter a FIFO of at most
   ``window`` in-flight records; the host consumes the *oldest* record (one
   small ``np.asarray`` transfer — the only blocking sync) while up to
   ``window - 1`` younger steps are still computing.  All per-token
@@ -52,8 +54,9 @@ real decode-state avals with the state and token buffers donated
 its buffers in place where the backend supports donation.
 
 Caveat: with ``guard_logits=False`` a ``poison_logits`` fault cannot
-reproduce the sync engine's NaN-row argmax on device (the device argmax
-sees the unpoisoned row), so bitwise fault parity requires the default
+reproduce the sync engine's token 0 for a poisoned row (the argmax of an
+all-NaN row): the device argmax sees the unpoisoned row and feeds it on
+without a host round trip, so bitwise fault parity requires the default
 ``guard_logits=True`` — the poisoned request retires ERRORED before its
 next token is ever used, identically in both runtimes.
 """
@@ -71,6 +74,7 @@ import numpy as np
 
 from repro.core import attention as catt
 from repro.serve import pages as pg
+from repro.serve.engine import serve_select_tokens
 
 
 class DeadlockError(RuntimeError):
@@ -250,9 +254,7 @@ class AsyncRunner:
             logits, st = model.decode_step(
                 p, s, t, impl=impl, quant_impl=quant_impl
             )
-            row = logits[:, 0]
-            nxt = jnp.argmax(row, axis=-1).astype(jnp.int32)
-            finite = jnp.isfinite(row).all(axis=-1)
+            nxt, finite = serve_select_tokens(logits)
             return nxt, finite, nxt[:, None], st
 
         self._tokens_dev = jnp.zeros((engine.slots, 1), jnp.int32)
@@ -515,9 +517,8 @@ class AsyncRunner:
                     logits, st = model.decode_step(
                         p, s, t, impl=impl, quant_impl=quant_impl
                     )
-                row = logits[:, 0]
-                nxt = jnp.argmax(row, axis=-1).astype(jnp.int32)
-                return nxt, jnp.isfinite(row).all(axis=-1), nxt[:, None], st
+                nxt, finite = serve_select_tokens(logits)
+                return nxt, finite, nxt[:, None], st
 
             self._astep_sk = jax.jit(serve_async_decode_step_splitkv,
                                      donate_argnums=(1, 2))

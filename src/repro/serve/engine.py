@@ -86,8 +86,11 @@ lifecycle counter lives in a shared :class:`MetricsRegistry` (the ``stats``
 property keeps the historical dict view), each cycle is decomposed into
 timed phases — ``schedule``, ``prefill``, ``decode_dispatch``,
 ``device_wait`` (an explicit ``jax.block_until_ready`` boundary),
-``logits_pull`` (the device→host copy of the logits rows), ``sample``
-(argmax and the finiteness guard) and ``advance`` — feeding per-phase
+``logits_pull`` (the device→host copy of the ``[slots]`` token ids and
+finite flags that the ``jit_serve_select_tokens`` program picks on the
+device behind the decode step; the logits rows never leave the device),
+``sample`` (the ``poison_logits`` fault hook and the guard over those
+flags) and ``advance`` — feeding per-phase
 histograms plus the derived ``host_stall_fraction``, and token latencies
 split into TTFT (submission → first token, queue wait included) and TPOT
 (inter-token) series.  Every cycle and every phase block is also a
@@ -156,6 +159,9 @@ TIMING_SUMMARY_KEYS = frozenset({
 #: pre-registry dict interface)
 STAT_COUNTERS = (
     "decoded_tokens", "steps", "prefill_calls", "splitkv_steps",
+    # bytes the sync cycle's logits_pull fetched to the host: the selected
+    # ids and finite flags, slots x 5 per decode step
+    "decode_pull_bytes",
     "prefill_tokens", "prefill_tokens_saved", "cow_copies",
     # tokens the prefill programs computed (slots x bucket length; the
     # prompt length in the exact-length shim), of which prefill_tokens were
@@ -177,6 +183,17 @@ STAT_COUNTERS = (
     # after their request already left the slot (retirement/preemption lag)
     "completions_enqueued", "discarded_steps",
 )
+
+
+def serve_select_tokens(logits):
+    """Greedy selection, the one definition every runtime shares: from the
+    decode step's ``[slots, 1, vocab]`` logits, each slot's next token
+    (``[slots]`` int32 argmax) and whether its row is finite (``[slots]``
+    bool).  The sync cycle jits it as its own program behind the decode
+    step; the async runtime calls it inside its fused decode programs."""
+    row = logits[:, 0]
+    return (jnp.argmax(row, axis=-1).astype(jnp.int32),
+            jnp.isfinite(row).all(axis=-1))
 
 
 def _percentile(xs: list[float], q: float) -> float:
@@ -418,14 +435,16 @@ class ServeEngine:
 
         self._impl = impl
         self._quant_impl = quant_impl
-        # one jitted decode step (static shapes) shared by every family, and
-        # the host-side next-token buffer (one device->host pull per cycle);
-        # every program is a named function, so a profile names it
-        # jit_serve_<...>
+        # one jitted decode step (static shapes) shared by every family, the
+        # greedy selection program queued behind it (the cycle pulls its
+        # [slots] ids and flags, never the logits rows), and the host-side
+        # next-token buffer; every program is a named function, so a profile
+        # names it jit_serve_<...>
         def serve_decode_step(p, s, t):
             return model.decode_step(p, s, t, impl=impl, quant_impl=quant_impl)
 
         self._step = jax.jit(serve_decode_step)
+        self._select = jax.jit(serve_select_tokens)
         self._step_splitkv = None
         if mesh is not None and splitkv_axis not in getattr(mesh, "axis_names", ()):
             raise ValueError(
@@ -857,31 +876,41 @@ class ServeEngine:
             logits, self.state = step_fn(
                 self.params, self.state, jnp.asarray(self.tokens)
             )
+            nxt, finite = self._select(logits)
+            # queue both device->host copies now: they start as soon as the
+            # selection finishes, not when the host comes to read them
+            nxt.copy_to_host_async()
+            finite.copy_to_host_async()
         # one host sync per cycle: the explicit block_until_ready boundary
         # separates waiting on device compute from the host work after it —
-        # the pull of the logits rows, the sampling and guard over them, and
-        # the token accounting (the phase breakdown is how host-stall
-        # fraction gets measured)
+        # the pull of the selected ids and finite flags (the logits rows stay
+        # on the device), the fault hook and guard over them, and the token
+        # accounting (the phase breakdown is how host-stall fraction gets
+        # measured)
         with self._phase("device_wait"):
-            logits = jax.block_until_ready(logits)
-        with self._phase("logits_pull"):
-            rows = np.array(np.asarray(logits)[:, 0])
+            nxt, finite = jax.block_until_ready((nxt, finite))
+        pull_bytes = nxt.nbytes + finite.nbytes
+        with self._phase("logits_pull", bytes=pull_bytes):
+            nxt, finite = np.array(nxt), np.asarray(finite)
+        self.metrics.inc("decode_pull_bytes", pull_bytes)
         with self._phase("sample"):
+            poisoned = set()
             if self.faults is not None:
                 for slot, req in list(self.sched.active.items()):
                     if self.faults.fires(
                         "poison_logits", cycle=self._cycle, uid=req.uid,
                         progress=len(req.out_tokens),
                     ):
-                        rows[slot] = np.nan
-            nxt = np.argmax(rows, axis=-1)
+                        # a poisoned row reads as all-NaN: argmax gives 0
+                        poisoned.add(slot)
+                        nxt[slot] = 0
             bad: dict[int, str] = {}
             if self.guard_logits:
-                finite = np.isfinite(rows).all(axis=-1)
+                vocab = logits.shape[-1]
                 for slot in self.sched.active:
-                    if not finite[slot]:
+                    if slot in poisoned or not finite[slot]:
                         bad[slot] = "non-finite logits row"
-                    elif not 0 <= int(nxt[slot]) < rows.shape[-1]:
+                    elif not 0 <= int(nxt[slot]) < vocab:
                         bad[slot] = f"invalid next token id {int(nxt[slot])}"
         with self._phase("advance"):
             self.metrics.inc("steps")

@@ -12,6 +12,7 @@ from repro.configs.base import smoke_config
 from repro.core import qcache
 from repro.models.zoo import build_model
 from repro.serve.engine import Request, ServeEngine
+from repro.serve.faults import FaultPlan
 from repro.serve.pages import PagePool
 from repro.serve.scheduler import Phase, Scheduler, bucket_for
 
@@ -223,6 +224,87 @@ def test_paged_engine_matches_dense_oracle(small_model):
     for i, (r, w) in enumerate(zip(reqs, want)):
         assert r.done
         assert r.out_tokens == w, f"request {i} diverged from dense oracle"
+
+
+# --------------------------------------------------------------------------
+# Greedy selection on the device: the sync cycle pulls [slots] ids and flags
+# --------------------------------------------------------------------------
+
+def _requests(cfg, n, seed, max_new=6):
+    rng = np.random.default_rng(seed)
+    return [Request(uid=i, prompt=rng.integers(0, cfg.vocab, 40).astype(np.int32),
+                    max_new_tokens=max_new) for i in range(n)]
+
+
+def test_selection_program_returns_slot_vectors_only(small_model):
+    cfg, model, params = small_model
+    engine = ServeEngine(model, params, slots=3, max_seq=64)
+    logits, _ = jax.eval_shape(engine._step, params, engine.state,
+                               jnp.asarray(engine.tokens))
+    assert logits.shape == (3, 1, cfg.vocab)
+    nxt, finite = jax.eval_shape(engine._select, logits)
+    assert (nxt.shape, nxt.dtype) == ((3,), jnp.int32)
+    assert (finite.shape, finite.dtype) == ((3,), jnp.bool_)
+
+
+def test_sync_tokens_are_host_argmax_of_decode_logits(small_model):
+    """The oracle of the device-side selection: the token the sync cycle
+    feeds after each step is the host ``np.argmax`` of that step's
+    ``model.decode_step`` logits row, slot by slot."""
+    cfg, model, params = small_model
+    engine = ServeEngine(model, params, slots=2, max_seq=128)
+    step = engine._step
+    seen = []  # (request, tokens it had before the step, host argmax)
+
+    def recording(p, s, t):
+        logits, st = step(p, s, t)
+        host = np.argmax(np.asarray(logits)[:, 0], axis=-1)
+        seen.extend((req, len(req.out_tokens), int(host[slot]))
+                    for slot, req in engine.sched.active.items())
+        return logits, st
+
+    engine._step = recording
+    reqs = _requests(cfg, 3, seed=5)
+    for r in reqs:
+        engine.submit(r)
+    engine.run()
+    assert all(r.done for r in reqs)
+    checked = [(req.out_tokens[k + 1], tok) for req, k, tok in seen
+               if k + 1 < len(req.out_tokens)]
+    assert len(checked) == sum(len(r.out_tokens) - 1 for r in reqs)
+    assert all(got == want for got, want in checked)
+
+
+@pytest.mark.parametrize("guard", [True, False], ids=["guard", "no_guard"])
+def test_poisoned_slot_outcome(small_model, guard):
+    """A ``poison_logits`` fault stands for an all-NaN logits row: with the
+    guard on its request retires ERRORED keeping the token that step fed;
+    with it off the slot's next token is 0, what argmax of such a row
+    gives.  The other request's stream is untouched either way."""
+    cfg, model, params = small_model
+
+    def serve(plan):
+        engine = ServeEngine(model, params, slots=2, max_seq=128,
+                             guard_logits=guard, faults=plan)
+        reqs = _requests(cfg, 2, seed=11)
+        for r in reqs:
+            engine.submit(r)
+        engine.run()
+        return reqs
+
+    clean = serve(None)
+    plan = FaultPlan(seed=3, fire_at_token={"poison_logits": {(1, 2)}})
+    hit = serve(plan)
+    assert plan.fired("poison_logits") == 1
+    assert hit[0].out_tokens == clean[0].out_tokens
+    if guard:
+        assert hit[1].phase == Phase.ERRORED
+        assert "non-finite logits row" in hit[1].error
+        assert hit[1].out_tokens == clean[1].out_tokens[:3]
+    else:
+        assert hit[1].done
+        assert hit[1].out_tokens[:3] == clean[1].out_tokens[:3]
+        assert hit[1].out_tokens[3] == 0
 
 
 # --------------------------------------------------------------------------

@@ -36,7 +36,8 @@ from repro.serve.engine import (
 from repro.serve.telemetry import Histogram
 
 BLOCK = 32
-#: phases only the sync cycle runs: it pulls the logits rows to the host
+#: phases only the sync cycle runs: it pulls the token ids and finite flags
+#: its selection program picked on the device
 SYNC_ONLY_PHASES = {"logits_pull", "sample"}
 
 
@@ -276,7 +277,8 @@ def test_engine_trace_schema_valid_and_lifecycle_complete(small_model,
         assert req_names.count(span) == 2 * len(reqs), span  # B + E each
     assert req_names.count("done") == len(reqs)
     # per-cycle phase records are present for every phase the runtime runs
-    # (the async runtime keeps the logits on the device: no pull, no sample)
+    # (the async runtime consumes ids and flags fused into its decode
+    # program: no pull, no sample)
     phase_names = {e["name"] for e in engine.tracer.events
                    if e["cat"] == "engine"}
     ran = set(PHASE_METRICS) - (SYNC_ONLY_PHASES if async_runtime else set())
@@ -291,9 +293,9 @@ def test_engine_phase_breakdown_and_host_stall(small_model, async_runtime):
     sub-intervals of their cycles (they sum to at most the cycle total,
     whichever runtime attributed them — under the async runtime device_wait
     moves to the consumption boundary), the stall fraction is a fraction,
-    and the logits pull and the sampling are phases of the sync cycle alone:
-    once per decode step there, never under the async runtime, which keeps
-    argmax and the finiteness guard on the device."""
+    and the pull of the selected ids and the sampling are phases of the sync
+    cycle alone: once per decode step there, never under the async runtime,
+    which consumes the ids and flags its fused decode program returns."""
     cfg, model, params = small_model
     engine = _run(model, params, _workload(cfg, n=3), trace=True,
                   async_runtime=async_runtime)
@@ -376,6 +378,32 @@ def test_engine_phases_are_profiler_spans(tmp_path, small_model):
     assert sum(e[3]["computed"] for e in prefills) \
         == engine.stats["prefill_tokens_computed"] \
         > engine.stats["prefill_tokens"]
+
+
+def test_logits_pull_fetches_only_ids_and_flags(tmp_path, small_model):
+    """The sync cycle picks the greedy token on the device
+    (``serve_select_tokens``, dispatched inside ``serve.decode_dispatch``
+    behind the decode program) and pulls ``[slots]`` int32 ids and bool
+    finite flags: 5 bytes a slot per decode step, counted by
+    ``decode_pull_bytes`` and carried as the ``bytes`` stat of every
+    ``serve.logits_pull`` span."""
+    cfg, model, params = small_model
+    engine, events = _profiled_run(tmp_path, model, params,
+                                   _workload(cfg, n=3))
+    per_step = engine.slots * 5
+    steps = engine.stats["steps"]
+    assert steps > 0
+    assert engine.stats["decode_pull_bytes"] == steps * per_step
+    pulls = [e for e in events if e[0] == "serve.logits_pull"]
+    assert [e[3]["bytes"] for e in pulls] == [per_step] * steps
+    dispatch = [e for e in events if e[0] == "serve.decode_dispatch"]
+    selects = [e for e in events
+               if e[0] == "PjitFunction(serve_select_tokens)"]
+    assert len(dispatch) == steps
+    assert all(any(d[1] <= p[1] and p[2] <= d[2] for d in dispatch)
+               for p in selects)
+    assert all(any(d[1] <= p[1] and p[2] <= d[2] for p in selects)
+               for d in dispatch)
 
 
 def test_ttft_tpot_split_latency_series(small_model):
