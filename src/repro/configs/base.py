@@ -39,6 +39,13 @@ class ArchConfig:
     mixer: str = "attn"  # attn | mla | mamba2 | xlstm
     rope: bool = True
     rope_theta: float = 1.0e4
+    # YaRN rotary scaling (DeepSeek-V3 ``rope_scaling``); off at factor 1
+    yarn_factor: float = 1.0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    yarn_original_max_pos: int = 4096
     mrope_sections: tuple | None = None
     qk_norm: bool = False
     attn_bias: bool = False
@@ -62,6 +69,21 @@ class ArchConfig:
     router_score: str = "softmax"
     router_norm_topk: bool = False
     aux_loss_weight: float = 1.0e-2
+    # group-limited routing (DeepSeek-V3 ``noaux_tc``): the n_experts are cut
+    # into router_groups groups, each scored by the sum of its two best
+    # choice scores; the top_k experts come from the router_topk_groups best
+    # groups.  router_bias adds a per-expert correction bias to the choice
+    # scores only (never to the weights); routed_scaling multiplies the
+    # (normalised) weights.
+    router_groups: int = 0
+    router_topk_groups: int = 0
+    router_bias: bool = False
+    routed_scaling: float = 1.0
+    # expert share (expert parallelism): each layer's n_experts are divided
+    # over expert_chips chips; this model holds the n_experts / expert_chips
+    # experts of rank expert_rank and computes only their part of the layer
+    expert_chips: int = 1
+    expert_rank: int = 0
 
     # MLA (DeepSeek)
     q_lora: int = 0
@@ -110,6 +132,11 @@ class ArchConfig:
     @property
     def g_q(self) -> int:
         return self.n_heads // max(1, self.n_kv_heads)
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts this model holds: its rank's share of n_experts."""
+        return self.n_experts // max(1, self.expert_chips)
 
     @property
     def padded_vocab(self) -> int:

@@ -1,6 +1,8 @@
 """Shared model layers: norms, RoPE / M-RoPE, MLPs, embeddings."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -49,15 +51,45 @@ def rope_freqs(head_dim: int, theta: float = 10000.0):
     return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
 
 
-def apply_rope(x, positions, *, theta: float = 10000.0, sections=None):
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention-temperature factor ``0.1 * mscale * ln(factor) + 1``
+    (1 at factor <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, factor: float, beta_fast: float,
+               beta_slow: float, original_max_pos: int):
+    """YaRN inverse frequencies [head_dim/2] (DeepSeek-V3's
+    ``DeepseekV3YarnRotaryEmbedding``): bands that turn more than
+    ``beta_fast`` times over the original context keep their frequency,
+    bands that turn fewer than ``beta_slow`` times are divided by
+    ``factor``, and a linear ramp blends the two in between."""
+    def correction_dim(rotations):
+        return (head_dim * math.log(original_max_pos / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    extra = rope_freqs(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope(x, positions, *, theta: float = 10000.0, sections=None,
+               freqs=None, scale: float = 1.0):
     """x: [B, S, H, d]; positions: [B, S] int (or [3, B, S] for M-RoPE).
 
     M-RoPE (Qwen2-VL §3): the rotary frequency bands are split into
     ``sections = (t, h, w)`` groups (summing to d/2); each group consumes its
     own position stream — temporal for text, (h, w) grid for image patches.
+    ``freqs`` ([d/2]) replaces the plain ``theta`` frequencies (YaRN) and
+    ``scale`` multiplies cos and sin (YaRN's mscale ratio).
     """
     b, s, h, d = x.shape
-    freqs = rope_freqs(d, theta)  # [d/2]
+    if freqs is None:
+        freqs = rope_freqs(d, theta)  # [d/2]
     if sections is None:
         ang = positions.astype(jnp.float32)[:, :, None] * freqs[None, None, :]
     else:
@@ -71,6 +103,8 @@ def apply_rope(x, positions, *, theta: float = 10000.0, sections=None):
         ang = jnp.concatenate(parts, axis=-1)  # [B, S, d/2]
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -6,6 +6,18 @@ against the cached latent stream ``[c_kv ; k_rope]``.  BitDecoding applies to
 the latent cache itself (shared_kv mode): one quantized stream feeds both the
 score and value sides, and g_q = n_heads (128) — the query transformation's
 best case, a fully-populated MXU M dimension.
+
+The latent stream is stored lane-aligned: ``[c_kv ; k_rope ; 0]`` padded to
+:func:`latent_width` (a multiple of 128; 640 for DeepSeek-V3's 512 + 64), so
+the pools, the residual tail and the flush are allocated at the width the
+TPU kernels tile and no call pads them again.  The zero channels quantize to
+zero and the absorbed query carries zeros there.
+
+Rotary embedding covers the ``qk_rope`` channels of q and of the latent,
+with YaRN's frequencies and softmax-scale factor where the config sets
+``yarn_factor`` (DeepSeek-V3: 40).  The rope channels use the rotate-half
+layout; the published checkpoint's interleaved pairs are a fixed
+permutation of the ``q_up`` and ``kv_down`` rope columns.
 """
 from __future__ import annotations
 
@@ -34,12 +46,46 @@ def mla_def(cfg) -> dict:
     }
 
 
+def latent_width(cfg) -> int:
+    """Stored width of one latent token: kv_lora + qk_rope, lane-aligned."""
+    return -(-(cfg.kv_lora + cfg.qk_rope) // 128) * 128
+
+
+def _pad_latent(cfg, x):
+    """Zero channels after ``[c_kv ; k_rope]`` (or the absorbed query) up to
+    :func:`latent_width`."""
+    pad = latent_width(cfg) - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
+
+
+def _rope(cfg, x, positions):
+    """RoPE over the last dim of ``x [B,S,H,qk_rope]``; YaRN's frequencies
+    and cos/sin factor when ``cfg.yarn_factor > 1``."""
+    if cfg.yarn_factor <= 1:
+        return layers.apply_rope(x, positions, theta=cfg.rope_theta)
+    freqs = layers.yarn_freqs(cfg.qk_rope, cfg.rope_theta, cfg.yarn_factor,
+                              cfg.yarn_beta_fast, cfg.yarn_beta_slow,
+                              cfg.yarn_original_max_pos)
+    scale = (layers.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+             / layers.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    return layers.apply_rope(x, positions, freqs=freqs, scale=scale)
+
+
+def sm_scale(cfg) -> float:
+    """Softmax scale 1/sqrt(qk_nope + qk_rope), times YaRN's mscale^2
+    (``mscale_all_dim``) when it is on."""
+    s = 1.0 / (cfg.qk_nope + cfg.qk_rope) ** 0.5
+    if cfg.yarn_factor > 1 and cfg.yarn_mscale_all_dim:
+        s *= layers.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return s
+
+
 def _latent(p, cfg, x, positions):
     """x [B,S,d] -> (c_kv [B,S,kv_lora], k_rope [B,S,qk_rope]) with RoPE."""
     kvr = jnp.einsum("bsd,dl->bsl", x, p["kv_down"])
     c_kv = layers.rmsnorm(p["kv_norm"], kvr[..., : cfg.kv_lora])
     k_rope = kvr[..., cfg.kv_lora :]
-    k_rope = layers.apply_rope(k_rope[:, :, None, :], positions, theta=cfg.rope_theta)[:, :, 0]
+    k_rope = _rope(cfg, k_rope[:, :, None, :], positions)[:, :, 0]
     return c_kv, k_rope
 
 
@@ -47,7 +93,7 @@ def _queries(p, cfg, x, positions):
     c_q = layers.rmsnorm(p["q_norm"], jnp.einsum("bsd,dl->bsl", x, p["q_down"]))
     q = jnp.einsum("bsl,lhk->bshk", c_q, p["q_up"])
     q_nope = q[..., : cfg.qk_nope]
-    q_rope = layers.apply_rope(q[..., cfg.qk_nope :], positions, theta=cfg.rope_theta)
+    q_rope = _rope(cfg, q[..., cfg.qk_nope :], positions)
     return q_nope, q_rope
 
 
@@ -75,16 +121,15 @@ def mla_train(p, cfg, x, positions):
     v = constrain(v, ("pod", "data"), None, "model", None)
     out = catt.blockwise_attention(
         q, k, v, causal=True,
-        sm_scale=1.0 / (cfg.qk_nope + cfg.qk_rope) ** 0.5,
-        block_k=cfg.attn_block_k,
+        sm_scale=sm_scale(cfg), block_k=cfg.attn_block_k,
     )
     return jnp.einsum("bshk,hkd->bsd", out.astype(x.dtype), p["wo"])
 
 
 def mla_init_cache(cfg, batch: int, max_seq: int, *, block_align=None):
-    """Latent cache: one KV 'head' of width kv_lora + qk_rope, shared_kv."""
+    """Latent cache: one KV 'head' of :func:`latent_width`, shared_kv."""
     return qcache.init_cache(
-        batch, 1, cfg.kv_lora + cfg.qk_rope, max_seq,
+        batch, 1, latent_width(cfg), max_seq,
         bits=cfg.kv_bits, block_n=cfg.kv_block, k_gran="channel", shared_kv=True,
         block_align=block_align,
     )
@@ -95,7 +140,7 @@ def mla_init_paged_cache(cfg, n_pages: int, batch: int, nb_max: int):
     latent stream lives in shared ``shared_kv`` page pools — no V-side pools
     at all — and decodes through ``kernels/paged_bitdecode``'s latent walk."""
     return qcache.init_paged_cache(
-        n_pages, batch, 1, cfg.kv_lora + cfg.qk_rope, nb_max,
+        n_pages, batch, 1, latent_width(cfg), nb_max,
         bits=cfg.kv_bits, block_n=cfg.kv_block, k_gran="channel",
         shared_kv=True,
     )
@@ -112,7 +157,7 @@ def _expand_latent(p, cfg, lat):
     """
     b, t = lat.shape[:2]
     c = lat[..., : cfg.kv_lora]
-    r = lat[..., cfg.kv_lora :]
+    r = lat[..., cfg.kv_lora : cfg.kv_lora + cfg.qk_rope]
     k_nope = jnp.einsum("btl,lhk->bthk", c, p["k_up"])
     k = jnp.concatenate(
         [k_nope,
@@ -155,10 +200,10 @@ def mla_prefill_cache(p, cfg, x, positions, max_seq: int, *, quant_impl="auto",
         k_prior, v_prior = _expand_latent(p, cfg, prior[0][:, :, 0, :])
         out = catt.prefix_suffix_attention(
             q, k, v, k_prior, v_prior, prior_len,
-            sm_scale=1.0 / (cfg.qk_nope + cfg.qk_rope) ** 0.5,
+            sm_scale=sm_scale(cfg),
         )
         out = jnp.einsum("bshk,hkd->bsd", out.astype(x.dtype), p["wo"])
-    lat = jnp.concatenate([c_kv, k_rope], axis=-1)[:, None]  # [B,1,S,kvl+dr]
+    lat = _pad_latent(cfg, jnp.concatenate([c_kv, k_rope], axis=-1))[:, None]
     cache = mla_init_cache(cfg, x.shape[0], max_seq, block_align=block_align)
     cache = qcache.prefill(cache, lat, None, lengths=lengths, quant_impl=quant_impl)
     return out, cache
@@ -169,14 +214,16 @@ def mla_decode(p, cfg, x, positions, cache, *, impl="auto", quant_impl="auto"):
     b = x.shape[0]
     q_nope, q_rope = _queries(p, cfg, x, positions)  # [B,1,h,*]
     c_kv, k_rope = _latent(p, cfg, x, positions)
-    lat = jnp.concatenate([c_kv, k_rope], axis=-1)[:, None]  # [B, H=1, S=1, kvl+dr]
-    # absorb: q_eff = [q_nope @ W_uk ; q_rope]  -> width kv_lora + qk_rope
-    q_lat = jnp.einsum("bshk,lhk->bshl", q_nope, p["k_up"])  # [B,1,h,kv_lora]
-    q_eff = jnp.concatenate([q_lat, q_rope], axis=-1)
+    # [B, H=1, S=1, latent_width]
+    lat = _pad_latent(cfg, jnp.concatenate([c_kv, k_rope], axis=-1))[:, None]
+    with jax.named_scope("mla.absorb"):
+        # absorb: q_eff = [q_nope @ W_uk ; q_rope ; 0]  -> latent_width
+        q_lat = jnp.einsum("bshk,lhk->bshl", q_nope, p["k_up"])  # [B,1,h,kv_lora]
+        q_eff = _pad_latent(cfg, jnp.concatenate([q_lat, q_rope], axis=-1))
     out_lat, cache = catt.decode_append_attention(
         q_eff, cache, lat, None, quant_impl=quant_impl,
-        sm_scale=1.0 / (cfg.qk_nope + cfg.qk_rope) ** 0.5,
-        d_v=cfg.kv_lora, impl=impl,
+        sm_scale=sm_scale(cfg), d_v=cfg.kv_lora, impl=impl,
     )  # [B,1,h,kv_lora]
-    out = jnp.einsum("bshl,lhk->bshk", out_lat.astype(x.dtype), p["v_up"])
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), cache
+    with jax.named_scope("mla.absorb"):
+        out = jnp.einsum("bshl,lhk->bshk", out_lat.astype(x.dtype), p["v_up"])
+        return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), cache

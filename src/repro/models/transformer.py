@@ -67,6 +67,25 @@ def _mrope_decode_positions(cfg, pos):
     return jnp.broadcast_to(t[None, :, None], (3, pos.shape[0], 1))
 
 
+def _scan_params(kind, stack):
+    """A stack's params as its layer scan takes them.  An MoE stack's held
+    experts (``moe/wi``, ``moe/wo``) stay out of the scanned operands and are
+    read whole by every layer, which picks its own through ``moe/layer``: a
+    scanned slice would be copied into a buffer of its own for the grouped
+    matmul kernel, every layer of every step.  Returns (scanned, whole)."""
+    if kind != "moe":
+        return stack, None
+    moe_p = dict(stack["moe"])
+    whole = {k: moe_p.pop(k) for k in ("wi", "wo")}
+    moe_p["layer"] = jnp.arange(whole["wi"].shape[0], dtype=jnp.int32)
+    return dict(stack, moe=moe_p), whole
+
+
+def _layer_params(lp, whole):
+    """One layer's params inside the scan (see :func:`_scan_params`)."""
+    return lp if whole is None else dict(lp, moe=dict(lp["moe"], **whole))
+
+
 class DecoderLM:
     """Dense / MoE / MLA decoder-only LM (optionally with VLM patch stub)."""
 
@@ -238,7 +257,7 @@ class DecoderLM:
         x = x + a
         if kind != "none":
             h2 = layers.apply_norm(cfg.norm, p["ln2"], x, plus_one=cfg.rms_plus_one)
-            f = moe.moe_ffn(p["moe"], cfg, h2)[0] if kind == "moe" else layers.mlp(p["mlp"], h2, cfg.act)
+            f = moe.moe_share(p["moe"], cfg, h2)[0] if kind == "moe" else layers.mlp(p["mlp"], h2, cfg.act)
             x = x + f
         return x, cache
 
@@ -288,42 +307,41 @@ class DecoderLM:
         cache_lengths = None if lengths is None else lengths + n_lead
         caches = []
         for i, (kind, _) in enumerate(self.stacks):
+            stack_p, whole = _scan_params(kind, params[f"stack_{i}"])
             if prior is None:
-                def body(x, lp, _kind=kind):
+                def body(x, lp, _kind=kind, _whole=whole):
                     x, cache = self._block_prefill(
-                        lp, _kind, x, positions, max_seq, cache_lengths,
-                        block_align, quant_impl=quant_impl,
+                        _layer_params(lp, _whole), _kind, x, positions, max_seq,
+                        cache_lengths, block_align, quant_impl=quant_impl,
                     )
                     return x, cache
 
-                x, cache_stack = lax.scan(body, x, params[f"stack_{i}"])
+                x, cache_stack = lax.scan(body, x, stack_p)
             elif prior[i][1] is None:  # latent prior (MLA shared_kv pools)
-                def body_l(x, xs, _kind=kind):
+                def body_l(x, xs, _kind=kind, _whole=whole):
                     lp, kp = xs
                     x, cache = self._block_prefill(
-                        lp, _kind, x, positions, max_seq, cache_lengths,
+                        _layer_params(lp, _whole), _kind, x, positions, max_seq,
+                        cache_lengths,
                         block_align, prior=(kp, None), prior_len=prior_len,
                         quant_impl=quant_impl,
                     )
                     return x, cache
 
-                x, cache_stack = lax.scan(
-                    body_l, x, (params[f"stack_{i}"], prior[i][0])
-                )
+                x, cache_stack = lax.scan(body_l, x, (stack_p, prior[i][0]))
             else:
-                def body_p(x, xs, _kind=kind):
+                def body_p(x, xs, _kind=kind, _whole=whole):
                     lp, kp, vp = xs
                     x, cache = self._block_prefill(
-                        lp, _kind, x, positions, max_seq, cache_lengths,
+                        _layer_params(lp, _whole), _kind, x, positions, max_seq,
+                        cache_lengths,
                         block_align, prior=(kp, vp), prior_len=prior_len,
                         quant_impl=quant_impl,
                     )
                     return x, cache
 
                 kp_i, vp_i = prior[i]
-                x, cache_stack = lax.scan(
-                    body_p, x, (params[f"stack_{i}"], kp_i, vp_i)
-                )
+                x, cache_stack = lax.scan(body_p, x, (stack_p, kp_i, vp_i))
             caches.append(cache_stack)
         if lengths is None:
             logits = self._logits(params, x[:, -1:])
@@ -360,10 +378,20 @@ class DecoderLM:
                     block_align=align,
                 )
             caches.append(jax.tree.map(lambda a: jnp.broadcast_to(a, (n, *a.shape)), one))
-        return {
+        return self._with_moe_counts({
             "caches": caches,
             "pos": jnp.zeros((batch_size,), jnp.int32),
-        }
+        })
+
+    def _with_moe_counts(self, state, counts=None):
+        """A decode state of a model with routed experts carries the last
+        step's ``moe_counts`` (int32 [2]: pairs whose expert this model
+        holds, held experts with at least one pair, summed over the MoE
+        layers); other models' states have no such entry."""
+        if any(kind == "moe" for kind, _ in self.stacks):
+            state["moe_counts"] = (jnp.zeros((2,), jnp.int32) if counts is None
+                                   else counts)
+        return state
 
     def paged_spec(self) -> PagedSpec | None:
         """Declared cache family (see repro.models.family).  Plain attention
@@ -376,7 +404,7 @@ class DecoderLM:
         if cfg.mixer == "mla":
             return PagedSpec(
                 paged=True, block_n=cfg.kv_block, n_kv_heads=1,
-                d_k=cfg.kv_lora + cfg.qk_rope, d_v=cfg.kv_lora,
+                d_k=mla.latent_width(cfg), d_v=cfg.kv_lora,
                 shared_kv=True, page_layers=n_layers, supports_prior=True,
             )
         if cfg.mixer == "attn":
@@ -412,10 +440,10 @@ class DecoderLM:
                     bits=cfg.kv_bits, block_n=cfg.kv_block, k_gran=cfg.kv_gran,
                 )
             caches.append(jax.tree.map(lambda a: jnp.broadcast_to(a, (n, *a.shape)), one))
-        return {
+        return self._with_moe_counts({
             "caches": caches,
             "pos": jnp.zeros((batch_size,), jnp.int32),
-        }
+        })
 
     def _block_decode(self, p, kind, x, positions, cache, impl, quant_impl):
         cfg = self.cfg
@@ -434,14 +462,19 @@ class DecoderLM:
             f = layers.mlp(p["mlp"], h, cfg.act) if kind == "mlp" else 0.0
             return x + a + f, cache
         x = x + a
+        if kind == "moe":
+            h2 = layers.apply_norm(cfg.norm, p["ln2"], x, plus_one=cfg.rms_plus_one)
+            f, counts = moe.moe_share(p["moe"], cfg, h2, impl=impl)
+            return x + f, cache, counts
         if kind != "none":
             h2 = layers.apply_norm(cfg.norm, p["ln2"], x, plus_one=cfg.rms_plus_one)
-            f = moe.moe_ffn(p["moe"], cfg, h2)[0] if kind == "moe" else layers.mlp(p["mlp"], h2, cfg.act)
-            x = x + f
+            x = x + layers.mlp(p["mlp"], h2, cfg.act)
         return x, cache
 
     def decode_step(self, params, state, tokens, *, impl="auto", quant_impl="auto"):
-        """tokens [B, 1] -> (logits [B,1,V], new state)."""
+        """tokens [B, 1] -> (logits [B,1,V], new state).  A model with
+        routed experts also returns the step's ``moe_counts`` in the state
+        (see :meth:`_with_moe_counts`)."""
         cfg = self.cfg
         x = layers.embed(params["embed"], tokens)
         if cfg.embed_scale:
@@ -452,18 +485,39 @@ class DecoderLM:
         else:
             positions = pos[:, None]
         new_caches = []
+        counts = None
         for i, (kind, _) in enumerate(self.stacks):
-            def body(x, xs, _kind=kind):
-                lp, cache = xs
-                x, cache = self._block_decode(
-                    lp, _kind, x, positions, cache, impl, quant_impl
-                )
-                return x, cache
+            if kind == "moe":
+                stack_p, whole = _scan_params(kind, params[f"stack_{i}"])
+                xs = (stack_p, state["caches"][i])
 
-            x, cache_stack = lax.scan(body, x, (params[f"stack_{i}"], state["caches"][i]))
+                def body_moe(carry, xs, _whole=whole):
+                    x, c = carry
+                    lp, cache = xs
+                    x, cache, c_l = self._block_decode(
+                        _layer_params(lp, _whole), "moe", x, positions, cache,
+                        impl, quant_impl
+                    )
+                    return (x, c + c_l), cache
+
+                if counts is None:
+                    counts = jnp.zeros((2,), jnp.int32)
+                (x, counts), cache_stack = lax.scan(body_moe, (x, counts), xs)
+            else:
+                xs = (params[f"stack_{i}"], state["caches"][i])
+
+                def body(x, xs, _kind=kind):
+                    lp, cache = xs
+                    x, cache = self._block_decode(
+                        lp, _kind, x, positions, cache, impl, quant_impl
+                    )
+                    return x, cache
+
+                x, cache_stack = lax.scan(body, x, xs)
             new_caches.append(cache_stack)
         logits = self._logits(params, x)
-        return logits, {"caches": new_caches, "pos": pos + 1}
+        return logits, self._with_moe_counts(
+            {"caches": new_caches, "pos": pos + 1}, counts)
 
 
 class HybridLM:

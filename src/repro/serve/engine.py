@@ -96,8 +96,11 @@ split into TTFT (submission → first token, queue wait included) and TPOT
 (inter-token) series.  Every cycle and every phase block is also a
 ``jax.profiler.TraceAnnotation`` span (``serve.cycle``, ``serve.<phase>``;
 ``serve.prefill`` carries the ``tokens`` it was asked for and the tokens
-it ``computed``), so a profiler trace names each stretch of device idle
-time by the engine phase the host was in.  ``trace=True`` additionally
+it ``computed``; for a model with routed experts, each decode cycle's
+``serve.advance`` carries the step's ``moe_pairs_here`` and
+``moe_experts_hit``, pulled beside the token ids), so a profiler trace
+names each stretch of device idle time by the engine phase the host was
+in.  ``trace=True`` additionally
 records a structured event log (request lifecycle spans, COW / preemption
 / speculative / audit / fault instants, per-phase complete events) that
 exports as JSONL or Chrome ``trace_event`` JSON for Perfetto.  All of it is
@@ -160,7 +163,8 @@ TIMING_SUMMARY_KEYS = frozenset({
 STAT_COUNTERS = (
     "decoded_tokens", "steps", "prefill_calls", "splitkv_steps",
     # bytes the sync cycle's logits_pull fetched to the host: the selected
-    # ids and finite flags, slots x 5 per decode step
+    # ids and finite flags, slots x 5 per decode step (and the 8 B of
+    # moe_counts for a model with routed experts)
     "decode_pull_bytes",
     "prefill_tokens", "prefill_tokens_saved", "cow_copies",
     # tokens the prefill programs computed (slots x bucket length; the
@@ -183,6 +187,12 @@ STAT_COUNTERS = (
     # after their request already left the slot (retirement/preemption lag)
     "completions_enqueued", "discarded_steps",
 )
+
+#: counters of a model with routed experts (``moe_counts`` in its decode
+#: state), registered only for such a model: over the sync cycle's decode
+#: steps, the (token, expert) pairs whose expert the model holds and the
+#: held experts with at least one pair, each summed over the MoE layers
+MOE_COUNTERS = ("moe_pairs_here", "moe_experts_hit")
 
 
 def serve_select_tokens(logits):
@@ -625,6 +635,12 @@ class ServeEngine:
 
             self._prefill = jax.jit(serve_prefill)
 
+        # routed-expert counters ride the decode pull (MOE_COUNTERS)
+        self._moe = "moe_counts" in self.state
+        if self._moe:
+            for name in MOE_COUNTERS:
+                self.metrics.counter(name)
+
         # --- async overlapped runtime (docs/SERVING.md §13) ---------------
         self.async_runtime = bool(async_runtime)
         self._runner = None
@@ -646,8 +662,10 @@ class ServeEngine:
     @property
     def stats(self) -> dict:
         """Lifecycle counters as a plain dict (the pre-telemetry ``stats``
-        interface, now a read-only view of the metrics registry)."""
-        return {k: int(self.metrics.value(k)) for k in STAT_COUNTERS}
+        interface, now a read-only view of the metrics registry); a model
+        with routed experts adds ``MOE_COUNTERS``."""
+        names = STAT_COUNTERS + (MOE_COUNTERS if self._moe else ())
+        return {k: int(self.metrics.value(k)) for k in names}
 
     def _phase(self, name: str, **meta) -> _PhaseTimer:
         """Timer and profiler span for one cycle phase
@@ -881,6 +899,9 @@ class ServeEngine:
             # selection finishes, not when the host comes to read them
             nxt.copy_to_host_async()
             finite.copy_to_host_async()
+            moe = self.state["moe_counts"] if self._moe else None
+            if moe is not None:
+                moe.copy_to_host_async()
         # one host sync per cycle: the explicit block_until_ready boundary
         # separates waiting on device compute from the host work after it —
         # the pull of the selected ids and finite flags (the logits rows stay
@@ -890,9 +911,16 @@ class ServeEngine:
         with self._phase("device_wait"):
             nxt, finite = jax.block_until_ready((nxt, finite))
         pull_bytes = nxt.nbytes + finite.nbytes
+        advance_stats = {}
+        if moe is not None:
+            pull_bytes += moe.nbytes
         with self._phase("logits_pull", bytes=pull_bytes):
             nxt, finite = np.array(nxt), np.asarray(finite)
+            if moe is not None:
+                advance_stats = dict(zip(MOE_COUNTERS, np.asarray(moe).tolist()))
         self.metrics.inc("decode_pull_bytes", pull_bytes)
+        for name, n in advance_stats.items():
+            self.metrics.inc(name, n)
         with self._phase("sample"):
             poisoned = set()
             if self.faults is not None:
@@ -912,7 +940,7 @@ class ServeEngine:
                         bad[slot] = "non-finite logits row"
                     elif not 0 <= int(nxt[slot]) < vocab:
                         bad[slot] = f"invalid next token id {int(nxt[slot])}"
-        with self._phase("advance"):
+        with self._phase("advance", **advance_stats):
             self.metrics.inc("steps")
             if self.paged:
                 # occupancy at the cycle peak — post-admission, pre-release:
@@ -1763,7 +1791,7 @@ class ServeEngine:
             return dst.at[tuple(idx)].set(src[tuple(src_idx)].astype(dst.dtype))
 
         for key in self.state:
-            if key in handled:
+            if key in handled or key not in st:  # e.g. decode-only moe_counts
                 continue
             self.state[key] = jax.tree.map(splice, self.state[key], st[key])
         lazy: dict[int, tuple] = {}

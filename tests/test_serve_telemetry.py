@@ -406,6 +406,40 @@ def test_logits_pull_fetches_only_ids_and_flags(tmp_path, small_model):
                for d in dispatch)
 
 
+def test_moe_counters_ride_the_decode_pull(tmp_path, small_model, mla_model):
+    """A model with routed experts counts, per decode step, the (token,
+    expert) pairs whose expert it holds and the held experts hit, summed over
+    its MoE layers: ``moe_pairs_here`` and ``moe_experts_hit`` in ``stats``
+    and as stats of every decode cycle's ``serve.advance`` span, pulled with
+    the ids and flags (8 B more a step).  A model without experts has
+    neither, and its pull stays at 5 B a slot."""
+    from repro.serve.engine import MOE_COUNTERS
+
+    cfg, model, params = mla_model
+    engine, events = _profiled_run(tmp_path / "mla", model, params,
+                                   _workload(cfg, n=3))
+    steps = engine.stats["steps"]
+    advance = [e[3] for e in events if e[0] == "serve.advance"
+               and "moe_pairs_here" in e[3]]
+    assert len(advance) == steps > 0
+    for name in MOE_COUNTERS:
+        assert engine.stats[name] == sum(a[name] for a in advance) > 0
+    # every slot's token routes top_k pairs in each MoE layer, all held here
+    n_moe = cfg.n_layers - cfg.first_dense_layers
+    assert all(a["moe_pairs_here"] == engine.slots * cfg.top_k * n_moe
+               for a in advance)
+    assert all(1 <= a["moe_experts_hit"] <= cfg.held_experts * n_moe
+               for a in advance)
+    assert engine.stats["decode_pull_bytes"] == steps * (engine.slots * 5 + 8)
+
+    cfg, model, params = small_model
+    engine, events = _profiled_run(tmp_path / "dense", model, params,
+                                   _workload(cfg, n=2))
+    assert not set(MOE_COUNTERS) & set(engine.stats)
+    assert not any(set(MOE_COUNTERS) & set(e[3]) for e in events)
+    assert engine.stats["decode_pull_bytes"] == engine.stats["steps"] * engine.slots * 5
+
+
 def test_ttft_tpot_split_latency_series(small_model):
     cfg, model, params = small_model
     reqs = _workload(cfg, n=4)

@@ -152,3 +152,85 @@ def test_flash_prefill(shape):
             s_valid=4096, interpret=False),
         shape((1, 4 * H, 4096, D), BF16), shape((1, H, 4096, D), BF16),
         shape((1, H, 4096, D), BF16))
+
+
+# DeepSeek-V3's latent path at the deepseek-v3.reason cell's shapes: one
+# latent stream (h=1) of 576 channels, stored lane-aligned in 640, feeding
+# g=128 query heads and a 512-channel value slice; 32 slots, a 1,120-page
+# pool (32 x 34 pages + 32 scratch), 4-bit, 128-token blocks
+LB, LP, LNB, LD, LDV, LG = 32, 1120, 34, 640, 512, 128
+
+
+def _latent_pools(shape, d=LD):
+    npr = layout.words_per_block(BLOCK, 4)
+    return [shape((LP, 1, npr, d), I32), shape((LP, 1, d), BF16), shape((LP, 1, d), BF16)]
+
+
+def test_latent_paged_bitdecode(shape):
+    args = [shape((LB, 1, LG, LD), BF16), *_latent_pools(shape),
+            shape((LB, 1, BLOCK, LD), BF16), shape((LB, LNB), I32), shape((LB,), I32),
+            shape((LB,), I32)]
+    _compiles_to_mosaic(
+        lambda q, kw, ks, kz, kr, pt, pb, rl: pg_kernel.paged_bitdecode_attention_pallas(
+            q, kw, ks, kz, None, None, None, kr, None, pt, pb, rl, bits=4,
+            block_n=BLOCK, sm_scale=0.1, k_gran="channel", shared_kv=True, d_v=LDV,
+            num_splits=1, interpret=False),
+        *args)
+
+
+def test_latent_paged_residual_flush(shape):
+    _compiles_to_mosaic(
+        lambda kw, ks, kz, kr, full, dest: rf_kernel.paged_residual_flush_pallas(
+            kw, ks, kz, None, None, None, kr, None, full, dest, bits=4, block_n=BLOCK,
+            k_gran="channel", shared_kv=True, interpret=False),
+        *_latent_pools(shape), shape((LB, 1, BLOCK, LD), BF16), shape((LB,), I32),
+        shape((LB,), I32))
+
+
+def test_latent_kv_quant(shape):
+    # the prefill of 32 slots x the cell's longest prompt bucket (256)
+    _compiles_to_mosaic(
+        lambda x: kq_kernel.quantize_kv_pallas(
+            x, bits=4, granularity="channel", block_n=BLOCK, interpret=False),
+        shape((LB, 1, 256, LD), BF16))
+
+
+@pytest.mark.parametrize("m,k,n", [(256, 7168, 4096), (256, 2048, 7168),
+                                   (16384, 7168, 4096), (16384, 2048, 7168)],
+                         ids=["decode_up", "decode_down", "prefill_up", "prefill_down"])
+def test_held_expert_grouped_matmul(shape, m, k, n):
+    """The share's grouped matmul over 8 held experts (plus the trailing
+    group of pairs held elsewhere), at DeepSeek-V3's widths: a decode step's
+    32 x 8 pairs and a prefill chunk's 2,048 x 8."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from repro.models import moe
+
+    _compiles_to_mosaic(
+        lambda lhs, rhs, sizes: gmm(lhs, rhs, sizes, preferred_element_type=jnp.float32,
+                                    tiling=moe.gmm_tiling(m, k, n)),
+        shape((m, k), BF16), shape((8, k, n), BF16), shape((9,), I32))
+
+
+@pytest.mark.parametrize("width,copies", [(LD, False), (576, True)],
+                         ids=["lane_aligned", "unaligned"])
+def test_latent_walk_copies_no_pool(width, copies):
+    """At the pool's lane-aligned width the decode walk hands the pools to
+    the kernel as they are; at 576 channels it would pad (copy) them on
+    every call."""
+    from repro.kernels.paged_bitdecode import ops as pg_ops
+
+    sds = jax.ShapeDtypeStruct
+    npr = layout.words_per_block(BLOCK, 4)
+    jaxpr = jax.make_jaxpr(
+        lambda q, kw, ks, kz, kr, pt, pb, rl: pg_ops.paged_bitdecode_attention(
+            q, kw, ks, kz, None, None, None, kr, None, pt, pb, rl, bits=4,
+            block_n=BLOCK, sm_scale=0.1, k_gran="channel", shared_kv=True, d_v=LDV,
+            impl="pallas", num_splits=1))(
+        sds((LB, 1, LG, width), BF16), sds((LP, 1, npr, width), I32),
+        sds((LP, 1, width), BF16), sds((LP, 1, width), BF16),
+        sds((LB, 1, BLOCK, width), BF16), sds((LB, LNB), I32), sds((LB,), I32),
+        sds((LB,), I32))
+    pool_sized = [e for e in jaxpr.jaxpr.eqns if e.primitive.name != "pallas_call"
+                  and any(getattr(v.aval, "shape", ())[:1] == (LP,) for v in e.outvars)]
+    assert bool(pool_sized) == copies, pool_sized
