@@ -194,7 +194,8 @@ def _paged_decode_attention(
     """Paged decode dispatch: page-table walk through kernels/paged_bitdecode
     (or, under :class:`use_splitkv`, the table walk sharded across chips).
     ``d_v`` is required for shared_kv (MLA latent) caches — the V width is a
-    channel slice of the latent, not a stored pool dimension."""
+    channel slice of the latent, not a stored pool dimension.  A layer view
+    (``cache.layer`` set) is read in place from the layer-stacked pools."""
     draft_bits = _SPEC["draft_bits"]
     if draft_bits is None and _SPLITKV["mesh"] is not None and not return_lse:
         from repro.dist import splitkv as _sk
@@ -204,13 +205,13 @@ def _paged_decode_attention(
             sm_scale=sm_scale, d_v=d_v, impl=impl, num_splits=num_splits,
             page_affine=_SPLITKV["page_affine"],
         )
-    h_kv = cache.kw.shape[1]
+    h_kv = cache.k_res.shape[1]
     qt = query_transform(q, h_kv)
     out = pg_ops.paged_bitdecode_attention(
         qt, cache.kw, cache.k_scale, cache.k_zero,
         cache.vw, cache.v_scale, cache.v_zero,
         cache.k_res, cache.v_res,
-        cache.page_table, cache.pack_blocks, cache.res_len,
+        cache.page_table, cache.pack_blocks, cache.res_len, layer=cache.layer,
         bits=cache.bits, block_n=cache.block_n, sm_scale=sm_scale,
         k_gran=cache.k_gran, shared_kv=cache.shared_kv, d_v=d_v,
         impl=impl, num_splits=num_splits, return_lse=return_lse,

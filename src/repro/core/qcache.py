@@ -6,15 +6,17 @@ capacity ``N_r = block_n`` — the TPU tile-aligned instantiation of the paper's
 ``N_r = P_n × W_n × R``.  Newly decoded tokens append to the residual; when it
 fills, the whole block is quantized+packed+committed in one fused pass (the
 Residual Kernel, kernels/residual_flush) and the residual restarts.  The
-flush is gated behind ``lax.cond`` so the other ``block_n - 1`` decode steps
-do no quantization work.  ``shared_kv=True`` stores a single latent stream
-(MLA mode) — no V-side fields.
+flush is gated so the other ``block_n - 1`` decode steps do no quantization
+work.  ``shared_kv=True`` stores a single latent stream (MLA mode) — no
+V-side fields.
 
 Two at-rest layouts share this data model: the dense :class:`QuantKVCache`
 (``[B, H, nb, ...]``, one private block range per sequence) and the paged
 :class:`PagedQuantKVCache` (shared ``[P, H, ...]`` page pools walked through
 per-sequence page tables — the serving engine's layout, allocated by
-serve/pages.py).  Both append paths run the same gated fused flush.
+serve/pages.py).  Both append paths run the fused flush: the dense one
+behind a ``lax.cond`` on any full row, the paged one gated row by row inside
+the flush (see :func:`paged_append_decode`).
 
 See docs/ARCHITECTURE.md for the packed ``(words, scale, zero)`` layout spec.
 """
@@ -352,6 +354,13 @@ class PagedQuantKVCache:
     latent stream: the V-side pools and residual are ``None`` and the decode
     kernel slices V out of the dequantized K tile, exactly as the dense
     shared mode does (kernels/paged_bitdecode).
+
+    ``layer`` (int32 scalar, optional) makes the cache one layer's *view* of
+    layer-stacked pools: the pool fields are ``[L, P, H, ...]`` and the
+    kernels read and flush layer ``layer`` of them in place, while every
+    other field is that layer's own.  The decode layer scan builds such views
+    so that no layer's pool is ever sliced out of the stack (see
+    :func:`split_pools`); ``None`` is the plain layout.
     """
 
     # shared page pools
@@ -373,6 +382,8 @@ class PagedQuantKVCache:
     block_n: int
     k_gran: str
     shared_kv: bool = False
+    # layer index into layer-stacked pools (a view), or None
+    layer: jax.Array | None = None
 
     @property
     def length(self) -> jax.Array:
@@ -380,14 +391,14 @@ class PagedQuantKVCache:
 
     @property
     def n_pages(self) -> int:
-        return self.kw.shape[0]
+        return self.kw.shape[-4]
 
 
 jax.tree_util.register_dataclass(
     PagedQuantKVCache,
     data_fields=[
         "kw", "k_scale", "k_zero", "vw", "v_scale", "v_zero",
-        "k_res", "v_res", "page_table", "pack_blocks", "res_len",
+        "k_res", "v_res", "page_table", "pack_blocks", "res_len", "layer",
     ],
     meta_fields=["bits", "block_n", "k_gran", "shared_kv"],
 )
@@ -461,10 +472,15 @@ def paged_append_decode(
     flush_op=rf_ops.paged_residual_flush,
 ) -> PagedQuantKVCache:
     """Paged per-token append: write the new token row into the dense
-    residual, and — gated behind ``lax.cond`` exactly like the dense
-    :func:`append_decode` — commit just-filled residual blocks *through the
-    page table* into the pools with the fused paged residual-flush kernel.
-    Non-flush steps do zero quantize/pack/pool work.
+    residual, and commit just-filled residual blocks *through the page
+    table* into the pools with the fused paged residual-flush kernel.
+
+    The flush is called on every step and gates itself row by row
+    (``residual_flush.ops.paged_residual_flush``): the kernel quantizes only
+    full rows, and a non-full row copies its scratch page back.  A
+    ``lax.cond`` around the call, as the dense :func:`append_decode` has,
+    would make the TPU compiler move the layer-stacked pools out of fast
+    memory before it and back after it, in every layer of every step.
 
     The flush destination per sequence is ``page_table[b, pack_blocks[b]]``
     when its residual filled, else the slot's scratch page ``b`` (keeps the
@@ -478,6 +494,8 @@ def paged_append_decode(
 
     ``flush_op`` is the flush (``residual_flush.ops.paged_residual_flush``'s
     signature); pools on a mesh pass ``dist.splitkv.splitkv_paged_flush``.
+    On a layer view (``cache.layer`` set) the flush writes layer
+    ``cache.layer`` of the stacked pools in place.
     """
     b = cache.k_res.shape[0]
     nb_max = cache.page_table.shape[1]
@@ -488,35 +506,14 @@ def paged_append_decode(
     dest = jnp.where(full, dest, jnp.arange(b, dtype=jnp.int32))
     dest = jnp.clip(dest, 0, cache.n_pages - 1)
 
-    if cache.shared_kv:
-        pools = (cache.kw, cache.k_scale, cache.k_zero)
-    else:
-        pools = (cache.kw, cache.k_scale, cache.k_zero,
-                 cache.vw, cache.v_scale, cache.v_zero)
-
-    def flush(p):
-        if cache.shared_kv:
-            kw, ks, kz = p
-            vw = vs = vz = None
-        else:
-            kw, ks, kz, vw, vs, vz = p
-        out = flush_op(
-            kw, ks, kz, vw, vs, vz, k_res, v_res,
-            full.astype(jnp.int32), dest,
-            bits=cache.bits, block_n=cache.block_n, k_gran=cache.k_gran,
-            shared_kv=cache.shared_kv, impl=quant_impl,
-        )
-        return out[:3] if cache.shared_kv else out
-
-    pools = lax.cond(jnp.any(full), flush, lambda p: p, pools)
-    if cache.shared_kv:
-        kw, ks, kz = pools
-        vw = vs = vz = None
-    else:
-        kw, ks, kz, vw, vs, vz = pools
-    return dataclasses.replace(
-        cache, kw=kw, k_scale=ks, k_zero=kz, vw=vw, v_scale=vs, v_zero=vz,
-        k_res=k_res, v_res=v_res,
+    pools, _ = split_pools(cache)
+    pools = flush_op(
+        *pools, k_res, v_res, full.astype(jnp.int32), dest, layer=cache.layer,
+        bits=cache.bits, block_n=cache.block_n, k_gran=cache.k_gran,
+        shared_kv=cache.shared_kv, impl=quant_impl,
+    )
+    return with_pools(
+        cache, pools, k_res=k_res, v_res=v_res,
         pack_blocks=jnp.where(full, cache.pack_blocks + 1, cache.pack_blocks),
         res_len=jnp.where(full, 0, rl),
     )
@@ -575,6 +572,22 @@ _PAGED_POOL_BASE_RANK = {
 def _page_axis(arr, field: str) -> int:
     """Page-pool axis of a (possibly layer-stacked) pool field."""
     return arr.ndim - _PAGED_POOL_BASE_RANK[field]
+
+
+def split_pools(cache: PagedQuantKVCache):
+    """``(pools, rest)``: the six pool fields (``None`` where absent) and
+    the cache with them set to ``None``.  The decode layer scan carries the
+    layer-stacked pools whole and scans only ``rest``; :func:`with_pools`
+    joins a layer's ``rest`` (``layer`` set) and the stack into that layer's
+    view."""
+    pools = tuple(getattr(cache, f) for f in _PAGED_POOL_FIELDS)
+    return pools, dataclasses.replace(cache, **dict.fromkeys(_PAGED_POOL_FIELDS))
+
+
+def with_pools(cache: PagedQuantKVCache, pools, **changes) -> PagedQuantKVCache:
+    """Inverse of :func:`split_pools` (``changes`` replace further fields)."""
+    return dataclasses.replace(cache, **dict(zip(_PAGED_POOL_FIELDS, pools)),
+                               **changes)
 
 
 def copy_pages(

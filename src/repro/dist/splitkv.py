@@ -197,8 +197,10 @@ def splitkv_paged_decode_attention(
     ``pack_blocks``) columns — the same masking the padded-table path
     already relies on.
 
-    q: [B, 1, h_q, d_k]; cache: PagedQuantKVCache.  Returns
-    [B, 1, h_q, d_v], replicated along ``axis``.  Composes with the
+    q: [B, 1, h_q, d_k]; cache: PagedQuantKVCache, or a layer view of
+    layer-stacked pools (``cache.layer`` set; the page axis is then dim 1,
+    and every chip reads layer ``cache.layer`` of its pools in place).
+    Returns [B, 1, h_q, d_v], replicated along ``axis``.  Composes with the
     in-kernel split (``num_splits``) per chip.  ``shared_kv`` caches (the
     MLA latent pools) shard the same way — one pool set, no V operands —
     with ``d_v`` naming the latent's value slice.
@@ -210,7 +212,7 @@ def splitkv_paged_decode_attention(
             f"mesh has no axis {axis!r}; available: {tuple(mesh.axis_names)}"
         )
     n = mesh.shape[axis]
-    h_kv = cache.kw.shape[1]
+    h_kv = cache.k_res.shape[1]
     qt = query_transform(q, h_kv)
     nb = cache.page_table.shape[1]
     pad = -(-nb // n) * n - nb
@@ -222,13 +224,14 @@ def splitkv_paged_decode_attention(
         table = jnp.pad(table, ((0, 0), (0, pad)))
 
     shared = cache.shared_kv
+    stacked = cache.layer is not None
     rep = PS()
-    # pool fields shard their leading (page) axis under page affinity; the
-    # residuals stay replicated (they are slot-indexed, not page-indexed)
-    pool = PS(axis) if page_affine else rep
-    if page_affine and cache.kw.shape[0] % n:
+    # pool fields shard their page axis under page affinity; the residuals
+    # stay replicated (they are slot-indexed, not page-indexed)
+    pool = _pool_spec(axis, page_affine, stacked)
+    if page_affine and cache.n_pages % n:
         raise ValueError(
-            f"page_affine needs the pool page count ({cache.kw.shape[0]}) "
+            f"page_affine needs the pool page count ({cache.n_pages}) "
             f"divisible by the {axis!r} axis size ({n}); allocate the pool "
             "with shards equal to the axis size (serve/pages.py)"
         )
@@ -247,8 +250,14 @@ def splitkv_paged_decode_attention(
         in_specs = (
             (rep,) + (pool,) * 6 + (rep, rep) + (PS(None, axis), rep, rep)
         )
+    if stacked:
+        operands += (cache.layer,)
+        in_specs += (rep,)
 
     def local(*args):
+        layer_ = None
+        if stacked:
+            *args, layer_ = args
         if shared:
             qt_, kw_, ks_, kz_, kres_, tbl_, pb_, rl_ = args
             vw_ = vs_ = vz_ = vres_ = None
@@ -265,11 +274,11 @@ def splitkv_paged_decode_attention(
             # allocator's affinity invariant every valid entry in this
             # shard's table columns is shard-local, so only masked entries
             # (scratch ids beyond pb_local) clamp
-            pp_local = kw_.shape[0]
+            pp_local = kw_.shape[-4]
             tbl_ = jnp.clip(tbl_ - idx * pp_local, 0, pp_local - 1)
         o, lse = pg_ops.paged_bitdecode_attention(
             qt_, kw_, ks_, kz_, vw_, vs_, vz_, kres_, vres_,
-            tbl_, pb_local, rl_local,
+            tbl_, pb_local, rl_local, layer=layer_,
             bits=cache.bits, block_n=cache.block_n, sm_scale=sm_scale,
             k_gran=cache.k_gran, shared_kv=shared, d_v=d_v,
             impl=impl, num_splits=num_splits, return_lse=True,
@@ -282,6 +291,14 @@ def splitkv_paged_decode_attention(
     return inverse_query_transform(out)
 
 
+def _pool_spec(axis: str, page_affine: bool, stacked: bool) -> PS:
+    """Partition of a pool field: its page axis (dim 1 when layer-stacked,
+    else dim 0) split along ``axis`` under page affinity, else replicated."""
+    if not page_affine:
+        return PS()
+    return PS(None, axis) if stacked else PS(axis)
+
+
 def splitkv_paged_flush(
     kw_pool, k_scale_pool, k_zero_pool, vw_pool, v_scale_pool, v_zero_pool,
     k_res, v_res, full, dest_page,
@@ -289,10 +306,12 @@ def splitkv_paged_flush(
     mesh,
     axis: str = "data",
     page_affine: bool = False,
+    layer=None,
     **flush_kw,
 ):
     """The paged residual flush (``residual_flush.ops.paged_residual_flush``,
-    same arguments and result) for pools that live on ``mesh``.
+    same arguments and result, ``layer`` included) for pools that live on
+    ``mesh``.
 
     Replicated pools: every chip commits the same pages.  Page-affine pools
     (leading page axis sharded along ``axis``): each chip commits only the
@@ -302,7 +321,8 @@ def splitkv_paged_flush(
     parked row writes back the page it read, so the page keeps its content.
     """
     rep = PS()
-    pool = PS(axis) if page_affine else rep
+    stacked = layer is not None
+    pool = _pool_spec(axis, page_affine, stacked)
     pools = [kw_pool, k_scale_pool, k_zero_pool, vw_pool, v_scale_pool,
              v_zero_pool]
     have = [x is not None for x in pools]
@@ -310,18 +330,22 @@ def splitkv_paged_flush(
     operands += [k_res] + ([v_res] if v_res is not None else [])
     n_pools = sum(have)
     b = full.shape[0]
-    if page_affine and kw_pool.shape[0] // mesh.shape[axis] <= b:
+    n_pages = kw_pool.shape[-4]
+    if page_affine and n_pages // mesh.shape[axis] <= b:
         raise ValueError(
             f"page_affine flush needs more than {b} pages per shard, got "
-            f"{kw_pool.shape[0] // mesh.shape[axis]}"
+            f"{n_pages // mesh.shape[axis]}"
         )
 
     def local(*args):
+        layer_ = None
+        if stacked:
+            *args, layer_ = args
         pl_ = list(args[:n_pools])
         res = list(args[n_pools:-2])
         fl, dst = args[-2], args[-1]
         if page_affine:
-            pp = pl_[0].shape[0]
+            pp = pl_[0].shape[-4]
             idx = lax.axis_index(axis)
             mine = (fl != 0) & (dst // pp == idx)
             dst = dst - idx * pp
@@ -334,13 +358,17 @@ def splitkv_paged_flush(
         full_pools = [next(it) if h else None for h in have]
         k_r, v_r = res[0], (res[1] if len(res) > 1 else None)
         out = rf_ops.paged_residual_flush(*full_pools, k_r, v_r, fl, dst,
-                                          **flush_kw)
+                                          layer=layer_, **flush_kw)
         return tuple(x for x in out if x is not None)
 
     in_specs = (pool,) * n_pools + (rep,) * (len(operands) - n_pools) + (rep, rep)
+    operands += [full, dest_page]
+    if stacked:
+        in_specs += (rep,)
+        operands.append(jnp.asarray(layer, jnp.int32))
     out = jax.shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=(pool,) * n_pools,
         check_vma=False,
-    )(*operands, full, dest_page)
+    )(*operands)
     it = iter(out)
     return tuple(next(it) if h else None for h in have)

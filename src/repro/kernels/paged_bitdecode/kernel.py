@@ -13,9 +13,12 @@ entries [s*bps, (s+1)*bps), writes its own slot of the per-split partials
 split and the partials are combined by the shared logsumexp merge epilogue
 (bitdecode.kernel.merge_partials).
 
-Pools are [n_pages, H, ...] and one grid step moves one page of *every*
-KV head (blocks ``(1, H, ...)``), running the heads' flash updates one after
-another in registers; everything else matches kernels/bitdecode.
+Pools are layer-stacked, [L, n_pages, H, ...], and a scalar-prefetch
+``layer`` picks the layer the walk reads: the decode layer scan hands the
+kernel the whole stack and never slices one layer's pool out of it.  One
+grid step moves one page of *every* KV head of that layer (blocks
+``(1, H, ...)``, the layer dim squeezed), running the heads' flash updates
+one after another in registers; everything else matches kernels/bitdecode.
 
 ``shared_kv=True`` is the MLA latent-cache mode, mirrored from the dense
 kernel: the pools hold a single quantized latent stream, there are no V-side
@@ -37,7 +40,7 @@ from repro.kernels.bitdecode.kernel import (_unpack, dequant_tile, finalize,
                                             init_carries, make_flash_update)
 
 
-def _paged_body(pt_ref, pb_ref, rl_ref, q_ref, kw_ref, ks_ref, kz_ref,
+def _paged_body(pt_ref, pb_ref, rl_ref, ly_ref, q_ref, kw_ref, ks_ref, kz_ref,
                 vw_ref, vs_ref, vz_ref, kres_ref, vres_ref,
                 o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, bits, bps, num_splits, res_n, sm_scale, k_gran,
@@ -93,15 +96,15 @@ def _paged_body(pt_ref, pb_ref, rl_ref, q_ref, kw_ref, ks_ref, kz_ref,
         finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr)
 
 
-def _kernel_standard(pt, pb, rl, q, kw, ks, kz, vw, vs, vz, kres, vres,
+def _kernel_standard(pt, pb, rl, ly, q, kw, ks, kz, vw, vs, vz, kres, vres,
                      o, lse, m, l, acc, **kw_args):
-    _paged_body(pt, pb, rl, q, kw, ks, kz, vw, vs, vz, kres, vres,
+    _paged_body(pt, pb, rl, ly, q, kw, ks, kz, vw, vs, vz, kres, vres,
                 o, lse, m, l, acc, **kw_args)
 
 
-def _kernel_shared(pt, pb, rl, q, kw, ks, kz, kres, o, lse, m, l, acc,
+def _kernel_shared(pt, pb, rl, ly, q, kw, ks, kz, kres, o, lse, m, l, acc,
                    **kw_args):
-    _paged_body(pt, pb, rl, q, kw, ks, kz, None, None, None, kres, None,
+    _paged_body(pt, pb, rl, ly, q, kw, ks, kz, None, None, None, kres, None,
                 o, lse, m, l, acc, **kw_args)
 
 
@@ -112,15 +115,16 @@ def _kernel_shared(pt, pb, rl, q, kw, ks, kz, kres, o, lse, m, l, acc,
 )
 def paged_bitdecode_attention_pallas(
     q,             # [B, H, g, d_k]  (pre-padded)
-    kw_pool,       # int32 [P, H, npr, d_k]
-    k_scale_pool,  # [P, H, d_k] (channel) or [P, H, block_n]
+    kw_pool,       # int32 [L, P, H, npr, d_k]
+    k_scale_pool,  # [L, P, H, d_k] (channel) or [L, P, H, block_n]
     k_zero_pool,
-    vw_pool,       # int32 [P, H, npr, d_v]; None when shared_kv
-    v_scale_pool,  # [P, H, block_n]; None when shared_kv
+    vw_pool,       # int32 [L, P, H, npr, d_v]; None when shared_kv
+    v_scale_pool,  # [L, P, H, block_n]; None when shared_kv
     v_zero_pool,
     k_res, v_res,  # [B, H, res_n, d]; v_res None when shared_kv
     page_table,    # int32 [B, nb_max]
     pack_blocks, res_len,
+    layer,         # int32 scalar: the layer of the pools the walk reads
     *,
     bits: int, block_n: int, sm_scale: float, k_gran: str,
     shared_kv: bool = False, d_v: int | None = None,
@@ -128,7 +132,7 @@ def paged_bitdecode_attention_pallas(
 ):
     """Returns per-split partials (o [S,B,H,g,d_v], lse [S,B,H,g])."""
     b, h, g, d_k = q.shape
-    _, _, npr, _ = kw_pool.shape
+    npr = kw_pool.shape[-2]
     if not shared_kv:
         d_v = vw_pool.shape[-1]
     nb = page_table.shape[1]
@@ -147,8 +151,9 @@ def paged_bitdecode_attention_pallas(
     # is not a legal tile, while (1, H, d) covers the head axis in full
     def pool_spec(*minor):
         return pl.BlockSpec(
-            (1, h, *minor),
-            lambda i, s, j, pt, pb, rl: (page(s, j, pt, i), 0) + (0,) * len(minor),
+            (pl.squeezed, 1, h, *minor),
+            lambda i, s, j, pt, pb, rl, ly: (ly[0], page(s, j, pt, i), 0)
+            + (0,) * len(minor),
         )
 
     def seq_spec(*minor):
@@ -173,7 +178,7 @@ def paged_bitdecode_attention_pallas(
         pl.BlockSpec((1, 1, h, g, 1), lambda i, s, j, *_: (s, i, 0, 0, 0)),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, num_splits, n_steps),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -200,5 +205,6 @@ def paged_bitdecode_attention_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(page_table.astype(jnp.int32), pack_blocks.astype(jnp.int32),
-      res_len.astype(jnp.int32), *operands)
+      res_len.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+      *operands)
     return out, lse[..., 0]

@@ -4,6 +4,10 @@
 quantized latent stream (V-side pools and residual are ``None``), the kernel
 reads each page once and slices the V tile out of the dequantized K tile —
 the paged twin of ``kernels/bitdecode``'s shared mode, same split-KV grid.
+
+``layer`` makes the pools layer-stacked (``[L, P, H, ...]``, the decode
+layer scan's layout) and names the layer the walk reads; without it the
+pools are one layer's and are read as a stack of one.
 """
 from __future__ import annotations
 
@@ -27,6 +31,7 @@ def paged_bitdecode_attention(
     k_res, v_res,
     page_table, pack_blocks, res_len,
     *,
+    layer=None,
     bits: int, block_n: int = 128, sm_scale: float | None = None,
     k_gran: str = "channel", shared_kv: bool = False, d_v: int | None = None,
     impl: str = "auto",
@@ -34,6 +39,13 @@ def paged_bitdecode_attention(
     draft_bits: int | None = None,
 ):
     b, h, g, d_k = q.shape
+    if layer is None:  # one layer's pools: a stack of one
+        kw_pool, k_scale_pool, k_zero_pool, vw_pool, v_scale_pool, v_zero_pool = (
+            None if p is None else p[None]
+            for p in (kw_pool, k_scale_pool, k_zero_pool, vw_pool, v_scale_pool,
+                      v_zero_pool))
+        layer = 0
+    layer = jnp.asarray(layer, jnp.int32)
     if shared_kv:
         if d_v is None:
             raise ValueError("shared_kv requires d_v")
@@ -59,7 +71,7 @@ def paged_bitdecode_attention(
     if impl == "xla":
         out, lse = _ref.paged_bitdecode_attention_ref(
             q, kw_pool, k_scale_pool, k_zero_pool, vw_pool, v_scale_pool,
-            v_zero_pool, k_res, v_res, page_table, pack_blocks, res_len,
+            v_zero_pool, k_res, v_res, page_table, pack_blocks, res_len, layer,
             bits=bits, block_n=block_n, sm_scale=sm_scale, k_gran=k_gran,
             shared_kv=shared_kv, d_v=d_v, num_splits=num_splits,
             draft_bits=draft_bits,
@@ -77,11 +89,11 @@ def paged_bitdecode_attention(
         return jnp.pad(x, cfg) if any(p for _, p in axis_pads) else x
 
     q_p = pad(q, [(2, g_p - g), (3, dk_p - d_k)])
-    kw_p = pad(kw_pool, [(3, dk_p - d_k)])
+    kw_p = pad(kw_pool, [(4, dk_p - d_k)])
     if k_gran == "channel" and dk_p != d_k:
         ones = jnp.ones(k_scale_pool.shape[:-1] + (dk_p - d_k,), k_scale_pool.dtype)
         ks_p = jnp.concatenate([k_scale_pool, ones], axis=-1)
-        kz_p = pad(k_zero_pool, [(2, dk_p - d_k)])
+        kz_p = pad(k_zero_pool, [(3, dk_p - d_k)])
     else:
         ks_p, kz_p = k_scale_pool, k_zero_pool
     kres_p = pad(k_res, [(3, dk_p - d_k)])
@@ -94,14 +106,14 @@ def paged_bitdecode_attention(
         dv_eff = d_v
     else:
         dv_p = _round_up(d_v, 128)
-        vw_p = pad(vw_pool, [(3, dv_p - d_v)])
+        vw_p = pad(vw_pool, [(4, dv_p - d_v)])
         vs_p, vz_p = v_scale_pool, v_zero_pool
         vres_p = pad(v_res, [(3, dv_p - d_v)])
         dv_eff = dv_p
 
     o_parts, lse_parts = _kernel.paged_bitdecode_attention_pallas(
         q_p, kw_p, ks_p, kz_p, vw_p, vs_p, vz_p,
-        kres_p, vres_p, page_table, pack_blocks, res_len,
+        kres_p, vres_p, page_table, pack_blocks, res_len, layer,
         bits=bits, block_n=block_n, sm_scale=float(sm_scale), k_gran=k_gran,
         shared_kv=shared_kv, d_v=dv_eff if shared_kv else None,
         num_splits=num_splits, interpret=jax.default_backend() != "tpu",

@@ -21,10 +21,10 @@ in one pass per ``(batch, head)``:
 
 Per-sequence gating: ``full[b]`` (scalar prefetch) marks sequences whose
 residual just filled.  Programs for non-full sequences copy their (aliased)
-input block back unchanged — a one-block VMEM round-trip, only ever paid
-when *some other* sequence in the batch flushes, because the caller wraps
-the whole kernel invocation in ``lax.cond(any(full), ...)`` and skips it
-entirely on the per-token hot path.
+input block back unchanged — a one-block VMEM round-trip.  The dense cache's
+caller wraps the whole kernel invocation in ``lax.cond(any(full), ...)`` and
+skips it on the per-token hot path; the paged variant below runs on every
+decode step and relies on this per-row gate alone.
 
 Constraints (TPU, non-interpret): ``d % 128 == 0`` (the aliased cache cannot
 be lane-padded in place — the kernel raises otherwise) and
@@ -34,7 +34,10 @@ The paged variant (:func:`paged_residual_flush_pallas`) commits through a
 page table instead, one grid step per sequence covering every KV head
 (blocks ``(1, H, ...)``, whose params form a legal tile): the destination
 is a *pool page* index (``dest_page[b]``, scalar prefetch) into the shared
-``[P, H, ...]`` pools rather than a block of sequence ``b``'s own cache.  Same aliasing trick, one extra invariant: the
+pools rather than a block of sequence ``b``'s own cache.  The pools are
+layer-stacked (``[L, P, H, ...]``) and a scalar-prefetch ``layer`` picks the
+layer, so the decode layer scan flushes into the whole stack in place and
+never copies one layer's pool out.  Same aliasing trick, one extra invariant: the
 per-sequence destinations must be pairwise distinct, because two grid rows
 writing the same pool page would race.  Callers guarantee it by routing
 non-flushing sequences to a reserved per-slot scratch page (pages
@@ -243,6 +246,7 @@ def residual_flush_pallas(
 def _paged_body(
     full_ref,
     dest_ref,
+    layer_ref,
     kres_ref,
     *refs,
     bits,
@@ -305,6 +309,7 @@ def paged_residual_flush_pallas(
     v_res,
     full,
     dest_page,
+    layer,
     *,
     bits: int,
     block_n: int,
@@ -312,14 +317,16 @@ def paged_residual_flush_pallas(
     shared_kv: bool = False,
     interpret: bool,
 ):
-    """Commit ``k_res[b]``/``v_res[b]`` into pool page ``dest_page[b]`` of the
-    shared ``[P, H, ...]`` page pools for every sequence with ``full[b] != 0``;
-    other sequences' destination pages pass through untouched (callers point
-    them at per-slot scratch pages so destinations stay pairwise distinct).
-    Returns the six updated pool arrays (V side ``None`` when ``shared_kv`` —
-    the MLA latent pools have no V stream), aliased in place on TPU.
+    """Commit ``k_res[b]``/``v_res[b]`` into page ``dest_page[b]`` of layer
+    ``layer`` (int32 scalar) of the layer-stacked ``[L, P, H, ...]`` page
+    pools for every sequence with ``full[b] != 0``; other sequences'
+    destination pages pass through untouched (callers point them at per-slot
+    scratch pages so destinations stay pairwise distinct), and so does every
+    other layer.  Returns the six updated pool arrays (V side ``None`` when
+    ``shared_kv`` — the MLA latent pools have no V stream), aliased in place
+    on TPU.
     """
-    n_pages, h, npr, d_k = kw_pool.shape
+    _, n_pages, h, npr, d_k = kw_pool.shape
     b = k_res.shape[0]
     param_dtype = k_scale_pool.dtype
     if not interpret:
@@ -335,15 +342,16 @@ def paged_residual_flush_pallas(
             )
 
     def pool_spec(*minor):
-        # page dest_page[b] of every head; the clamp keeps the DMA in range
-        # (callers never pass out-of-pool pages)
+        # page dest_page[b] of every head of layer `layer`; the clamp keeps
+        # the DMA in range (callers never pass out-of-pool pages)
         return pl.BlockSpec(
-            (1, h, *minor),
-            lambda i, f, dr: (jnp.minimum(dr[i], n_pages - 1), 0) + (0,) * len(minor),
+            (pl.squeezed, 1, h, *minor),
+            lambda i, f, dr, ly: (ly[0], jnp.minimum(dr[i], n_pages - 1), 0)
+            + (0,) * len(minor),
         )
 
     def res_spec(d):
-        return pl.BlockSpec((1, h, block_n, d), lambda i, f, dr: (i, 0, 0, 0))
+        return pl.BlockSpec((1, h, block_n, d), lambda i, f, dr, ly: (i, 0, 0, 0))
 
     w_spec = pool_spec(npr, d_k)
     kp_spec = pool_spec(d_k if k_gran == "channel" else block_n)
@@ -354,7 +362,7 @@ def paged_residual_flush_pallas(
         pools = [kw_pool, k_scale_pool, k_zero_pool]
         in_specs = [kres_spec] + pool_specs
         operands = [k_res] + pools
-        n_lead = 3  # full, dest_page, k_res precede the aliased pools
+        n_lead = 4  # full, dest_page, layer, k_res precede the aliased pools
     else:
         d_v = vw_pool.shape[-1]
         vw_spec = pool_spec(npr, d_v)
@@ -365,15 +373,15 @@ def paged_residual_flush_pallas(
                  v_zero_pool]
         in_specs = [kres_spec, vres_spec] + pool_specs
         operands = [k_res, v_res] + pools
-        n_lead = 4  # full, dest_page, k_res, v_res precede the aliased pools
+        n_lead = 5  # full, dest_page, layer, k_res, v_res precede the pools
     out_shape = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in pools]
 
     # alias each pool input onto its output; indices count the scalar-prefetch
-    # operands (full, dest_page) and the residual inputs
+    # operands (full, dest_page, layer) and the residual inputs
     aliases = {n_lead + i: i for i in range(len(pools))}
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b,),
         in_specs=in_specs,
         out_specs=pool_specs,
@@ -390,7 +398,8 @@ def paged_residual_flush_pallas(
         interpret=interpret,
         name="paged_residual_flush",
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
-    )(full.astype(jnp.int32), dest_page.astype(jnp.int32), *operands)
+    )(full.astype(jnp.int32), dest_page.astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32), *operands)
     if shared_kv:
         kw_pool, k_scale_pool, k_zero_pool = out
         return kw_pool, k_scale_pool, k_zero_pool, None, None, None

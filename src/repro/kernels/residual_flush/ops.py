@@ -2,6 +2,8 @@
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
+from jax import lax
 
 from repro.kernels.residual_flush import kernel as _kernel
 from repro.kernels.residual_flush import ref as _ref
@@ -70,6 +72,7 @@ def paged_residual_flush(
     full,
     dest_page,
     *,
+    layer=None,
     bits: int,
     block_n: int,
     k_gran: str,
@@ -80,9 +83,17 @@ def paged_residual_flush(
     every sequence with ``full[b] != 0`` into pool page ``dest_page[b]`` of
     the shared ``[P, H, ...]`` page pools.
 
-    Same gating contract as :func:`residual_flush` (callers wrap the call in
-    ``lax.cond(any(full))`` — see ``qcache.paged_append_decode``), plus the
-    paged injectivity contract: ``dest_page`` entries must be pairwise
+    ``layer`` (int32 scalar) makes the pools layer-stacked, ``[L, P, H,
+    ...]``: the flush writes layer ``layer`` of them in place and returns the
+    whole stack, every other layer bitwise unchanged.  Without it the pools
+    are one layer's, flushed as a stack of one.
+
+    The flush gates itself, so callers run it on every decode step
+    (``qcache.paged_append_decode``): the kernel quantizes only full rows,
+    and the XLA reference runs under a ``lax.cond`` on any full row.  The
+    kernel is never put under a ``cond``: on the TPU that would move the
+    pools out of fast memory and back around it.  Callers keep the paged
+    injectivity contract: ``dest_page`` entries must be pairwise
     distinct.  Callers satisfy it by pointing non-flushing sequences at their
     reserved per-slot scratch page (pool pages ``[0, B)``, never allocated to
     requests — serve/pages.py).  ``shared_kv`` is the MLA latent-pool mode
@@ -92,19 +103,31 @@ def paged_residual_flush(
     minor dim that is not lane-aligned raises on TPU, exactly like the dense
     flush).
     """
+    pools = (kw_pool, k_scale_pool, k_zero_pool, vw_pool, v_scale_pool,
+             v_zero_pool)
+    if layer is None:
+        out = paged_residual_flush(
+            *(None if p is None else p[None] for p in pools), k_res, v_res,
+            full, dest_page, layer=0, bits=bits, block_n=block_n,
+            k_gran=k_gran, shared_kv=shared_kv, impl=impl,
+        )
+        return tuple(None if p is None else p[0] for p in out)
+    layer = jnp.asarray(layer, jnp.int32)
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl == "pallas":
         return _kernel.paged_residual_flush_pallas(
-            kw_pool, k_scale_pool, k_zero_pool, vw_pool, v_scale_pool,
-            v_zero_pool, k_res, v_res, full, dest_page,
+            *pools, k_res, v_res, full, dest_page, layer,
             bits=bits, block_n=block_n, k_gran=k_gran, shared_kv=shared_kv,
             interpret=jax.default_backend() != "tpu",
         )
     if impl == "xla":
-        return _ref.paged_residual_flush_ref(
-            kw_pool, k_scale_pool, k_zero_pool, vw_pool, v_scale_pool,
-            v_zero_pool, k_res, v_res, full, dest_page,
-            bits=bits, block_n=block_n, k_gran=k_gran, shared_kv=shared_kv,
+        return lax.cond(
+            jnp.any(full != 0),
+            lambda p: _ref.paged_residual_flush_ref(
+                *p, k_res, v_res, full, dest_page, layer,
+                bits=bits, block_n=block_n, k_gran=k_gran, shared_kv=shared_kv,
+            ),
+            lambda p: p, pools,
         )
     raise ValueError(f"unknown impl {impl!r}")

@@ -89,6 +89,7 @@ def paged_residual_flush_ref(
     v_res,
     full,
     dest_page,
+    layer,
     *,
     bits: int,
     block_n: int,
@@ -96,19 +97,21 @@ def paged_residual_flush_ref(
     shared_kv: bool = False,
 ):
     """Oracle for :func:`..kernel.paged_residual_flush_pallas`: quantize every
-    residual, gather the current destination pages, select against ``full``,
-    scatter back.  Same injectivity contract as the kernel: ``dest_page``
-    entries must be pairwise distinct (non-flushing sequences point at their
-    reserved per-slot scratch page), so the scatter has no duplicate indices.
+    residual, gather the current destination pages of layer ``layer``, select
+    against ``full``, scatter back.  Same injectivity contract as the kernel:
+    ``dest_page`` entries must be pairwise distinct (non-flushing sequences
+    point at their reserved per-slot scratch page), so the scatter has no
+    duplicate indices.
 
-    kw_pool: int32 [P, H, npr, d_k]; k_res: [B, H, block_n, d_k];
-    full/dest_page: int32 [B].  Returns the six updated pool arrays (V side
-    ``None`` when ``shared_kv`` — the MLA latent pools have no V stream).
+    kw_pool: int32 [L, P, H, npr, d_k]; k_res: [B, H, block_n, d_k];
+    full/dest_page: int32 [B]; layer: int32 scalar.  Returns the six updated
+    pool arrays (V side ``None`` when ``shared_kv`` — the MLA latent pools
+    have no V stream).
     """
     param_dtype = k_scale_pool.dtype
     if block_n != layout.words_per_block(block_n, bits) * layout.packing_ratio(bits):
         raise ValueError(f"block_n={block_n} violates the layout invariant")
-    dest = jnp.minimum(dest_page.astype(jnp.int32), kw_pool.shape[0] - 1)
+    dest = jnp.minimum(dest_page.astype(jnp.int32), kw_pool.shape[1] - 1)
     fl = full != 0
 
     w, s, z = jax.vmap(
@@ -116,9 +119,9 @@ def paged_residual_flush_ref(
     )(k_res)
 
     def commit(pool, new):
-        cur = jnp.take(pool, dest, axis=0)
+        cur = pool[layer, dest]
         keep = fl.reshape((-1,) + (1,) * (new.ndim - 1))
-        return pool.at[dest].set(jnp.where(keep, new.astype(pool.dtype), cur))
+        return pool.at[layer, dest].set(jnp.where(keep, new.astype(pool.dtype), cur))
 
     if shared_kv:
         return (

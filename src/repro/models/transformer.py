@@ -17,6 +17,7 @@ Decode state is a plain dict pytree:
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any
 
@@ -84,6 +85,39 @@ def _scan_params(kind, stack):
 def _layer_params(lp, whole):
     """One layer's params inside the scan (see :func:`_scan_params`)."""
     return lp if whole is None else dict(lp, moe=dict(lp["moe"], **whole))
+
+
+def _scan_cache(cache):
+    """A layer-stacked decode cache as its layer scan takes it: ``(pools,
+    xs)``.  A paged cache's page pools stay out of the scanned operands and
+    ride the scan's carry whole; each layer reads and flushes its own layer
+    of them in place through a layer view (``PagedQuantKVCache.layer``, the
+    scan counter in ``xs``).  A scanned slice would be copied out of the
+    stack for the kernels and written back, every layer of every step.  Other
+    caches scan whole (``pools`` None)."""
+    if not isinstance(cache, qcache.PagedQuantKVCache):
+        return None, cache
+    pools, rest = qcache.split_pools(cache)
+    n_layers = cache.kw.shape[0]
+    return pools, dataclasses.replace(
+        rest, layer=jnp.arange(n_layers, dtype=jnp.int32))
+
+
+def _layer_cache(pools, cache):
+    """One layer's cache inside the scan (see :func:`_scan_cache`)."""
+    return cache if pools is None else qcache.with_pools(cache, pools)
+
+
+def _unlayer_cache(pools, cache):
+    """Inverse of :func:`_layer_cache`: ``(pools, per-layer ys)``."""
+    return (None, cache) if pools is None else qcache.split_pools(cache)
+
+
+def _stacked_cache(pools, cache_stack):
+    """Inverse of :func:`_scan_cache` on the scan's results."""
+    if pools is None:
+        return cache_stack
+    return qcache.with_pools(cache_stack, pools, layer=None)
 
 
 class DecoderLM:
@@ -485,36 +519,29 @@ class DecoderLM:
         else:
             positions = pos[:, None]
         new_caches = []
-        counts = None
+        counts = jnp.zeros((2,), jnp.int32)
         for i, (kind, _) in enumerate(self.stacks):
-            if kind == "moe":
-                stack_p, whole = _scan_params(kind, params[f"stack_{i}"])
-                xs = (stack_p, state["caches"][i])
+            stack_p, whole = _scan_params(kind, params[f"stack_{i}"])
+            pools, caches = _scan_cache(state["caches"][i])
 
-                def body_moe(carry, xs, _whole=whole):
-                    x, c = carry
-                    lp, cache = xs
-                    x, cache, c_l = self._block_decode(
-                        _layer_params(lp, _whole), "moe", x, positions, cache,
-                        impl, quant_impl
-                    )
-                    return (x, c + c_l), cache
+            def body(carry, xs, _kind=kind, _whole=whole):
+                x, c, pools = carry
+                lp, cache = xs
+                out = self._block_decode(
+                    _layer_params(lp, _whole), _kind, x, positions,
+                    _layer_cache(pools, cache), impl, quant_impl
+                )
+                if _kind == "moe":
+                    x, cache, c_l = out
+                    c = c + c_l
+                else:
+                    x, cache = out
+                pools, cache = _unlayer_cache(pools, cache)
+                return (x, c, pools), cache
 
-                if counts is None:
-                    counts = jnp.zeros((2,), jnp.int32)
-                (x, counts), cache_stack = lax.scan(body_moe, (x, counts), xs)
-            else:
-                xs = (params[f"stack_{i}"], state["caches"][i])
-
-                def body(x, xs, _kind=kind):
-                    lp, cache = xs
-                    x, cache = self._block_decode(
-                        lp, _kind, x, positions, cache, impl, quant_impl
-                    )
-                    return x, cache
-
-                x, cache_stack = lax.scan(body, x, xs)
-            new_caches.append(cache_stack)
+            (x, counts, pools), cache_stack = lax.scan(
+                body, (x, counts, pools), (stack_p, caches))
+            new_caches.append(_stacked_cache(pools, cache_stack))
         logits = self._logits(params, x)
         return logits, self._with_moe_counts(
             {"caches": new_caches, "pos": pos + 1}, counts)
@@ -683,7 +710,8 @@ class HybridLM:
         positions = pos[:, None]
         shared = params["shared_attn"]
 
-        def super_body(x, xs):
+        def super_body(carry, xs):
+            x, pools = carry
             group, sst, cache = xs
 
             def inner(x, ys):
@@ -695,19 +723,22 @@ class HybridLM:
             x, sst = lax.scan(inner, x, (group, sst))
             h = layers.apply_norm(cfg.norm, shared["ln1"], x)
             a, cache = mattn.attn_decode(
-                shared["attn"], cfg, h, positions, cache, impl=impl,
-                quant_impl=quant_impl,
+                shared["attn"], cfg, h, positions, _layer_cache(pools, cache),
+                impl=impl, quant_impl=quant_impl,
             )
+            pools, cache = _unlayer_cache(pools, cache)
             x = x + a
             x = x + layers.mlp(
                 shared["mlp"], layers.apply_norm(cfg.norm, shared["ln2"], x), cfg.act
             )
-            return x, (sst, cache)
+            return (x, pools), (sst, cache)
 
-        x, (ssm_main, caches) = lax.scan(
-            super_body, x, (params["main"], state["ssm_main"], state["caches"][0])
+        pools, caches = _scan_cache(state["caches"][0])
+        (x, pools), (ssm_main, caches) = lax.scan(
+            super_body, (x, pools), (params["main"], state["ssm_main"], caches)
         )
-        new_state = dict(state, ssm_main=ssm_main, caches=[caches], pos=pos + 1)
+        new_state = dict(state, ssm_main=ssm_main,
+                         caches=[_stacked_cache(pools, caches)], pos=pos + 1)
         if self.tail:
             def tail_body(x, ys):
                 lp, st = ys

@@ -165,3 +165,56 @@ def test_paged_shared_flush_commits_latent_through_table(impl):
                                   np.asarray(ks_want)[0, :, 0])
     assert not np.asarray(pc2.kw[6]).any()  # untouched page
     assert not np.asarray(pc2.kw[0]).any()  # slot 0's scratch page
+
+
+# --------------------------------------------------------------------------
+# layer-stacked pools: the decode layer scan's layout ([L, P, H, ...] plus a
+# layer index) reads exactly what one layer's own pools read
+# --------------------------------------------------------------------------
+
+def stack_at(key, pools, n_layers, layer):
+    """Stack each pool as layer ``layer`` of ``n_layers``; the other layers
+    hold other content, so a read or write of the wrong layer shows."""
+    def one(i, p):
+        k = jax.random.fold_in(key, i)
+        if p.dtype == jnp.int32:
+            other = jax.random.randint(k, (n_layers, *p.shape), 0, 1 << 30, jnp.int32)
+        else:
+            other = jax.random.uniform(k, (n_layers, *p.shape), jnp.float32,
+                                       0.5, 2.0).astype(p.dtype)
+        return other.at[layer].set(p)
+
+    return [None if p is None else one(i, p) for i, p in enumerate(pools)]
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("num_splits", [1, 4])
+@pytest.mark.parametrize("shared_kv", [False, True])
+def test_stacked_pool_read_equals_layer_pool(shared_kv, num_splits, layer, impl):
+    """paged_bitdecode on layer ``layer`` of a 3-layer stack is bitwise the
+    call on that layer's own pools (the first and the last layer)."""
+    b, h, g, d, block_n, nb, n_pages = 2, 2, 8, 128, 128, 4, 10
+    if shared_kv:
+        q, kwp, ksp, kzp, k_res, table = _make_shared(
+            jax.random.PRNGKey(5), b=b, h=1, g=g, d=2 * d, n_pages=n_pages,
+            nb=nb, block_n=block_n, bits=4, k_gran="channel")
+        pools, v_res, extra = (kwp, ksp, kzp, None, None, None), None, dict(
+            shared_kv=True, d_v=d)
+    else:
+        (q, kwp, ksp, kzp, vwp, vsp, vzp, k_res, v_res, table) = _make(
+            jax.random.PRNGKey(5), b=b, h=h, g=g, d=d, n_pages=n_pages, nb=nb,
+            block_n=block_n, bits=4, k_gran="channel")
+        pools, extra = (kwp, ksp, kzp, vwp, vsp, vzp), {}
+    pb = jnp.asarray([nb, nb - 2], jnp.int32)
+    rl = jnp.asarray([17, 5], jnp.int32)
+    fn = functools.partial(
+        pg_ops.paged_bitdecode_attention, bits=4, block_n=block_n,
+        k_gran="channel", return_lse=True, impl=impl, num_splits=num_splits,
+        **extra)
+    want = fn(q, *pools, k_res, v_res, table, pb, rl)
+    stacked = stack_at(jax.random.PRNGKey(6), pools, 3, layer)
+    got = fn(q, *stacked, k_res, v_res, table, pb, rl,
+             layer=jnp.int32(layer))
+    for w, o in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(o), np.asarray(w))

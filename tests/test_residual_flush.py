@@ -215,3 +215,43 @@ def test_hot_path_does_no_quant_work(quant_impl):
         for br in cond_eqn.params["branches"]
     ]
     assert sum(branch_has_quant) == 1, branch_has_quant
+
+
+# ------------------------------------------------- layer-stacked paged flush
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("shared_kv", [False, True])
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_stacked_paged_flush_writes_only_its_layer(impl, shared_kv, layer):
+    """The paged flush into layer ``layer`` of a 3-layer pool stack writes
+    exactly the pages of that layer its full rows flush to, as the flush
+    into that layer's own pools does; every other page of every layer, the
+    non-full row's scratch page included, stays bitwise as it was."""
+    from test_kernels_paged import stack_at
+
+    b, n_pages, n_layers = 3, 9, 3
+    one = qcache.init_paged_cache(n_pages, b, H, D, 4, bits=4, block_n=BLOCK,
+                                  shared_kv=shared_kv)
+    pools, _ = qcache.split_pools(one)
+    stacked = stack_at(jax.random.PRNGKey(1), pools, n_layers, layer)
+    pools = [None if p is None else p[layer] for p in stacked]  # non-zero
+    k = jax.random.normal(jax.random.PRNGKey(2), (b, H, BLOCK, D)).astype(jnp.bfloat16)
+    v = None if shared_kv else (k * 0.5 + 1).astype(jnp.bfloat16)
+    full = jnp.asarray([1, 0, 1], jnp.int32)
+    dest = jnp.asarray([7, 1, 5], jnp.int32)  # row 1 parks on its scratch page
+    kw = dict(bits=4, block_n=BLOCK, k_gran="channel", shared_kv=shared_kv,
+              impl=impl)
+    want = rf_ops.paged_residual_flush(*pools, k, v, full, dest, **kw)
+    got = rf_ops.paged_residual_flush(*stacked, k, v, full, dest,
+                                      layer=jnp.int32(layer), **kw)
+    written = np.zeros((n_layers, n_pages), bool)
+    written[layer, [7, 5]] = True
+    for before, w, g in zip(stacked, want, got):
+        if before is None:
+            assert w is None and g is None
+            continue
+        g, before = np.asarray(g), np.asarray(before)
+        np.testing.assert_array_equal(g[layer], np.asarray(w))
+        np.testing.assert_array_equal(g[~written], before[~written])
+        assert (g[written] != before[written]).any()

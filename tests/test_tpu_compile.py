@@ -5,14 +5,15 @@ refuses — block shapes that are no legal tile, unaligned slices, too much
 VMEM.  These tests compile each kernel for a *described* v5e chip (the TPU
 compiler runs here; nothing executes) and check that the program holds the
 Mosaic kernel.  Widths: B=4, H_kv=8, g=4 (32 q heads), d=128, 128-token
-blocks, a 512-page pool (8 slots x 8192 tokens), 4096-token prefill.
+blocks, a 512-page pool (8 slots x 8192 tokens) in each of 2 layers,
+4096-token prefill; and the benchmark cells' own shapes below.
 """
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import layout
+from repro.core import layout, qcache
 from repro.kernels.bitdecode import kernel as bd_kernel
 from repro.kernels.flash_prefill import kernel as fp_kernel
 from repro.kernels.kv_quant import kernel as kq_kernel
@@ -20,6 +21,7 @@ from repro.kernels.paged_bitdecode import kernel as pg_kernel
 from repro.kernels.residual_flush import kernel as rf_kernel
 
 B, H, G, D, BLOCK, PAGES, NB = 4, 8, 8, 128, 128, 520, 64  # G: g=4 padded to 8
+L = 2  # layers of the stacked pools
 I32, BF16 = jnp.int32, jnp.bfloat16
 
 
@@ -47,18 +49,56 @@ def _param_width(k_gran):
     return D if k_gran == "channel" else BLOCK
 
 
+def _pools(shape, *, layers, pages, h, d, bits=4, k_gran="channel", v=True):
+    """Layer-stacked page pools: K words, scale, zero (and the V side)."""
+    npr, p = layout.words_per_block(BLOCK, bits), d if k_gran == "channel" else BLOCK
+    lead = (layers, pages, h)
+    out = [shape((*lead, npr, d), I32), shape((*lead, p), BF16), shape((*lead, p), BF16)]
+    if v:
+        out += [shape((*lead, npr, d), I32), shape((*lead, BLOCK), BF16),
+                shape((*lead, BLOCK), BF16)]
+    return out
+
+
+def _hbm_copies(text, prefix):
+    """Copies into HBM of arrays whose type starts with ``prefix`` in a
+    compiled program's text (``copy``, and the destination of
+    ``copy-start``); moves into VMEM (``S(1)``) are the compiler's
+    prefetches, not copies of a buffer."""
+    out = []
+    for line in text.splitlines():
+        for op in (" copy(", " copy-start("):
+            if op in line:
+                dest = line.split(" = ", 1)[1].split(op)[0].lstrip("(")
+                if dest.startswith(prefix) and "S(1)" not in dest.split("}, ")[0]:
+                    out.append(line.strip()[:160])
+    return out
+
+
+def _compiles_aliased(fn, pools, *rest):
+    """``fn`` compiles to a Mosaic kernel that writes the donated ``pools``
+    in place: all their bytes alias, and the packed-word pools (the bulk of
+    them) are never copied.  (A standalone program may still relayout a
+    one-head pool of params between its parameter's layout and the
+    kernel's; the decode step does that once, outside its layer loop.)"""
+    donate = tuple(range(len(pools)))
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*pools, *rest).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    pool_bytes = sum(p.size * p.dtype.itemsize for p in pools)
+    assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
+    words = pools[0].shape
+    assert not _hbm_copies(text, f"s32[{words[0]},{words[1]},")
+
+
 @pytest.mark.parametrize("num_splits", [1, 4])
 @pytest.mark.parametrize("k_gran", ["channel", "tensor"])
 @pytest.mark.parametrize("bits", [4, 2])
 def test_paged_bitdecode(shape, bits, k_gran, num_splits):
-    npr, p = layout.words_per_block(BLOCK, bits), _param_width(k_gran)
     args = [shape((B, H, G, D), BF16),
-            shape((PAGES, H, npr, D), I32), shape((PAGES, H, p), BF16),
-            shape((PAGES, H, p), BF16),
-            shape((PAGES, H, npr, D), I32), shape((PAGES, H, BLOCK), BF16),
-            shape((PAGES, H, BLOCK), BF16),
+            *_pools(shape, layers=L, pages=PAGES, h=H, d=D, bits=bits, k_gran=k_gran),
             shape((B, H, BLOCK, D), BF16), shape((B, H, BLOCK, D), BF16),
-            shape((B, NB), I32), shape((B,), I32), shape((B,), I32)]
+            shape((B, NB), I32), shape((B,), I32), shape((B,), I32), shape((), I32)]
     _compiles_to_mosaic(
         lambda *a: pg_kernel.paged_bitdecode_attention_pallas(
             *a, bits=bits, block_n=BLOCK, sm_scale=D**-0.5, k_gran=k_gran,
@@ -69,17 +109,37 @@ def test_paged_bitdecode(shape, bits, k_gran, num_splits):
 @pytest.mark.parametrize("k_gran", ["channel", "tensor"])
 @pytest.mark.parametrize("bits", [4, 2])
 def test_paged_residual_flush(shape, bits, k_gran):
-    npr, p = layout.words_per_block(BLOCK, bits), _param_width(k_gran)
-    args = [shape((PAGES, H, npr, D), I32), shape((PAGES, H, p), BF16),
-            shape((PAGES, H, p), BF16),
-            shape((PAGES, H, npr, D), I32), shape((PAGES, H, BLOCK), BF16),
-            shape((PAGES, H, BLOCK), BF16),
-            shape((B, H, BLOCK, D), BF16), shape((B, H, BLOCK, D), BF16),
-            shape((B,), I32), shape((B,), I32)]
-    _compiles_to_mosaic(
+    _compiles_aliased(
         lambda *a: rf_kernel.paged_residual_flush_pallas(
             *a, bits=bits, block_n=BLOCK, k_gran=k_gran, interpret=False),
-        *args)
+        _pools(shape, layers=L, pages=PAGES, h=H, d=D, bits=bits, k_gran=k_gran),
+        shape((B, H, BLOCK, D), BF16), shape((B, H, BLOCK, D), BF16),
+        shape((B,), I32), shape((B,), I32), shape((), I32))
+
+
+# Qwen2-7B at the qwen2-7b.reason cell's shapes: 4 KV heads, g=7 padded to
+# 8, d=128; 32 slots, a 1,120-page pool (32 x 34 pages + 32 scratch) in each
+# of the stage's 14 layers, 4-bit, 128-token blocks
+QB, QH, QG, QP, QNB, QL = 32, 4, 8, 1120, 34, 14
+
+
+def test_qwen_stacked_paged_bitdecode(shape):
+    _compiles_to_mosaic(
+        lambda *a: pg_kernel.paged_bitdecode_attention_pallas(
+            *a, bits=4, block_n=BLOCK, sm_scale=D**-0.5, k_gran="channel",
+            num_splits=1, interpret=False),
+        shape((QB, QH, QG, D), BF16), *_pools(shape, layers=QL, pages=QP, h=QH, d=D),
+        shape((QB, QH, BLOCK, D), BF16), shape((QB, QH, BLOCK, D), BF16),
+        shape((QB, QNB), I32), shape((QB,), I32), shape((QB,), I32), shape((), I32))
+
+
+def test_qwen_stacked_paged_residual_flush(shape):
+    _compiles_aliased(
+        lambda *a: rf_kernel.paged_residual_flush_pallas(
+            *a, bits=4, block_n=BLOCK, k_gran="channel", interpret=False),
+        _pools(shape, layers=QL, pages=QP, h=QH, d=D),
+        shape((QB, QH, BLOCK, D), BF16), shape((QB, QH, BLOCK, D), BF16),
+        shape((QB,), I32), shape((QB,), I32), shape((), I32))
 
 
 @pytest.mark.parametrize("k_gran", ["channel", "tensor"])
@@ -157,34 +217,34 @@ def test_flash_prefill(shape):
 # DeepSeek-V3's latent path at the deepseek-v3.reason cell's shapes: one
 # latent stream (h=1) of 576 channels, stored lane-aligned in 640, feeding
 # g=128 query heads and a 512-channel value slice; 32 slots, a 1,120-page
-# pool (32 x 34 pages + 32 scratch), 4-bit, 128-token blocks
-LB, LP, LNB, LD, LDV, LG = 32, 1120, 34, 640, 512, 128
+# pool (32 x 34 pages + 32 scratch) in each of the 7 MoE layers, 4-bit,
+# 128-token blocks
+LB, LP, LNB, LD, LDV, LG, LL = 32, 1120, 34, 640, 512, 128, 7
 
 
 def _latent_pools(shape, d=LD):
-    npr = layout.words_per_block(BLOCK, 4)
-    return [shape((LP, 1, npr, d), I32), shape((LP, 1, d), BF16), shape((LP, 1, d), BF16)]
+    return _pools(shape, layers=LL, pages=LP, h=1, d=d, v=False)
 
 
 def test_latent_paged_bitdecode(shape):
     args = [shape((LB, 1, LG, LD), BF16), *_latent_pools(shape),
             shape((LB, 1, BLOCK, LD), BF16), shape((LB, LNB), I32), shape((LB,), I32),
-            shape((LB,), I32)]
+            shape((LB,), I32), shape((), I32)]
     _compiles_to_mosaic(
-        lambda q, kw, ks, kz, kr, pt, pb, rl: pg_kernel.paged_bitdecode_attention_pallas(
-            q, kw, ks, kz, None, None, None, kr, None, pt, pb, rl, bits=4,
+        lambda q, kw, ks, kz, kr, pt, pb, rl, ly: pg_kernel.paged_bitdecode_attention_pallas(
+            q, kw, ks, kz, None, None, None, kr, None, pt, pb, rl, ly, bits=4,
             block_n=BLOCK, sm_scale=0.1, k_gran="channel", shared_kv=True, d_v=LDV,
             num_splits=1, interpret=False),
         *args)
 
 
 def test_latent_paged_residual_flush(shape):
-    _compiles_to_mosaic(
-        lambda kw, ks, kz, kr, full, dest: rf_kernel.paged_residual_flush_pallas(
-            kw, ks, kz, None, None, None, kr, None, full, dest, bits=4, block_n=BLOCK,
-            k_gran="channel", shared_kv=True, interpret=False),
-        *_latent_pools(shape), shape((LB, 1, BLOCK, LD), BF16), shape((LB,), I32),
-        shape((LB,), I32))
+    _compiles_aliased(
+        lambda kw, ks, kz, kr, full, dest, ly: rf_kernel.paged_residual_flush_pallas(
+            kw, ks, kz, None, None, None, kr, None, full, dest, ly, bits=4,
+            block_n=BLOCK, k_gran="channel", shared_kv=True, interpret=False)[:3],
+        _latent_pools(shape), shape((LB, 1, BLOCK, LD), BF16), shape((LB,), I32),
+        shape((LB,), I32), shape((), I32))
 
 
 def test_latent_kv_quant(shape):
@@ -221,16 +281,56 @@ def test_latent_walk_copies_no_pool(width, copies):
     from repro.kernels.paged_bitdecode import ops as pg_ops
 
     sds = jax.ShapeDtypeStruct
-    npr = layout.words_per_block(BLOCK, 4)
     jaxpr = jax.make_jaxpr(
         lambda q, kw, ks, kz, kr, pt, pb, rl: pg_ops.paged_bitdecode_attention(
             q, kw, ks, kz, None, None, None, kr, None, pt, pb, rl, bits=4,
             block_n=BLOCK, sm_scale=0.1, k_gran="channel", shared_kv=True, d_v=LDV,
-            impl="pallas", num_splits=1))(
-        sds((LB, 1, LG, width), BF16), sds((LP, 1, npr, width), I32),
-        sds((LP, 1, width), BF16), sds((LP, 1, width), BF16),
+            impl="pallas", num_splits=1, layer=3))(
+        sds((LB, 1, LG, width), BF16), *_latent_pools(sds, width),
         sds((LB, 1, BLOCK, width), BF16), sds((LB, LNB), I32), sds((LB,), I32),
         sds((LB,), I32))
     pool_sized = [e for e in jaxpr.jaxpr.eqns if e.primitive.name != "pallas_call"
-                  and any(getattr(v.aval, "shape", ())[:1] == (LP,) for v in e.outvars)]
+                  and any(LP in getattr(v.aval, "shape", ()) for v in e.outvars)]
     assert bool(pool_sized) == copies, pool_sized
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it (scan and
+    cond bodies), but not inside a ``pallas_call``."""
+    from jax.extend import core as jc
+
+    for e in jaxpr.eqns:
+        yield e
+        if e.primitive.name == "pallas_call":
+            continue
+        for val in e.params.values():
+            for w in val if isinstance(val, (tuple, list)) else (val,):
+                if isinstance(w, jc.ClosedJaxpr):
+                    yield from _eqns(w.jaxpr)
+                elif isinstance(w, jc.Jaxpr):
+                    yield from _eqns(w)
+
+
+@pytest.mark.parametrize("arch", ["llama3-8b", "deepseek-v3-671b"], ids=["attn", "mla"])
+def test_decode_step_copies_no_layer_pool(arch):
+    """The decode layer scan hands the kernels the layer-stacked pools and a
+    layer index: outside a Pallas kernel, no equation of the decode step
+    (the scan bodies and the flush gate included) makes an array of one
+    layer's pool shape, which a slice of the stack or a write-back would."""
+    from repro.configs.base import smoke_config
+    from repro.models.zoo import build_model
+
+    cfg = smoke_config(arch).with_(kv_bits=4, kv_block=32)
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    state = jax.eval_shape(lambda: model.init_paged_decode_state(2, n_pages=11, nb_max=3))
+    layer_pools = {p.shape[1:] for p in qcache.split_pools(state["caches"][-1])[0]
+                   if p is not None}
+    assert all(s[0] == 11 for s in layer_pools)
+    jaxpr = jax.make_jaxpr(lambda p, s, t: model.decode_step(
+        p, s, t, impl="pallas", quant_impl="pallas"))(
+        params, state, jax.ShapeDtypeStruct((2, 1), I32))
+    made = [(e.primitive.name, v.aval.shape) for e in _eqns(jaxpr.jaxpr)
+            if e.primitive.name != "pallas_call" for v in e.outvars
+            if getattr(v.aval, "shape", None) in layer_pools]
+    assert not made, made
