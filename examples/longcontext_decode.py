@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import smoke_config
+from repro.core import qcache
 from repro.models.zoo import build_model
 
 
@@ -36,9 +37,9 @@ def main():
         logits, state = jax.jit(lambda p, b: model.prefill(p, b, 640))(
             params, {"tokens": prompt})
         tok = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
-        ids, logps = decode_n(model, params, state, tok, 24)
-        results[bits] = (ids, logps)
         cache = state["caches"][0]
+        ids, logps = decode_n(model, params, qcache.to_pages(state), tok, 24)
+        results[bits] = (ids, logps)
         kv_bytes = cache.kw.size * 4 * 2 + cache.k_res.size * 2 * 2
         print(f"int{bits}: cache≈{kv_bytes/1e6:.2f}MB  first tokens {ids[:8]}")
 

@@ -1,7 +1,7 @@
 """Quickstart: build a small llama-family model, prefill a prompt into the
-quantized KV cache, and greedily decode a few tokens — the minimal
-BitDecoding pipeline (query transform -> residual append -> fused low-bit
-attention).
+quantized KV cache, hand it to the paged decode layout, and greedily decode
+a few tokens — the minimal BitDecoding pipeline (query transform -> residual
+append -> fused low-bit attention).
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import smoke_config
+from repro.core import qcache
 from repro.models.zoo import build_model
 
 
@@ -28,6 +29,7 @@ def main():
           f"(packed blocks={int(cache0.pack_blocks[0, 0])}, "
           f"residual={int(cache0.res_len[0, 0])})")
 
+    state = qcache.to_pages(state)  # decode reads pages (identity page table)
     step = jax.jit(model.decode_step)
     tok = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
     out = [int(tok[0, 0])]

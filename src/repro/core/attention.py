@@ -16,8 +16,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import qcache
-from repro.core.qcache import PagedQuantKVCache, QuantKVCache
-from repro.kernels.bitdecode import ops as bd_ops
+from repro.core.qcache import PagedQuantKVCache
 from repro.kernels.paged_bitdecode import ops as pg_ops
 
 MASK_VALUE = -1e37
@@ -40,7 +39,7 @@ def inverse_query_transform(o: jax.Array) -> jax.Array:
 
 
 # Split-KV (sequence-parallel) decode context: when set, decode_attention
-# routes through dist.splitkv with the packed cache sharded along blocks.
+# routes through dist.splitkv with the page-table walk sharded along blocks.
 # page_affine additionally declares the pools' leading (page) axis sharded
 # along the same mesh axis (page-affine allocator — serve/pages.py), so the
 # walk reads each page only on the chip that stores it.
@@ -100,7 +99,7 @@ class masked_append:
 class use_draft:
     """Switch decode attention to the speculative *draft* read path: the
     packed cache is dequantized at ``bits`` (truncated-bit read, see
-    ``kernels/bitdecode/ref._dequant_blocks``) and appends are residual-only
+    ``kernels/paged_bitdecode/ref._dequant_blocks``) and appends are residual-only
     (``qcache.draft_append`` — no flush, pools untouched).  Draft state is
     discarded after the verify step, so the committed cache is read-only
     here.  Forces the XLA reference kernels and bypasses split-KV routing.
@@ -121,7 +120,7 @@ class use_draft:
 
 def decode_attention(
     q: jax.Array,  # [B, 1, h_q, d_k]
-    cache: QuantKVCache,
+    cache: PagedQuantKVCache,
     *,
     sm_scale: float | None = None,
     d_v: int | None = None,
@@ -129,73 +128,31 @@ def decode_attention(
     num_splits: int | str | None = "auto",
     return_lse: bool = False,
 ):
-    """Low-bit fused decode attention against a QuantKVCache.
+    """Low-bit fused decode attention against a PagedQuantKVCache: the
+    page-table walk of ``kernels/paged_bitdecode``.
 
     Split-KV decode is two-level:
 
-    * **in-kernel** (``num_splits``): the packed-block walk becomes an extra
+    * **in-kernel** (``num_splits``): the page-table walk becomes an extra
       parallel grid dimension with per-split (o, lse) partials and a fused
       logsumexp merge.  ``"auto"`` applies the heuristic in
-      ``kernels/bitdecode/ops.auto_num_splits``: split only when ``B x H_kv``
-      underfills the chip's parallel grid slots (the single-batch
-      long-context regime — e.g. B=1, H_kv=2 at 128K) AND the sequence is
-      long enough that each split owns >= 2 packed blocks; batch-heavy
+      ``kernels/paged_bitdecode/ops.auto_num_splits``: split only when
+      ``B x H_kv`` underfills the chip's parallel grid slots (the
+      single-batch long-context regime — e.g. B=1, H_kv=2 at 128K) AND the
+      sequence is long enough that each split owns >= 2 pages; batch-heavy
       serving shapes keep ``num_splits = 1`` and pay nothing.
-    * **cross-chip** (:class:`use_splitkv`): the packed cache is sharded
+    * **cross-chip** (:class:`use_splitkv`): the page-table walk is sharded
       along a mesh axis and per-chip partials merge with the same lse math
-      (repro.dist.splitkv).  Both levels compose.
+      (``dist.splitkv.splitkv_paged_decode_attention``).  Both levels
+      compose.
 
-    ``cache`` may be a dense :class:`QuantKVCache` or a paged
-    :class:`PagedQuantKVCache` (serving engine layout): the paged route runs
-    ``kernels/paged_bitdecode`` over the cache's page table, with the same
-    two split-KV levels (in-kernel ``num_splits``; cross-chip page-table-walk
-    sharding via ``dist.splitkv.splitkv_paged_decode_attention``).
-    """
-    if isinstance(cache, PagedQuantKVCache):
-        return _paged_decode_attention(
-            q, cache, sm_scale=sm_scale, d_v=d_v, impl=impl,
-            num_splits=num_splits, return_lse=return_lse,
-        )
-    draft_bits = _SPEC["draft_bits"]
-    if draft_bits is None and _SPLITKV["mesh"] is not None and not return_lse:
-        from repro.dist import splitkv as _sk
-
-        return _sk.splitkv_decode_attention(
-            q, cache, _SPLITKV["mesh"], axis=_SPLITKV["axis"],
-            sm_scale=sm_scale, d_v=d_v, impl=impl, num_splits=num_splits,
-        )
-    h_kv = cache.kw.shape[1]
-    qt = query_transform(q, h_kv)
-    out = bd_ops.bitdecode_attention(
-        qt, cache.kw, cache.k_scale, cache.k_zero,
-        cache.vw, cache.v_scale, cache.v_zero,
-        cache.k_res, cache.v_res, cache.pack_blocks, cache.res_len,
-        bits=cache.bits, block_n=cache.block_n, sm_scale=sm_scale,
-        k_gran=cache.k_gran, shared_kv=cache.shared_kv, d_v=d_v,
-        impl=impl, num_splits=num_splits, return_lse=return_lse,
-        draft_bits=draft_bits,
-    )
-    if return_lse:
-        o, lse = out
-        return inverse_query_transform(o), lse
-    return inverse_query_transform(out)
-
-
-def _paged_decode_attention(
-    q: jax.Array,  # [B, 1, h_q, d_k]
-    cache: PagedQuantKVCache,
-    *,
-    sm_scale: float | None,
-    d_v: int | None,
-    impl: str,
-    num_splits,
-    return_lse: bool,
-):
-    """Paged decode dispatch: page-table walk through kernels/paged_bitdecode
-    (or, under :class:`use_splitkv`, the table walk sharded across chips).
     ``d_v`` is required for shared_kv (MLA latent) caches — the V width is a
     channel slice of the latent, not a stored pool dimension.  A layer view
-    (``cache.layer`` set) is read in place from the layer-stacked pools."""
+    (``cache.layer`` set) is read in place from the layer-stacked pools.  A
+    prefill's dense :class:`~repro.core.qcache.QuantKVCache` raises a
+    ``TypeError``: convert it once with ``qcache.to_pages``.
+    """
+    qcache.require_pages(cache)
     draft_bits = _SPEC["draft_bits"]
     if draft_bits is None and _SPLITKV["mesh"] is not None and not return_lse:
         from repro.dist import splitkv as _sk
@@ -225,7 +182,7 @@ def _paged_decode_attention(
 
 def decode_append_attention(
     q: jax.Array,  # [B, 1, h_q, d_k]
-    cache: QuantKVCache | PagedQuantKVCache,
+    cache: PagedQuantKVCache,
     k_new: jax.Array,  # [B, H, 1, d_k]
     v_new: jax.Array | None,  # None when shared_kv
     *,
@@ -234,17 +191,14 @@ def decode_append_attention(
 ):
     """The per-token serving hot path in one call: append the new KV token to
     the cache (residual write + gated residual-flush kernel, see
-    ``qcache.append_decode`` / ``qcache.paged_append_decode``) and run fused
-    low-bit decode attention over the updated cache.  Returns
-    ``(out, cache)``.
+    ``qcache.paged_append_decode``) and run fused low-bit decode attention
+    over the updated cache.  Returns ``(out, cache)``.
 
     ``quant_impl`` selects the flush implementation
     ('auto' | 'pallas' | 'xla'); ``attn_kwargs`` are forwarded to
     :func:`decode_attention` (``impl``, ``num_splits``, ``sm_scale``,
     ``d_v``, ...).  Model blocks (models/attention.py, models/mla.py) route
-    through here so the engine's impl switches reach both kernels, and the
-    dense/paged choice follows the cache type — the serving engine swaps the
-    decode state for a paged one and the model code never changes.
+    through here so the engine's impl switches reach both kernels.
 
     Under :class:`use_splitkv` the paged flush runs per chip as well
     (``dist.splitkv.splitkv_paged_flush``).
@@ -254,9 +208,10 @@ def decode_append_attention(
     dequantizes at the truncated draft bit-width; under :class:`masked_append`
     frozen lanes skip the append bitwise (multi-token verify).
     """
+    qcache.require_pages(cache)
     if _SPEC["draft_bits"] is not None:
         cache = qcache.draft_append(cache, k_new, v_new)
-    elif isinstance(cache, PagedQuantKVCache):
+    else:
         flush = {}
         if _SPLITKV["mesh"] is not None:
             from repro.dist import splitkv as _sk
@@ -268,10 +223,6 @@ def decode_append_attention(
         cache = qcache.paged_append_decode(
             cache, k_new, v_new, quant_impl=quant_impl, mask=_SPEC["mask"],
             **flush,
-        )
-    else:
-        cache = qcache.append_decode(
-            cache, k_new, v_new, quant_impl=quant_impl, mask=_SPEC["mask"]
         )
     return decode_attention(q, cache, **attn_kwargs), cache
 

@@ -10,13 +10,14 @@ flush is gated so the other ``block_n - 1`` decode steps do no quantization
 work.  ``shared_kv=True`` stores a single latent stream (MLA mode) — no
 V-side fields.
 
-Two at-rest layouts share this data model: the dense :class:`QuantKVCache`
-(``[B, H, nb, ...]``, one private block range per sequence) and the paged
-:class:`PagedQuantKVCache` (shared ``[P, H, ...]`` page pools walked through
-per-sequence page tables — the serving engine's layout, allocated by
-serve/pages.py).  Both append paths run the fused flush: the dense one
-behind a ``lax.cond`` on any full row, the paged one gated row by row inside
-the flush (see :func:`paged_append_decode`).
+Two at-rest layouts share this data model.  The dense :class:`QuantKVCache`
+(``[B, H, nb, ...]``, one private block range per sequence) is what prefill
+writes.  Decode reads only the paged :class:`PagedQuantKVCache` (shared
+``[P, H, ...]`` page pools walked through per-sequence page tables): the
+serving engine splices prefills into its pools (serve/pages.py), and other
+callers hand a prefill's state to :func:`to_pages` once.  The decode append
+(:func:`paged_append_decode`) runs the fused flush, gated row by row inside
+the flush.
 
 See docs/ARCHITECTURE.md for the packed ``(words, scale, zero)`` layout spec.
 """
@@ -74,6 +75,17 @@ jax.tree_util.register_dataclass(
 )
 
 
+def n_blocks(max_seq: int, block_n: int, block_align: int | None = None) -> int:
+    """Packed blocks that hold ``max_seq`` tokens, rounded up to a multiple
+    of ``block_align`` (normally the split-KV mesh-axis size, see
+    :func:`splitkv_block_align`) so a sharded page-table walk splits the
+    block axis evenly, with no per-call pad."""
+    nb = max(1, -(-max_seq // block_n))
+    if block_align and block_align > 1:
+        nb = -(-nb // block_align) * block_align
+    return nb
+
+
 def init_cache(
     batch: int,
     h_kv: int,
@@ -89,17 +101,9 @@ def init_cache(
     res_dtype=jnp.bfloat16,
     block_align: int | None = None,
 ) -> QuantKVCache:
-    """Allocate an empty cache with capacity >= max_seq tokens.
-
-    ``block_align`` rounds the packed block count ``nb`` up to a multiple
-    (normally the split-KV mesh-axis size, plumbed through
-    ``model.init_decode_state(..., mesh=...)``) so ``dist.splitkv`` shards the
-    block axis without its per-call zero-pad — which is otherwise a full
-    cache copy every decoded token when ``nb % axis_size != 0``.
-    """
-    nb = max(1, -(-max_seq // block_n))
-    if block_align and block_align > 1:
-        nb = -(-nb // block_align) * block_align
+    """Allocate an empty cache with capacity >= max_seq tokens
+    (``nb = n_blocks(max_seq, block_n, block_align)``)."""
+    nb = n_blocks(max_seq, block_n, block_align)
     npr = layout.words_per_block(block_n, bits)
     if k_gran == "channel":
         kp_shape = (batch, h_kv, nb, d_k)
@@ -130,8 +134,8 @@ def init_cache(
 
 def _append_residual(cache: QuantKVCache, k_new, v_new, mask=None):
     """Write one new token per sequence into the residual buffers.  Returns
-    (k_res, v_res, res_len_after, full) — the shared front half of both
-    append paths.
+    (k_res, v_res, res_len_after, full) — the shared front half of
+    :func:`paged_append_decode` and :func:`draft_append`.
 
     ``mask`` ([B] bool, optional) freezes sequences: a ``False`` lane keeps
     its residual rows and ``res_len`` bitwise unchanged (``jnp.where`` with a
@@ -159,106 +163,10 @@ def _append_residual(cache: QuantKVCache, k_new, v_new, mask=None):
     return k_res, v_res, rl, rl == cache.block_n
 
 
-def _commit_append(cache: QuantKVCache, packed, k_res, v_res, full, rl):
-    """Shared back half of both append paths: write the (possibly flushed)
-    packed arrays and update occupancy.  ``packed`` is the six packed fields
-    in dataclass order (V side None when shared_kv)."""
-    kw, ks, kz, vw, vs, vz = packed
-    return dataclasses.replace(
-        cache, kw=kw, k_scale=ks, k_zero=kz, vw=vw, v_scale=vs, v_zero=vz,
-        k_res=k_res, v_res=v_res,
-        pack_blocks=jnp.where(full, cache.pack_blocks + 1, cache.pack_blocks),
-        res_len=jnp.where(full, 0, rl),
-    )
-
-
-def append_decode(
-    cache: QuantKVCache,
-    k_new: jax.Array,  # [B, H, 1, d_k]
-    v_new: jax.Array | None,  # [B, H, 1, d_v]; None when shared_kv
-    *,
-    quant_impl: str = "auto",
-    mask=None,
-) -> QuantKVCache:
-    """Append one decoded token per sequence; flush the residual block when
-    full (paper: "Once per token generation, the Residual Kernel ... optionally
-    quantizes it (when res_len = N_r) into packed format").
-
-    The flush is *gated*: the fused residual-flush kernel
-    (kernels/residual_flush) runs under a ``lax.cond`` taken only when some
-    sequence's residual just filled — 1 step in ``block_n``.  On the other
-    ``block_n - 1`` steps the hot path is exactly one token-row write into
-    the bf16 residual plus the occupancy update; no quantization, packing,
-    or packed-cache traffic at all (previously the whole residual block was
-    re-quantized speculatively every token — kept as
-    :func:`append_decode_speculative` for benchmarking).
-
-    quant_impl: 'auto' | 'pallas' | 'xla', forwarded to
-    ``residual_flush.ops.residual_flush``.
-
-    ``mask`` ([B] bool, optional): lanes with ``mask=False`` keep the cache
-    bitwise unchanged (no residual write, no occupancy change; a concurrent
-    flush of *other* lanes selects the frozen lane's old block back — the
-    same non-full select the gated flush always performs).  See
-    :func:`_append_residual`.
-    """
-    k_res, v_res, rl, full = _append_residual(cache, k_new, v_new, mask)
-
-    if cache.shared_kv:
-        packed = (cache.kw, cache.k_scale, cache.k_zero)
-    else:
-        packed = (cache.kw, cache.k_scale, cache.k_zero,
-                  cache.vw, cache.v_scale, cache.v_zero)
-
-    def flush(p):
-        if cache.shared_kv:
-            kw, ks, kz = p
-            vw = vs = vz = None
-        else:
-            kw, ks, kz, vw, vs, vz = p
-        out = rf_ops.residual_flush(
-            kw, ks, kz, vw, vs, vz, k_res, v_res,
-            full.astype(jnp.int32), cache.pack_blocks,
-            bits=cache.bits, block_n=cache.block_n, k_gran=cache.k_gran,
-            shared_kv=cache.shared_kv, impl=quant_impl,
-        )
-        return out[:3] if cache.shared_kv else out
-
-    packed = lax.cond(jnp.any(full), flush, lambda p: p, packed)
-    if cache.shared_kv:
-        packed = (*packed, None, None, None)
-    return _commit_append(cache, packed, k_res, v_res, full, rl)
-
-
-def append_decode_speculative(
-    cache: QuantKVCache,
-    k_new: jax.Array,  # [B, H, 1, d_k]
-    v_new: jax.Array | None,  # [B, H, 1, d_v]; None when shared_kv
-    *,
-    quant_impl: str = "xla",
-) -> QuantKVCache:
-    """Pre-fusion append path: the flush op runs *unconditionally* on every
-    decoded token (no ``lax.cond`` gate), re-quantizing the whole residual
-    block and select-committing at block granularity each step.  Kept as the
-    baseline for bench_quant_overhead's flush-vs-speculative sweep and as a
-    second oracle for the gated path — identical cache contents by
-    construction, since both call the same flush op and a non-full sequence
-    selects its old block back."""
-    k_res, v_res, rl, full = _append_residual(cache, k_new, v_new)
-    packed = rf_ops.residual_flush(
-        cache.kw, cache.k_scale, cache.k_zero,
-        cache.vw, cache.v_scale, cache.v_zero,
-        k_res, v_res, full.astype(jnp.int32), cache.pack_blocks,
-        bits=cache.bits, block_n=cache.block_n, k_gran=cache.k_gran,
-        shared_kv=cache.shared_kv, impl=quant_impl,
-    )
-    return _commit_append(cache, packed, k_res, v_res, full, rl)
-
-
 def splitkv_block_align(mesh, axis: str | None) -> int | None:
     """Block-axis alignment implied by a split-KV mesh axis (None when no
     mesh / unknown axis) — the ``block_align`` to pass to :func:`init_cache`
-    so ``dist.splitkv`` never zero-pads the packed-block axis per call."""
+    and :func:`n_blocks`."""
     if mesh is None or axis is None or axis not in mesh.axis_names:
         return None
     return int(mesh.shape[axis])
@@ -352,8 +260,7 @@ class PagedQuantKVCache:
 
     ``shared_kv=True`` (the MLA latent mode) pages a *single* quantized
     latent stream: the V-side pools and residual are ``None`` and the decode
-    kernel slices V out of the dequantized K tile, exactly as the dense
-    shared mode does (kernels/paged_bitdecode).
+    kernel slices V out of the dequantized K tile (kernels/paged_bitdecode).
 
     ``layer`` (int32 scalar, optional) makes the cache one layer's *view* of
     layer-stacked pools: the pool fields are ``[L, P, H, ...]`` and the
@@ -478,9 +385,9 @@ def paged_append_decode(
     The flush is called on every step and gates itself row by row
     (``residual_flush.ops.paged_residual_flush``): the kernel quantizes only
     full rows, and a non-full row copies its scratch page back.  A
-    ``lax.cond`` around the call, as the dense :func:`append_decode` has,
-    would make the TPU compiler move the layer-stacked pools out of fast
-    memory before it and back after it, in every layer of every step.
+    ``lax.cond`` around the call would make the TPU compiler move the
+    layer-stacked pools out of fast memory before it and back after it, in
+    every layer of every step.
 
     The flush destination per sequence is ``page_table[b, pack_blocks[b]]``
     when its residual filled, else the slot's scratch page ``b`` (keeps the
@@ -519,6 +426,59 @@ def paged_append_decode(
     )
 
 
+def identity_table(shape) -> jax.Array:
+    """The identity page table of ``shape`` (``[*lead, B, nb]``): sequence
+    ``b``'s block ``j`` on page ``B + b*nb + j``, after the ``B`` scratch
+    pages — a dense cache's blocks laid out in ``B + B*nb`` pages."""
+    b, nb = shape[-2:]
+    table = b + jnp.arange(b * nb, dtype=jnp.int32).reshape(b, nb)
+    return jnp.broadcast_to(table, shape)
+
+
+def to_pages(x):
+    """The prefill→decode handoff for callers outside the serving engine:
+    every :class:`QuantKVCache` in the pytree ``x`` (one layer's, or stacked
+    over leading dims) becomes a :class:`PagedQuantKVCache` holding the same
+    data under an :func:`identity_table`, in ``B + B*nb`` pool pages whose
+    first ``B`` are the slots' zeroed scratch pages.  Residuals and
+    occupancy carry over.  It copies every packed block once: call it once
+    per handoff, never per step."""
+
+    def one(c):
+        if not isinstance(c, QuantKVCache):
+            return c
+        lead = c.kw.ndim - 5
+        b, nb = c.kw.shape[lead], c.kw.shape[lead + 2]
+
+        def pool(x):  # [*lead, B, H, nb, ...] -> [*lead, B + B*nb, H, ...]
+            if x is None:
+                return None
+            x = jnp.moveaxis(x, lead + 2, lead + 1)
+            x = x.reshape(*x.shape[:lead], b * nb, *x.shape[lead + 2:])
+            scratch = jnp.zeros((*x.shape[:lead], b, *x.shape[lead + 1:]), x.dtype)
+            return jnp.concatenate([scratch, x], axis=lead)
+
+        return PagedQuantKVCache(
+            *(pool(getattr(c, f)) for f in _PAGED_POOL_FIELDS),
+            k_res=c.k_res, v_res=c.v_res,
+            page_table=identity_table((*c.kw.shape[:lead], b, nb)),
+            pack_blocks=c.pack_blocks, res_len=c.res_len,
+            bits=c.bits, block_n=c.block_n, k_gran=c.k_gran, shared_kv=c.shared_kv,
+        )
+
+    return jax.tree.map(one, x, is_leaf=lambda c: isinstance(c, QuantKVCache))
+
+
+def require_pages(cache) -> PagedQuantKVCache:
+    """``cache`` if decode can read it; a prefill's :class:`QuantKVCache`
+    raises, naming the conversion."""
+    if not isinstance(cache, PagedQuantKVCache):
+        raise TypeError(
+            f"decode reads a PagedQuantKVCache, not a {type(cache).__name__}: "
+            "hand model.prefill's state to qcache.to_pages first")
+    return cache
+
+
 # --------------------------------------------------------------------------
 # Speculative-draft residual helpers (QuantSpec-style self-speculation)
 # --------------------------------------------------------------------------
@@ -533,7 +493,7 @@ def widen_residual(cache, extra: int):
     appends in-bounds when ``res_len`` starts near ``block_n``; the decode
     references read the residual capacity from ``k_res.shape[2]`` and mask by
     ``res_len``, so a wider residual changes nothing numerically.  Works on
-    dense and paged caches, including layer-stacked serving state.
+    layer-stacked serving state.
     """
     if extra <= 0:
         return cache
@@ -554,7 +514,7 @@ def draft_append(cache, k_new, v_new):
     token row and bump ``res_len`` — no flush, no pool/packed-cache traffic,
     no ``pack_blocks`` change.  The caller guarantees capacity via
     :func:`widen_residual`; draft state is discarded after verification, so
-    committed blocks are never touched.  Dense and paged caches alike.
+    committed blocks are never touched.
     """
     k_res, v_res, rl, _ = _append_residual(cache, k_new, v_new)
     return dataclasses.replace(cache, k_res=k_res, v_res=v_res, res_len=rl)

@@ -6,11 +6,11 @@ Modules:
     plus :func:`constrain`, the activation sharding-constraint helper used
     by the models and the train step;
   * :mod:`repro.dist.state_specs` — PartitionSpec trees for decode state
-    (dense QuantKVCache and paged PagedQuantKVCache placement, incl. the
-    split-KV block-axis / page-table-column sharding);
+    (PagedQuantKVCache placement, incl. the split-KV page-table-column and
+    page-affine pool sharding);
   * :mod:`repro.dist.splitkv`     — sequence-parallel decode across a mesh
-    axis with the logsumexp partials merge (FlashDecoding across chips),
-    for both the dense block-sharded and paged table-walk-sharded layouts.
+    axis with the logsumexp partials merge (FlashDecoding across chips):
+    the page-table walk sharded, and the paged flush under ``shard_map``.
 """
 from __future__ import annotations
 

@@ -1,12 +1,12 @@
 """Cross-chip split-KV decode: FlashDecoding partitioning across a mesh axis.
 
-The single-chip kernel already splits the packed-block walk across its grid
-(kernels/bitdecode, ``num_splits``); this module is the level above: the
-packed cache is sharded *across chips* along the block axis of a mesh axis
-(normally "data", which carries no batch at the long-context small-batch
-shapes — see launch/mesh.pick_batch_axes), every chip runs the local fused
-kernel over its shard, and the per-chip partials are combined with the
-logsumexp merge specified by tests/test_splitkv_math.py:
+The single-chip kernel already splits the page-table walk across its grid
+(kernels/paged_bitdecode, ``num_splits``); this module is the level above:
+the walk is sharded *across chips* along the table's block axis over a mesh
+axis (normally "data", which carries no batch at the long-context
+small-batch shapes — see launch/mesh.pick_batch_axes), every chip runs the
+local fused kernel over its slice, and the per-chip partials are combined
+with the logsumexp merge specified by tests/test_splitkv_math.py:
 
     m = max_i lse_i;  w_i = exp(lse_i - m);  out = sum_i w_i o_i / sum_i w_i
 
@@ -15,15 +15,6 @@ tokens; the kernel's finalize l=0 guard emits lse ~ -inf, so its weight
 underflows to exactly 0 and the merge is unaffected.  The bf16 residual tail
 is replicated and processed by the *last* shard only (it usually owns the
 fewest valid blocks, so the extra block balances the walk).
-
-Padding: this module shards dim 2 (the packed-block axis ``nb``) of every
-packed cache field — ``kw [B, H, nb, npr, d]`` and the ``[B, H, nb, …]``
-scale/zero arrays (layout spec: docs/ARCHITECTURE.md §2).  When
-``nb % axis_size != 0`` the axis is zero-padded *per call* before the
-shard_map; padded blocks sit beyond ``pack_blocks`` so they are never read
-as valid, but the pad is a full-cache copy every decode step at that shape —
-size caches so ``axis_size`` divides ``nb`` (ROADMAP: mesh-aligned cache
-allocation).  Queries, residuals, and occupancy counters are replicated.
 
 Mesh axes are *physical* names here (normally ``"data"``) — the logical-axis
 indirection of dist.sharding applies to parameters, not to this explicitly
@@ -34,11 +25,11 @@ Merge math and diagrams: docs/ARCHITECTURE.md §5.  Wired in through
 lowering the long-context decode cells and the serve engine enters for its
 split-KV decode step.
 
-Paged twin: :func:`splitkv_paged_decode_attention` shards the page-table
-*walk* (not the pools) for PagedQuantKVCache states — see its docstring and
-docs/ARCHITECTURE.md §7.  :func:`splitkv_paged_flush` runs the decode
-flush on the same pools: Mosaic kernels are never partitioned by the
-compiler, so every kernel of a step on a mesh runs under ``shard_map``.
+:func:`splitkv_paged_decode_attention` shards the page-table *walk* (and,
+page-affine, the pools) — see its docstring and docs/ARCHITECTURE.md §7.
+:func:`splitkv_paged_flush` runs the decode flush on the same pools: Mosaic
+kernels are never partitioned by the compiler, so every kernel of a step on
+a mesh runs under ``shard_map``.
 """
 from __future__ import annotations
 
@@ -47,7 +38,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as PS
 
-from repro.kernels.bitdecode import ops as bd_ops
 from repro.kernels.paged_bitdecode import ops as pg_ops
 from repro.kernels.residual_flush import ops as rf_ops
 
@@ -63,101 +53,6 @@ def merge_collective(o, lse, axis: str):
     num = lax.psum(w[..., None] * o, axis)
     den = lax.psum(w, axis)
     return num / jnp.maximum(den, 1e-30)[..., None]
-
-
-def _pad_block_axis(x, pad: int):
-    """Zero-pad the packed-block axis (dim 2 of [B, H, nb, ...]) so it splits
-    evenly across the mesh axis.  Padded blocks sit beyond pack_blocks and
-    are never read as valid.
-
-    NB: when nb is not already a multiple of the axis size this copies the
-    cache every call — size caches so nb divides the split axis (ROADMAP:
-    mesh-aligned cache allocation)."""
-    if not pad or x is None:
-        return x
-    cfg = [(0, 0)] * x.ndim
-    cfg[2] = (0, pad)
-    return jnp.pad(x, cfg)
-
-
-def splitkv_decode_attention(
-    q,
-    cache,
-    mesh,
-    *,
-    axis: str = "data",
-    sm_scale: float | None = None,
-    d_v: int | None = None,
-    impl: str = "auto",
-    num_splits: int | str | None = "auto",
-):
-    """Sequence-parallel decode attention against a block-sharded QuantKVCache.
-
-    q: [B, 1, h_q, d_k] (model layout; the query transformation happens
-    here).  Returns [B, 1, h_q, d_v], replicated along ``axis``.  Composes
-    with the in-kernel split: each shard's local kernel may further split its
-    block range (``num_splits``), giving mesh x grid sequence parallelism.
-    """
-    from repro.core.attention import inverse_query_transform, query_transform
-
-    if axis not in mesh.axis_names:
-        raise ValueError(
-            f"mesh has no axis {axis!r}; available: {tuple(mesh.axis_names)}"
-        )
-    n = mesh.shape[axis]
-    h_kv = cache.kw.shape[1]
-    qt = query_transform(q, h_kv)
-    nb = cache.kw.shape[2]
-    pad = -(-nb // n) * n - nb
-
-    shared = cache.shared_kv
-    blk = PS(None, None, axis)  # shard dim 2 (packed blocks) of [B,H,nb,...]
-    rep = PS()
-
-    operands = [
-        qt,
-        _pad_block_axis(cache.kw, pad),
-        _pad_block_axis(cache.k_scale, pad),
-        _pad_block_axis(cache.k_zero, pad),
-    ]
-    in_specs = [rep, blk, blk, blk]
-    if not shared:
-        operands += [
-            _pad_block_axis(cache.vw, pad),
-            _pad_block_axis(cache.v_scale, pad),
-            _pad_block_axis(cache.v_zero, pad),
-        ]
-        in_specs += [blk, blk, blk]
-    operands += [cache.k_res, cache.v_res, cache.pack_blocks, cache.res_len]
-    in_specs += [rep] + ([rep] if not shared else []) + [rep, rep]
-    if shared:
-        operands = [x for x in operands if x is not None]
-
-    def local(*args):
-        if shared:
-            qt_, kw_, ks_, kz_, kres_, pb_, rl_ = args
-            vw_ = vs_ = vz_ = vres_ = None
-        else:
-            qt_, kw_, ks_, kz_, vw_, vs_, vz_, kres_, vres_, pb_, rl_ = args
-        idx = lax.axis_index(axis)
-        nb_local = kw_.shape[2]
-        lo = idx * nb_local
-        pb_local = jnp.clip(pb_ - lo, 0, nb_local)
-        rl_local = jnp.where(idx == n - 1, rl_, 0)
-        o, lse = bd_ops.bitdecode_attention(
-            qt_, kw_, ks_, kz_, vw_, vs_, vz_, kres_, vres_,
-            pb_local, rl_local,
-            bits=cache.bits, block_n=cache.block_n, sm_scale=sm_scale,
-            k_gran=cache.k_gran, shared_kv=shared, d_v=d_v,
-            impl=impl, num_splits=num_splits, return_lse=True,
-        )
-        return merge_collective(o, lse, axis)
-
-    out = jax.shard_map(
-        local, mesh=mesh, in_specs=tuple(in_specs), out_specs=rep,
-        check_vma=False,
-    )(*operands)
-    return inverse_query_transform(out)
 
 
 def splitkv_paged_decode_attention(
@@ -181,7 +76,7 @@ def splitkv_paged_decode_attention(
     ``axis`` — each chip walks a contiguous slice of every sequence's table,
     clips ``pack_blocks`` to its slice, and the per-chip flash partials merge
     with the usual lse collectives.  The bf16 residual rides with the last
-    shard, exactly as in the dense path.
+    shard.
 
     ``page_affine=False`` (default) walks the table against *replicated*
     pools — every chip stores every page.  ``page_affine=True`` additionally

@@ -1,30 +1,28 @@
 """PartitionSpec trees for decode state (the serving-side face of sharding).
 
-Decode state is a pytree of stacked QuantKVCache dataclasses plus per-model
-recurrent state (SSM/xLSTM) and a position vector.  Placement policy:
+Decode state is a pytree of stacked PagedQuantKVCache dataclasses plus
+per-model recurrent state (SSM/xLSTM) and a position vector.  Placement
+policy:
 
   * batch dims shard over the largest ("pod", "data") group that divides the
     global batch (mirrors launch/mesh.pick_batch_axes);
-  * the KV-head dim of caches shards over "model" (TP decode);
+  * the KV-head dim of pools and residuals shards over "model" (TP decode);
   * when ``seq_ax`` is given (long-context small-batch shapes, where the
-    batch group is empty), the *packed-block* axis of every QuantKVCache
-    shards along it — the at-rest layout matching repro.dist.splitkv, so the
-    sequence-parallel decode reads its shard locally instead of re-gathering
-    the cache every step.
+    batch group is empty), the page-table columns shard along it — the
+    at-rest layout matching repro.dist.splitkv's sharded walk — and, under
+    page affinity, the pools' page dim too.
 
 Leaves that are not cache fields (pos, SSM states, ...) shard their batch
 dim, identified as the first dim equal to ``global_batch`` — a heuristic,
 but a safe one: specs only place data, they never change semantics.
 
-Cache-field roles map onto the QuantKVCache shapes of docs/ARCHITECTURE.md
-§2 (``kw [B, H, nb, npr, d]`` etc.), shifted right by the model's stacking
-dims (layers, super-blocks).  Axis names are physical mesh axes
+Cache-field roles map onto the PagedQuantKVCache shapes of
+docs/ARCHITECTURE.md §7, shifted right by the model's stacking dims
+(layers, super-blocks).  Axis names are physical mesh axes
 (``"pod"/"data"/"model"`` plus the caller's ``seq_ax``), matching
 dist.sharding's :func:`~repro.dist.sharding.base_rules` targets for the
 same tensors.  Like dist.sharding, placement never pads: an axis group that
-does not divide a dim is dropped (the leaf stays replicated on that dim) —
-any padding needed to honor a split (e.g. the block axis when
-``nb % axis_size != 0``) happens in dist.splitkv at call time instead.
+does not divide a dim is dropped (the leaf stays replicated on that dim).
 
 Specs are consumed via ``jax.device_put`` / shardings built under
 ``jax.set_mesh``.
@@ -37,26 +35,13 @@ import math
 import jax
 from jax.sharding import PartitionSpec as PS
 
-from repro.core.qcache import PagedQuantKVCache, QuantKVCache
+from repro.core.qcache import PagedQuantKVCache
 
-# field -> (base rank without stacking dims, {base-dim index: role})
-_CACHE_FIELD_ROLES = {
-    "kw": (5, {0: "batch", 1: "heads", 2: "blocks"}),
-    "k_scale": (4, {0: "batch", 1: "heads", 2: "blocks"}),
-    "k_zero": (4, {0: "batch", 1: "heads", 2: "blocks"}),
-    "vw": (5, {0: "batch", 1: "heads", 2: "blocks"}),
-    "v_scale": (4, {0: "batch", 1: "heads", 2: "blocks"}),
-    "v_zero": (4, {0: "batch", 1: "heads", 2: "blocks"}),
-    "k_res": (4, {0: "batch", 1: "heads"}),
-    "v_res": (4, {0: "batch", 1: "heads"}),
-    "pack_blocks": (1, {0: "batch"}),
-    "res_len": (1, {0: "batch"}),
-}
-
-# Paged layout: by default the pools ([P, H, ...]) replicate their page dim
-# (pages are scattered arbitrarily, only the table *walk* is
-# sequence-parallel — see dist.splitkv.splitkv_paged_decode_attention) and
-# shard KV heads over "model"; the page_table columns carry the "blocks"
+# field -> (base rank without stacking dims, {base-dim index: role}).  By
+# default the pools ([P, H, ...]) replicate their page dim (pages are
+# scattered arbitrarily, only the table *walk* is sequence-parallel — see
+# dist.splitkv.splitkv_paged_decode_attention) and shard KV heads over
+# "model"; the page_table columns carry the "blocks"
 # role so the at-rest placement matches the sharded walk.  Prefix sharing
 # rides this placement unchanged: a shared page id may appear in several
 # table rows (or twice in one row's shard), and because every chip holds
@@ -118,12 +103,7 @@ def _cache_specs(c, mesh, batch_axes, seq_ax, page_affine=False):
         "blocks": (seq_ax,) if seq_ax else (),
         "pages": (seq_ax,) if seq_ax else (),
     }
-    if isinstance(c, PagedQuantKVCache):
-        roles_table = (
-            _PAGED_AFFINE_FIELD_ROLES if page_affine else _PAGED_FIELD_ROLES
-        )
-    else:
-        roles_table = _CACHE_FIELD_ROLES
+    roles_table = _PAGED_AFFINE_FIELD_ROLES if page_affine else _PAGED_FIELD_ROLES
 
     def field_spec(name: str, arr):
         if arr is None:
@@ -187,13 +167,11 @@ def decode_state_specs(model, mesh, *, global_batch: int, seq_ax: str | None = N
                     break
         return PS(*parts)
 
-    _cache_types = (QuantKVCache, PagedQuantKVCache)
-
     def node(x):
-        if isinstance(x, _cache_types):
+        if isinstance(x, PagedQuantKVCache):
             return _cache_specs(x, mesh, batch_axes, seq_ax, page_affine)
         return generic(x)
 
     return jax.tree.map(
-        node, state, is_leaf=lambda x: isinstance(x, _cache_types)
+        node, state, is_leaf=lambda x: isinstance(x, PagedQuantKVCache)
     )

@@ -1,6 +1,6 @@
 """Pallas TPU kernel: causal flash attention for prefill/training forward.
 
-This is the train/prefill counterpart of the bitdecode kernel: the XLA
+This is the train/prefill counterpart of the paged_bitdecode kernel: the XLA
 attention path materializes every f32 score tile to HBM (S·block·heads per
 step).  Here score tiles live entirely in VMEM: HBM traffic collapses to
 Q/K/V/O once per block pair (K/V re-streamed per q-block — the flash

@@ -1,29 +1,55 @@
 """Pallas TPU kernel: paged low-bit flash-decode attention (paper's Page
-setting, §VI-A), with the same split-KV grid as kernels/bitdecode.
+setting, §VI-A), with FlashDecoding-style split-KV sequence parallelism.
 
 TPU-idiomatic paging: instead of a scalar-core page-table walk (vLLM/GPU),
 the page table is a *scalar-prefetch* operand — BlockSpec index_maps read
 ``page_table[b, jj]`` to pick which page of the global pool the next grid
-step's DMA fetches, so page indirection rides the same double-buffered
-HBM→VMEM pipeline as the dense kernel (zero extra kernels, zero gathers).
+step's DMA fetches, so page indirection rides the double-buffered HBM→VMEM
+pipeline (zero extra kernels, zero gathers).
 
-Split-KV: grid = (B, num_splits, bps + 1); split ``s`` walks page-table
-entries [s*bps, (s+1)*bps), writes its own slot of the per-split partials
-(o [S,B,H,g,d_v], lse [S,B,H,g]); the residual tail rides with the last
-split and the partials are combined by the shared logsumexp merge epilogue
-(bitdecode.kernel.merge_partials).
+Two-phase reduction
+-------------------
+Phase 1 — grid = (B, num_splits, bps + 1), bps = ceil(nb / num_splits):
+split ``s`` walks page-table entries [s*bps, (s+1)*bps) with online-softmax
+carries in VMEM scratch; the final grid step of the LAST split additionally
+processes the half-precision *residual* buffer (paper §IV-A(2)).  Every
+split finalizes into its own slot of the partials outputs
+(o [S,B,H,g,d_v], lse [S,B,H,g]), so a single-batch long-context decode
+exposes ``B x num_splits``-way grid parallelism.
+
+Phase 2 — :func:`merge_partials`, a small XLA epilogue: a logsumexp-weighted
+combine of the per-split partials.  A split whose range lies entirely
+beyond ``pack_blocks[b]`` never updates its carries, so ``finalize``'s l=0
+guard emits lse ~ -inf and the merge weights it out *exactly* (the same
+contract tests/test_splitkv_math.py pins for the cross-chip merge in
+repro.dist.splitkv, which reuses this math over a mesh axis).
+
+Cooperative-unit mapping (paper §III-A):
+  * unpack + dequant: shift/mask/FMA on the VPU — the CUDA-core role;
+  * QK^T and PV: `lax.dot_general` with bf16 operands, f32 accumulation on
+    the MXU — the Tensor-Core role;
+  * Mosaic's grid pipeline double-buffers the HBM→VMEM DMA of page i+1
+    against the compute of page i — the paper's cp.async/wgmma software
+    pipeline (§V-C(2)) falls out of the BlockSpec machinery;
+  * the online-softmax carry in VMEM scratch across sequential grid steps
+    replaces the multi-warp cooperative softmax (§IV-B(2)); the split axis
+    replaces FlashDecoding's inter-CTA partials+combine.
+
+The strided packed layout (core/layout.py) makes the unpack a handful of
+full-width vector ops whose output is already in natural token order inside
+the (sublane, lane) tile — the ldmatrix-induced-layout analogue.
 
 Pools are layer-stacked, [L, n_pages, H, ...], and a scalar-prefetch
 ``layer`` picks the layer the walk reads: the decode layer scan hands the
 kernel the whole stack and never slices one layer's pool out of it.  One
 grid step moves one page of *every* KV head of that layer (blocks
 ``(1, H, ...)``, the layer dim squeezed), running the heads' flash updates
-one after another in registers; everything else matches kernels/bitdecode.
+one after another in registers.
 
-``shared_kv=True`` is the MLA latent-cache mode, mirrored from the dense
-kernel: the pools hold a single quantized latent stream, there are no V-side
-pools at all, and the V tile is a channel slice (``[:, :d_v]``) of the
-dequantized K tile — one pool page read per grid step feeds both matmuls.
+``shared_kv=True`` is the MLA latent-cache mode: the pools hold a single
+quantized latent stream, there are no V-side pools at all, and the V tile
+is a channel slice (``[:, :d_v]``) of the dequantized K tile — one pool
+page read per grid step feeds both matmuls.
 """
 from __future__ import annotations
 
@@ -36,8 +62,96 @@ from jax.experimental.pallas import tpu as pltpu
 
 from jax import lax
 
-from repro.kernels.bitdecode.kernel import (_unpack, dequant_tile, finalize,
-                                            init_carries, make_flash_update)
+from repro.core import layout
+
+MASK_VALUE = -1e37
+
+
+def _unpack(w, bits):
+    """int32 (npr, d) -> int32 (block_n, d), natural token order (strided layout)."""
+    shifts, mask = layout.plane_shift_mask(bits)
+    planes = [(w >> s) & mask for s in shifts]
+    return jnp.concatenate(planes, axis=0)
+
+
+def make_flash_update(q, m_scr, l_scr, acc_scr, sm_scale):
+    """Online-softmax update closure for one KV head.
+    q: (g, d_k) bf16; scratch refs hold the running (m, l, acc) carries."""
+
+    def update(k_tile, v_tile, row_mask=None):
+        s = (
+            lax.dot_general(
+                q, k_tile, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            * sm_scale
+        )  # (g, n) — MXU
+        if row_mask is not None:
+            s = jnp.where(row_mask, s, MASK_VALUE)
+        m_prev = m_scr[...]  # (g, 128) lane-replicated
+        m_cur = jnp.max(s, axis=1, keepdims=True)  # (g, 1)
+        m_next = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(s - m_next[:, :1])  # (g, n)
+        l_next = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = lax.dot_general(
+            p.astype(jnp.bfloat16), v_tile, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (g, d_v) — MXU
+        acc_scr[...] = acc_scr[...] * alpha[:, :1] + pv
+        m_scr[...] = m_next
+        l_scr[...] = l_next
+
+    return update
+
+
+def dequant_tile(wq, scale, zero, k_gran):
+    """(n, d) int codes + params -> bf16 tile (VPU scale-FMA)."""
+    s = scale.astype(jnp.float32)
+    z = zero.astype(jnp.float32)
+    if k_gran == "channel":  # params per channel: (d,)
+        return (wq.astype(jnp.float32) * s[None, :] + z[None, :]).astype(jnp.bfloat16)
+    return (wq.astype(jnp.float32) * s[:, None] + z[:, None]).astype(jnp.bfloat16)
+
+
+def finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr):
+    """Write the carries' normalized output and logsumexp.  The carries are
+    ``(H, g, .)`` (all heads of a step); ``lse_ref`` keeps a unit lane axis
+    so its block stays a legal TPU tile."""
+    # guard l=0 (all tokens masked — e.g. a split whose range lies beyond
+    # pack_blocks, or an empty split-KV shard): output zeros with lse ~ -inf
+    # so merge_partials / the cross-chip merge weights it out exactly
+    l = jnp.maximum(l_scr[...], 1e-30)
+    o = acc_scr[...] / l[..., :1]
+    o_ref[...] = o.reshape(o_ref.shape).astype(o_ref.dtype)
+    lse = m_scr[..., :1] + jnp.log(l[..., :1])
+    lse_ref[...] = lse.reshape(lse_ref.shape)
+
+
+def init_carries(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full(m_scr.shape, MASK_VALUE, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+
+def merge_partials(o_parts, lse_parts, *, return_lse: bool = True):
+    """Phase-2 combine of per-split flash partials (XLA epilogue).
+
+    o_parts: [S, ..., g, d_v] per-split normalized outputs;
+    lse_parts: [S, ..., g] per-split logsumexps.  Splits with no valid
+    tokens carry lse ~ -inf (finalize's l=0 guard) and get weight exp(-inf)=0,
+    so empty splits drop out exactly — the same lse-merge the distributed
+    layer (repro.dist.splitkv) runs across a mesh axis, specified by
+    tests/test_splitkv_math.py.
+    """
+    m = jnp.max(lse_parts, axis=0)
+    w = jnp.exp(lse_parts - m[None])  # [S, ..., g]
+    den = jnp.maximum(jnp.sum(w, axis=0), 1e-30)
+    num = jnp.sum(w[..., None] * o_parts, axis=0)
+    out = num / den[..., None]
+    if not return_lse:
+        return out
+    return out, m + jnp.log(den)
 
 
 def _paged_body(pt_ref, pb_ref, rl_ref, ly_ref, q_ref, kw_ref, ks_ref, kz_ref,

@@ -2,8 +2,16 @@
 
 ``shared_kv=True`` is the MLA latent-cache mode: the pools hold a single
 quantized latent stream (V-side pools and residual are ``None``), the kernel
-reads each page once and slices the V tile out of the dequantized K tile —
-the paged twin of ``kernels/bitdecode``'s shared mode, same split-KV grid.
+reads each page once and slices the V tile out of the dequantized K tile.
+
+Split-KV (FlashDecoding) dispatch lives here: ``num_splits`` partitions the
+page-table walk into contiguous ranges that the Pallas grid processes as an
+extra parallel dimension (kernel.py phase 1), combined by the logsumexp merge
+epilogue (kernel.merge_partials, phase 2).  ``num_splits="auto"`` applies the
+serving heuristic: split only when the natural ``B x H_kv`` parallelism
+underfills the chip's cores AND the sequence is long enough that each split
+still amortizes its setup over >= 2 pages — i.e. exactly the paper's
+headline long-context small-batch decode regime.
 
 ``layer`` makes the pools layer-stacked (``[L, P, H, ...]``, the decode
 layer scan's layout) and names the layer the walk reads; without it the
@@ -14,14 +22,48 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.bitdecode import kernel as bd_kernel
-from repro.kernels.bitdecode import ops as bd_ops
 from repro.kernels.paged_bitdecode import kernel as _kernel
 from repro.kernels.paged_bitdecode import ref as _ref
+
+# Parallel grid slots the attached accelerators can fill concurrently.  TPU
+# Mosaic maps "parallel" grid dims over Megacore (2 cores/chip); we target a
+# little above that per device so splits also cover pipeline bubbles.
+_CORES_PER_DEVICE = 4  # ~2 physical cores x2 oversubscription
+_MAX_SPLITS = 16
+_cores_cache: int | None = None
+
+
+def default_splitkv_cores() -> int:
+    """Parallel-slot target for the split heuristic: ``jax.device_count() *
+    4``.  Resolved lazily (device_count initializes the backend) and cached
+    for the process lifetime."""
+    global _cores_cache
+    if _cores_cache is None:
+        _cores_cache = max(1, jax.device_count() * _CORES_PER_DEVICE)
+    return _cores_cache
 
 
 def _round_up(x, m):
     return -(-x // m) * m
+
+
+def auto_num_splits(b: int, h_kv: int, nb: int, *, cores: int | None = None) -> int:
+    """Split-KV heuristic: 1 unless B*H_kv underfills the cores and the
+    packed sequence is long enough for every split to own >= 2 blocks."""
+    cores = default_splitkv_cores() if cores is None else cores
+    if b * h_kv >= cores or nb < 4:
+        return 1
+    want = -(-cores // (b * h_kv))  # splits needed to fill the cores
+    return max(1, min(want, nb // 2, _MAX_SPLITS))
+
+
+def resolve_num_splits(num_splits, b: int, h_kv: int, nb: int) -> int:
+    if num_splits in (None, "auto"):
+        return auto_num_splits(b, h_kv, nb)
+    s = int(num_splits)
+    if s < 1:
+        raise ValueError(f"num_splits must be >= 1, got {num_splits}")
+    return max(1, min(s, nb)) if nb else 1
 
 
 def paged_bitdecode_attention(
@@ -65,9 +107,11 @@ def paged_bitdecode_attention(
     elif impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if num_splits in (None, "auto") and impl == "xla":
-        num_splits = 1  # splitting only pays on the Pallas grid (see bd_ops)
+        # splitting only pays on the Pallas grid: the XLA ref multiplies its
+        # work by the split count (explicit integers are always honored)
+        num_splits = 1
     else:
-        num_splits = bd_ops.resolve_num_splits(num_splits, b, h, page_table.shape[1])
+        num_splits = resolve_num_splits(num_splits, b, h, page_table.shape[1])
     if impl == "xla":
         out, lse = _ref.paged_bitdecode_attention_ref(
             q, kw_pool, k_scale_pool, k_zero_pool, vw_pool, v_scale_pool,
@@ -121,7 +165,7 @@ def paged_bitdecode_attention(
     if o_parts.shape[0] == 1:  # unsplit: partials are already the answer
         out, lse = o_parts[0], lse_parts[0]
     else:
-        out, lse = bd_kernel.merge_partials(o_parts, lse_parts)
+        out, lse = _kernel.merge_partials(o_parts, lse_parts)
     out = out[:, :, :g, :d_v]
     lse = lse[:, :, :g]
     return (out, lse) if return_lse else out

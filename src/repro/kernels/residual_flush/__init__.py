@@ -1,1 +1,1 @@
-from repro.kernels.residual_flush.ops import residual_flush  # noqa: F401
+from repro.kernels.residual_flush.ops import paged_residual_flush  # noqa: F401

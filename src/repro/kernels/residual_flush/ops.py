@@ -1,4 +1,4 @@
-"""Public entry point for the fused residual-flush (quantize+pack+commit)."""
+"""Public entry point for the fused paged residual flush (quantize+pack+commit)."""
 from __future__ import annotations
 
 import jax
@@ -7,57 +7,6 @@ from jax import lax
 
 from repro.kernels.residual_flush import kernel as _kernel
 from repro.kernels.residual_flush import ref as _ref
-
-
-def residual_flush(
-    kw,
-    k_scale,
-    k_zero,
-    vw,
-    v_scale,
-    v_zero,
-    k_res,
-    v_res,
-    full,
-    dest_block,
-    *,
-    bits: int,
-    block_n: int,
-    k_gran: str,
-    shared_kv: bool,
-    impl: str = "auto",
-):
-    """Commit the bf16 residual of every sequence with ``full[b] != 0`` into
-    packed block ``dest_block[b]`` of the low-bit cache.
-
-    Arguments mirror the QuantKVCache packed/residual fields (V side None
-    when ``shared_kv``); returns the six updated packed arrays.  Callers gate
-    the invocation on ``jnp.any(full)`` (see ``qcache.append_decode``) so the
-    per-token hot path performs no quantization work at all.
-
-    impl: 'pallas' (single fused kernel, in-place via aliasing; interpret
-    mode off-TPU), 'xla' (the select-based reference oracle), or 'auto'
-    (pallas on TPU, xla elsewhere).  The aliased cache cannot be lane-padded
-    in place, so on TPU a head dim that is not lane-aligned raises; pass
-    ``impl='xla'`` to run such a shape through the reference.
-    """
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if impl == "pallas":
-        interpret = jax.default_backend() != "tpu"
-        return _kernel.residual_flush_pallas(
-            kw, k_scale, k_zero, vw, v_scale, v_zero, k_res, v_res,
-            full, dest_block,
-            bits=bits, block_n=block_n, k_gran=k_gran, shared_kv=shared_kv,
-            interpret=interpret,
-        )
-    if impl == "xla":
-        return _ref.residual_flush_ref(
-            kw, k_scale, k_zero, vw, v_scale, v_zero, k_res, v_res,
-            full, dest_block,
-            bits=bits, block_n=block_n, k_gran=k_gran, shared_kv=shared_kv,
-        )
-    raise ValueError(f"unknown impl {impl!r}")
 
 
 def paged_residual_flush(
@@ -79,8 +28,7 @@ def paged_residual_flush(
     shared_kv: bool = False,
     impl: str = "auto",
 ):
-    """Paged face of the fused residual flush: commit the bf16 residual of
-    every sequence with ``full[b] != 0`` into pool page ``dest_page[b]`` of
+    """The fused residual flush: commit the bf16 residual of every sequence with ``full[b] != 0`` into pool page ``dest_page[b]`` of
     the shared ``[P, H, ...]`` page pools.
 
     ``layer`` (int32 scalar) makes the pools layer-stacked, ``[L, P, H,
@@ -100,8 +48,8 @@ def paged_residual_flush(
     (no V-side pools; V operands are ``None``).
 
     impl: 'pallas' | 'xla' | 'auto' (pallas on TPU, xla elsewhere; a pool
-    minor dim that is not lane-aligned raises on TPU, exactly like the dense
-    flush).
+    minor dim that is not lane-aligned raises on TPU: the aliased pools
+    cannot be lane-padded in place).
     """
     pools = (kw_pool, k_scale_pool, k_zero_pool, vw_pool, v_scale_pool,
              v_zero_pool)

@@ -84,8 +84,6 @@ def main():
     ap.add_argument("--kv-bits", type=int, default=4)
     ap.add_argument("--pages", type=int, default=None,
                     help="page-pool size (default: fully provisioned)")
-    ap.add_argument("--dense", action="store_true",
-                    help="force the exact-length shim (dense decode state)")
     ap.add_argument("--splitkv", choices=("auto", "always", "never"),
                     default="auto", help="cross-chip split-KV routing policy")
     ap.add_argument("--shared-prefix-len", type=int, default=0,
@@ -149,8 +147,7 @@ def main():
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = cfg.with_(kv_bits=args.kv_bits)
     engine = build_engine(
-        cfg, slots=args.slots, max_seq=args.max_seq,
-        paged=False if args.dense else None, n_pages=args.pages,
+        cfg, slots=args.slots, max_seq=args.max_seq, n_pages=args.pages,
         splitkv=args.splitkv, share_prefix=not args.no_prefix_sharing,
         reserve_policy=args.reserve_policy,
         expected_quantile=args.expected_quantile,

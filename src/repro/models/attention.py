@@ -1,8 +1,8 @@
 """Model-level attention block: projections + RoPE + BitDecoding cache.
 
-Train/prefill path uses the blockwise flash attention; the decode path
-appends to the QuantKVCache and runs the fused low-bit kernel through the
-query transformation (core/attention.py).
+Train/prefill path uses the blockwise flash attention and writes the dense
+QuantKVCache; the decode path appends to the paged cache and runs the fused
+low-bit kernel through the query transformation (core/attention.py).
 """
 from __future__ import annotations
 

@@ -1,10 +1,13 @@
 """Encoder-decoder LM (Seamless-M4T backbone).  The modality frontend is a
 stub: the encoder consumes precomputed frame embeddings from input_specs().
 
-Decode uses two BitDecoding caches per decoder layer:
+Decode uses two BitDecoding caches per decoder layer, both paged under
+identity page tables (``qcache.to_pages``):
   * self-attention: growing quantized cache (online Residual-Kernel path);
   * cross-attention: *static* quantized cache built once after encoding —
     the paper's offline (Fig. 1a) case, same kernels, residual never flushed.
+``prefill`` returns them as the dense caches prefill writes; hand its state
+to ``qcache.to_pages`` before decoding.
 """
 from __future__ import annotations
 
@@ -119,11 +122,11 @@ class EncDecLM:
             bits=cfg.kv_bits, block_n=cfg.kv_block, k_gran=cfg.kv_gran,
         )
         n = cfg.dec_layers
-        return {
+        return qcache.to_pages({
             "self": jax.tree.map(lambda a: jnp.broadcast_to(a, (n, *a.shape)), self_c),
             "cross": jax.tree.map(lambda a: jnp.broadcast_to(a, (n, *a.shape)), cross_c),
             "pos": jnp.zeros((batch_size,), jnp.int32),
-        }
+        })
 
     def prefill(self, params, batch, max_seq: int):
         """Encode + build static cross caches + prefill decoder self caches."""

@@ -13,7 +13,9 @@ All stacks store per-layer params with a leading ``layers`` axis and run
 body implements full-block remat for training.
 
 Decode state is a plain dict pytree:
-  {"caches": [per-stack stacked QuantKVCache], "ssm": ..., "pos": int32[B]}
+  {"caches": [per-stack stacked PagedQuantKVCache], "ssm": ..., "pos": int32[B]}
+``prefill`` returns the same dict with the dense QuantKVCache that prefill
+writes; ``qcache.to_pages`` turns it into a decode state.
 """
 from __future__ import annotations
 
@@ -89,15 +91,12 @@ def _layer_params(lp, whole):
 
 def _scan_cache(cache):
     """A layer-stacked decode cache as its layer scan takes it: ``(pools,
-    xs)``.  A paged cache's page pools stay out of the scanned operands and
-    ride the scan's carry whole; each layer reads and flushes its own layer
-    of them in place through a layer view (``PagedQuantKVCache.layer``, the
-    scan counter in ``xs``).  A scanned slice would be copied out of the
-    stack for the kernels and written back, every layer of every step.  Other
-    caches scan whole (``pools`` None)."""
-    if not isinstance(cache, qcache.PagedQuantKVCache):
-        return None, cache
-    pools, rest = qcache.split_pools(cache)
+    xs)``.  The page pools stay out of the scanned operands and ride the
+    scan's carry whole; each layer reads and flushes its own layer of them
+    in place through a layer view (``PagedQuantKVCache.layer``, the scan
+    counter in ``xs``).  A scanned slice would be copied out of the stack
+    for the kernels and written back, every layer of every step."""
+    pools, rest = qcache.split_pools(qcache.require_pages(cache))
     n_layers = cache.kw.shape[0]
     return pools, dataclasses.replace(
         rest, layer=jnp.arange(n_layers, dtype=jnp.int32))
@@ -105,19 +104,36 @@ def _scan_cache(cache):
 
 def _layer_cache(pools, cache):
     """One layer's cache inside the scan (see :func:`_scan_cache`)."""
-    return cache if pools is None else qcache.with_pools(cache, pools)
+    return qcache.with_pools(cache, pools)
 
 
 def _unlayer_cache(pools, cache):
     """Inverse of :func:`_layer_cache`: ``(pools, per-layer ys)``."""
-    return (None, cache) if pools is None else qcache.split_pools(cache)
+    return qcache.split_pools(cache)
 
 
 def _stacked_cache(pools, cache_stack):
     """Inverse of :func:`_scan_cache` on the scan's results."""
-    if pools is None:
-        return cache_stack
     return qcache.with_pools(cache_stack, pools, layer=None)
+
+
+def _identity_decode_state(model, batch_size: int, max_seq: int, mesh,
+                           splitkv_axis: str):
+    """Empty decode state for ``max_seq`` tokens per sequence, for callers
+    outside the serving engine: ``model.init_paged_decode_state`` with
+    ``B + B*nb`` pages under identity page tables
+    (``qcache.identity_table``), the layout ``qcache.to_pages`` gives a
+    prefill.  With a ``mesh``, ``nb`` rounds up to the ``splitkv_axis``
+    size, as the engine's does, so the sharded table walk splits it
+    evenly."""
+    nb = qcache.n_blocks(max_seq, model.cfg.kv_block,
+                         qcache.splitkv_block_align(mesh, splitkv_axis))
+    state = model.init_paged_decode_state(
+        batch_size, n_pages=batch_size * (nb + 1), nb_max=nb)
+    state["caches"] = [
+        dataclasses.replace(c, page_table=qcache.identity_table(c.page_table.shape))
+        for c in state["caches"]]
+    return state
 
 
 class DecoderLM:
@@ -394,28 +410,8 @@ class DecoderLM:
 
     def init_decode_state(self, batch_size: int, max_seq: int, *, mesh=None,
                           splitkv_axis: str = "data"):
-        """Dense decode state.  When a ``mesh`` is given, the packed-block
-        capacity is rounded up to the ``splitkv_axis`` size so
-        ``dist.splitkv`` shards the block axis pad-free (mesh-aligned cache
-        allocation — otherwise the per-call zero-pad copies the whole cache
-        every decoded token at ``nb % axis_size != 0`` shapes)."""
-        cfg = self.cfg
-        align = qcache.splitkv_block_align(mesh, splitkv_axis)
-        caches = []
-        for kind, n in self.stacks:
-            if cfg.mixer == "mla":
-                one = mla.mla_init_cache(cfg, batch_size, max_seq, block_align=align)
-            else:
-                one = qcache.init_cache(
-                    batch_size, cfg.n_kv_heads, cfg.head_dim, max_seq,
-                    bits=cfg.kv_bits, block_n=cfg.kv_block, k_gran=cfg.kv_gran,
-                    block_align=align,
-                )
-            caches.append(jax.tree.map(lambda a: jnp.broadcast_to(a, (n, *a.shape)), one))
-        return self._with_moe_counts({
-            "caches": caches,
-            "pos": jnp.zeros((batch_size,), jnp.int32),
-        })
+        """See :func:`_identity_decode_state`."""
+        return _identity_decode_state(self, batch_size, max_seq, mesh, splitkv_axis)
 
     def _with_moe_counts(self, state, counts=None):
         """A decode state of a model with routed experts carries the last
@@ -458,12 +454,6 @@ class DecoderLM:
         (``mla.mla_init_paged_cache``); both families decode through
         ``kernels/paged_bitdecode``."""
         cfg = self.cfg
-        spec = self.paged_spec()
-        if spec is None or not spec.paged:
-            raise ValueError(
-                f"no paged decode path for mixer={cfg.mixer!r} with this "
-                "front (see DecoderLM.paged_spec)"
-            )
         caches = []
         for kind, n in self.stacks:
             if cfg.mixer == "mla":
@@ -671,19 +661,8 @@ class HybridLM:
 
     def init_decode_state(self, batch_size: int, max_seq: int, *, mesh=None,
                           splitkv_axis: str = "data"):
-        cfg = self.cfg
-        cache = qcache.init_cache(
-            batch_size, cfg.n_kv_heads, cfg.head_dim, max_seq,
-            bits=cfg.kv_bits, block_n=cfg.kv_block, k_gran=cfg.kv_gran,
-            block_align=qcache.splitkv_block_align(mesh, splitkv_axis),
-        )
-        return {
-            **self._side_states(batch_size),
-            "caches": [jax.tree.map(
-                lambda a: jnp.broadcast_to(a, (self.n_super, *a.shape)), cache
-            )],
-            "pos": jnp.zeros((batch_size,), jnp.int32),
-        }
+        """See :func:`_identity_decode_state`."""
+        return _identity_decode_state(self, batch_size, max_seq, mesh, splitkv_axis)
 
     def init_paged_decode_state(self, batch_size: int, *, n_pages: int,
                                 nb_max: int):

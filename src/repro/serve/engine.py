@@ -118,7 +118,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.core import attention as catt
 from repro.core import qcache
-from repro.kernels.bitdecode import ops as bd_ops
+from repro.kernels.paged_bitdecode import ops as pg_ops
 from repro.models.family import get_path, set_path
 from repro.serve import pages as pg
 from repro.serve.audit import audit_engine
@@ -244,7 +244,7 @@ class _PhaseTimer:
 class ServeEngine:
     def __init__(self, model, params, *, slots: int = 8, max_seq: int = 2048,
                  eos_id: int | None = None, impl: str = "auto",
-                 quant_impl: str = "auto", paged: bool | None = None,
+                 quant_impl: str = "auto",
                  n_pages: int | None = None, min_bucket: int = 16,
                  mesh=None, splitkv_axis: str = "data",
                  splitkv: str = "auto", share_prefix: bool = True,
@@ -262,12 +262,11 @@ class ServeEngine:
                  async_runtime: bool = False, async_window: int = 2,
                  completion_queue: int = 64, watchdog_s: float = 30.0,
                  detokenizer=None, on_complete=None):
-        """``paged=None`` follows the model's ``paged_spec()`` (paged when it
-        declares a paged family); ``paged=False`` forces the exact-length
-        shim for any token-prefill model (debug/baseline path); ``paged=True``
-        raises if the model declares no paged family.  ``n_pages`` bounds the
-        KV pool (default: full provisioning, ``slots * nb_max`` + scratch —
-        lower it to oversubscribe and exercise admission backpressure).
+        """The engine pages every family whose ``paged_spec()`` declares a
+        paged cache; a family without a KV cache (xLSTM) runs the
+        exact-length shim.  ``n_pages`` bounds the KV pool (default: full
+        provisioning, ``slots * nb_max`` + scratch — lower it to
+        oversubscribe and exercise admission backpressure).
         ``mesh``/``splitkv_axis`` attach the cross-chip split-KV decode path;
         ``splitkv`` is the routing policy: 'auto' (engage on long-context
         low-occupancy cycles), 'always', 'never'.  ``share_prefix`` enables
@@ -410,15 +409,10 @@ class ServeEngine:
                 "model declares no serveable cache family (paged_spec() is "
                 "None): its prefill needs inputs beyond tokens"
             )
-        if paged and not spec.paged:
-            raise ValueError(
-                "model declares no paged decode capability "
-                "(see repro.models.family.PagedSpec)"
-            )
         self.spec = spec
-        self.paged = (spec is not None and spec.paged) if paged is None else bool(paged)
-        self.block_n = spec.block_n if spec is not None else getattr(cfg, "kv_block", 128)
-        self._h_kv = spec.n_kv_heads if spec is not None else getattr(cfg, "n_kv_heads", 1)
+        self.paged = spec.paged
+        self.block_n = spec.block_n
+        self._h_kv = spec.n_kv_heads
 
         # self-speculative decoding (draft against the truncated-bit read of
         # the same pools; one batched verify scan; docs/SERVING.md §11)
@@ -1423,7 +1417,7 @@ class ServeEngine:
             return False
         active = self.sched.active.values()
         max_blocks = max((r.pos // self.block_n for r in active), default=0)
-        cores = bd_ops.default_splitkv_cores()
+        cores = pg_ops.default_splitkv_cores()
         return (
             len(self.sched.active) * self._h_kv < cores
             and max_blocks >= 2 * axis_size

@@ -36,7 +36,7 @@ def test_check_docs_detects_drift():
     mod = _load_check_docs()
     documented = mod.documented_families((REPO / "README.md").read_text())
     assert "residual_flush" in documented
-    assert "bitdecode" in documented
+    assert "paged_bitdecode" in documented
     broken = (REPO / "README.md").read_text().replace("`residual_flush`", "`x`")
     assert mod.documented_families(broken) != documented
 
@@ -64,7 +64,7 @@ def test_serving_doc_flags_match_engine_signature():
     mod = _load_check_docs()
     text = (REPO / "docs" / "SERVING.md").read_text()
     flags = mod.table_rows(text, "Engine flags")
-    assert {"share_prefix", "spec_tail", "paged", "n_pages",
+    assert {"share_prefix", "spec_tail", "page_affine", "n_pages",
             "trace", "metrics_every"} <= flags
 
 

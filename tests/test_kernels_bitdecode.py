@@ -1,5 +1,7 @@
-"""Per-kernel allclose tests: bitdecode Pallas kernel vs pure-jnp oracle,
-plus fidelity vs the exact fp16 attention (paper Table I analogue)."""
+"""Per-kernel allclose tests: the paged_bitdecode Pallas kernel vs the
+pure-jnp oracle over a dense cache's blocks under an identity page table
+(``qcache.to_pages``), plus fidelity vs the exact fp16 attention (paper
+Table I analogue)."""
 import functools
 
 import jax
@@ -7,8 +9,23 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.bitdecode import ops as bd_ops
+from repro.core import qcache
 from repro.kernels.kv_quant import ref as kq_ref
+from repro.kernels.paged_bitdecode import ops as pg_ops
+
+
+def paged_args(case, *, bits, block_n, k_gran="channel", shared_kv=False):
+    """A case's dense blocks (``kw [B, H, nb, ...]`` etc.) as the positional
+    arguments of ``paged_bitdecode_attention``: pools and page table from
+    ``qcache.to_pages``, ``q`` first."""
+    pages = qcache.to_pages(qcache.QuantKVCache(
+        **{f: case[f] for f in ("kw", "k_scale", "k_zero", "vw", "v_scale",
+                                "v_zero", "k_res", "v_res", "pack_blocks",
+                                "res_len")},
+        bits=bits, block_n=block_n, k_gran=k_gran, shared_kv=shared_kv))
+    return (case["q"], pages.kw, pages.k_scale, pages.k_zero, pages.vw,
+            pages.v_scale, pages.v_zero, pages.k_res, pages.v_res,
+            pages.page_table, pages.pack_blocks, pages.res_len)
 
 
 def _make_case(key, *, b, h, g, d_k, d_v, nb, block_n, res_n, bits, k_gran,
@@ -42,10 +59,11 @@ def test_bitdecode_pallas_matches_ref(bits, k_gran, g, d):
         block_n=block_n, res_n=block_n, bits=bits, k_gran=k_gran,
         pack_blocks=[nb, nb - 1], res_len=[37, 0],
     )
-    fn = functools.partial(bd_ops.bitdecode_attention, bits=bits, block_n=block_n,
-                           k_gran=k_gran, return_lse=True)
-    out_p, lse_p = fn(**case, impl="pallas")
-    out_r, lse_r = fn(**case, impl="xla")
+    args = paged_args(case, bits=bits, block_n=block_n, k_gran=k_gran)
+    fn = functools.partial(pg_ops.paged_bitdecode_attention, bits=bits,
+                           block_n=block_n, k_gran=k_gran, return_lse=True)
+    out_p, lse_p = fn(*args, impl="pallas")
+    out_r, lse_r = fn(*args, impl="xla")
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r), rtol=2e-2, atol=2e-2)
     np.testing.assert_allclose(np.asarray(lse_p), np.asarray(lse_r), rtol=1e-3, atol=1e-3)
 
@@ -59,10 +77,11 @@ def test_bitdecode_unaligned_shapes(g, d):
         block_n=block_n, res_n=block_n, bits=bits, k_gran=k_gran,
         pack_blocks=[nb], res_len=[5],
     )
-    fn = functools.partial(bd_ops.bitdecode_attention, bits=bits, block_n=block_n,
-                           k_gran=k_gran, return_lse=True)
-    out_p, lse_p = fn(**case, impl="pallas")
-    out_r, lse_r = fn(**case, impl="xla")
+    args = paged_args(case, bits=bits, block_n=block_n, k_gran=k_gran)
+    fn = functools.partial(pg_ops.paged_bitdecode_attention, bits=bits,
+                           block_n=block_n, k_gran=k_gran, return_lse=True)
+    out_p, lse_p = fn(*args, impl="pallas")
+    out_r, lse_r = fn(*args, impl="xla")
     assert out_p.shape == (b, h, g, d)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r), rtol=2e-2, atol=2e-2)
     np.testing.assert_allclose(np.asarray(lse_p), np.asarray(lse_r), rtol=1e-3, atol=1e-3)
@@ -79,11 +98,12 @@ def test_bitdecode_shared_kv_mla_mode():
     case = dict(case)
     case["vw"] = case["v_scale"] = case["v_zero"] = None
     case["v_res"] = None
-    # residual V must be the slice of residual K for shared mode
-    fn = functools.partial(bd_ops.bitdecode_attention, bits=bits, block_n=block_n,
-                           k_gran="channel", shared_kv=True, d_v=d_v, return_lse=True)
-    out_p, lse_p = fn(**case, impl="pallas")
-    out_r, lse_r = fn(**{**case, "v_res": case["k_res"][..., :d_v]}, impl="xla")
+    args = paged_args(case, bits=bits, block_n=block_n, shared_kv=True)
+    fn = functools.partial(pg_ops.paged_bitdecode_attention, bits=bits,
+                           block_n=block_n, k_gran="channel", shared_kv=True,
+                           d_v=d_v, return_lse=True)
+    out_p, lse_p = fn(*args, impl="pallas")
+    out_r, lse_r = fn(*args, impl="xla")  # residual V: the slice of residual K
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r), rtol=2e-2, atol=2e-2)
 
 
@@ -103,8 +123,9 @@ def test_bitdecode_fidelity_vs_fp16(bits, max_err):
         block_n=block_n, res_n=block_n, bits=bits, k_gran="channel",
         pack_blocks=[nb], res_len=[64],
     )
-    out_q = bd_ops.bitdecode_attention(**case, bits=bits, block_n=block_n,
-                                       k_gran="channel", impl="xla")
+    out_q = pg_ops.paged_bitdecode_attention(
+        *paged_args(case, bits=bits, block_n=block_n), bits=bits,
+        block_n=block_n, k_gran="channel", impl="xla")
     # exact fp16 oracle over the same tokens
     k_all = jnp.concatenate([k_full, case["k_res"][:, :, :64].astype(jnp.float32)], axis=2)
     v_all = jnp.concatenate([v_full, case["v_res"][:, :, :64].astype(jnp.float32)], axis=2)

@@ -1,6 +1,7 @@
 """Paged (Page-setting) kernel vs oracle, including shared page pools with
-scrambled page tables and per-sequence lengths, and the shared_kv (MLA
-latent-pool) parity grid against the dense shared_kv oracle."""
+scrambled page tables and per-sequence lengths, the shared_kv (MLA
+latent-pool) parity grid, and scrambled tables reading exactly what the
+identity table of ``qcache.to_pages`` reads."""
 import functools
 
 import jax
@@ -8,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.bitdecode import ops as bd_ops
+from repro.core import qcache
 from repro.kernels.kv_quant import ref as kq_ref
 from repro.kernels.paged_bitdecode import ops as pg_ops
 
@@ -48,24 +49,38 @@ def test_paged_matches_ref(bits, k_gran):
     np.testing.assert_allclose(np.asarray(lse_p), np.asarray(lse_r), rtol=1e-3, atol=1e-3)
 
 
+def identity_layout(pools, table, pack_blocks, res_len, k_res, v_res, **static):
+    """The blocks a (scrambled) ``table`` names, gathered into a dense cache
+    and laid out again by ``qcache.to_pages``: the same content under the
+    identity page table.  Returns the pool and table arguments in
+    ``paged_bitdecode_attention``'s order, from the pools to ``res_len``."""
+    gather = lambda x: None if x is None else jnp.moveaxis(  # noqa: E731
+        jnp.take(x, table, axis=0), 2, 1)
+    dense = qcache.QuantKVCache(
+        *(gather(p) for p in pools), k_res=k_res, v_res=v_res,
+        pack_blocks=pack_blocks, res_len=res_len, **static)
+    pc = qcache.to_pages(dense)
+    assert not np.array_equal(np.asarray(pc.page_table), np.asarray(table))
+    return (pc.kw, pc.k_scale, pc.k_zero, pc.vw, pc.v_scale, pc.v_zero,
+            pc.k_res, pc.v_res, pc.page_table, pc.pack_blocks, pc.res_len)
+
+
 def test_paged_equals_dense_on_same_blocks():
-    """A paged cache with identity page table == the dense kernel."""
-    b, h, g, d, block_n, nb = 1, 2, 4, 128, 128, 4
-    args = _make(jax.random.PRNGKey(1), b=b, h=h, g=g, d=d, n_pages=nb,
+    """A scrambled page table reads bitwise what the identity table over the
+    same blocks reads (the dense layout ``qcache.to_pages`` builds)."""
+    b, h, g, d, block_n, nb, n_pages = 2, 2, 4, 128, 128, 3, 8
+    args = _make(jax.random.PRNGKey(1), b=b, h=h, g=g, d=d, n_pages=n_pages,
                  nb=nb, block_n=block_n, bits=4, k_gran="channel")
-    (q, kwp, ksp, kzp, vwp, vsp, vzp, k_res, v_res, _) = args
-    table = jnp.arange(nb, dtype=jnp.int32)[None]
-    pb = jnp.asarray([nb], jnp.int32)
-    rl = jnp.asarray([9], jnp.int32)
-    out_p = pg_ops.paged_bitdecode_attention(
-        q, kwp, ksp, kzp, vwp, vsp, vzp, k_res, v_res, table, pb, rl,
-        bits=4, block_n=block_n, impl="pallas")
-    dense = lambda x: jnp.moveaxis(x, 0, 1)[None]  # noqa: E731
-    out_d = bd_ops.bitdecode_attention(
-        q, dense(kwp), dense(ksp), dense(kzp), dense(vwp), dense(vsp),
-        dense(vzp), k_res, v_res, pb, rl, bits=4, block_n=block_n,
-        impl="pallas")
-    np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_d), rtol=1e-5, atol=1e-5)
+    (q, kwp, ksp, kzp, vwp, vsp, vzp, k_res, v_res, table) = args
+    pb = jnp.asarray([nb, nb - 1], jnp.int32)
+    rl = jnp.asarray([9, 0], jnp.int32)
+    fn = functools.partial(pg_ops.paged_bitdecode_attention, bits=4,
+                           block_n=block_n, impl="pallas")
+    out_s = fn(q, kwp, ksp, kzp, vwp, vsp, vzp, k_res, v_res, table, pb, rl)
+    out_i = fn(q, *identity_layout(
+        (kwp, ksp, kzp, vwp, vsp, vzp), table, pb, rl, k_res, v_res,
+        bits=4, block_n=block_n, k_gran="channel", shared_kv=False))
+    np.testing.assert_array_equal(np.asarray(out_s), np.asarray(out_i))
 
 
 # --------------------------------------------------------------------------
@@ -92,7 +107,7 @@ def _make_shared(key, *, b, h, g, d, n_pages, nb, block_n, bits, k_gran):
 @pytest.mark.parametrize("res_len", [0, 17])  # empty vs partial residual
 def test_paged_shared_kv_matches_ref(bits, k_gran, num_splits, res_len):
     """The satellite grid: bits x granularity x num_splits x partial
-    residual — paged shared_kv Pallas vs the (dense-ref-backed) oracle."""
+    residual — paged shared_kv Pallas vs the gather-then-reference oracle."""
     b, h, g, d, dv, block_n, nb, n_pages = 2, 1, 8, 256, 128, 64, 3, 8
     q, kwp, ksp, kzp, k_res, table = _make_shared(
         jax.random.PRNGKey(2), b=b, h=h, g=g, d=d, n_pages=n_pages, nb=nb,
@@ -114,30 +129,29 @@ def test_paged_shared_kv_matches_ref(bits, k_gran, num_splits, res_len):
 
 
 def test_paged_shared_kv_equals_dense_shared_oracle():
-    """Paged shared_kv over a scrambled table == the dense shared_kv kernel
-    over the table-gathered blocks (bitwise: same compute, same order)."""
+    """Paged shared_kv over a scrambled table == the same walk over the
+    identity table of the table-gathered blocks (bitwise: same compute,
+    same order)."""
     b, h, g, d, dv, block_n, nb, n_pages = 2, 1, 8, 256, 128, 64, 3, 8
     q, kwp, ksp, kzp, k_res, table = _make_shared(
         jax.random.PRNGKey(3), b=b, h=h, g=g, d=d, n_pages=n_pages, nb=nb,
         block_n=block_n, bits=4, k_gran="channel")
     pb = jnp.asarray([nb, nb - 1], jnp.int32)
     rl = jnp.asarray([9, 0], jnp.int32)
-    out_p = pg_ops.paged_bitdecode_attention(
-        q, kwp, ksp, kzp, None, None, None, k_res, None, table, pb, rl,
-        bits=4, block_n=block_n, shared_kv=True, d_v=dv, impl="pallas")
-    gather = lambda x: jnp.moveaxis(jnp.take(x, table, axis=0), 2, 1)  # noqa: E731
-    out_d = bd_ops.bitdecode_attention(
-        q, gather(kwp), gather(ksp), gather(kzp), None, None, None,
-        k_res, None, pb, rl, bits=4, block_n=block_n, shared_kv=True,
-        d_v=dv, impl="pallas")
-    np.testing.assert_array_equal(np.asarray(out_p), np.asarray(out_d))
+    fn = functools.partial(pg_ops.paged_bitdecode_attention, bits=4,
+                           block_n=block_n, shared_kv=True, d_v=dv, impl="pallas")
+    out_s = fn(q, kwp, ksp, kzp, None, None, None, k_res, None, table, pb, rl)
+    out_i = fn(q, *identity_layout(
+        (kwp, ksp, kzp, None, None, None), table, pb, rl, k_res, None,
+        bits=4, block_n=block_n, k_gran="channel", shared_kv=True))
+    np.testing.assert_array_equal(np.asarray(out_s), np.asarray(out_i))
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 def test_paged_shared_flush_commits_latent_through_table(impl):
     """Shared-kv paged flush: a filled latent residual commits into the pool
-    page its table points at, bitwise-identical packing to the dense shared
-    flush of the same content; other pages untouched."""
+    page its table points at, bitwise-identical packing to a direct
+    quantization of the same content; other pages untouched."""
     import dataclasses
 
     from repro.core import qcache

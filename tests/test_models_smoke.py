@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import _REGISTRY, smoke_config
+from repro.core import qcache
 from repro.models.zoo import build_model
 
 ARCHS = [n for n in _REGISTRY]
@@ -51,6 +52,7 @@ def test_arch_prefill_decode(arch):
 
     logits, state = jax.jit(lambda p, b: m.prefill(p, b, 192))(params, batch)
     assert logits.shape == (B, 1, cfg.vocab)
+    state = qcache.to_pages(state)
     step = jax.jit(m.decode_step)
     tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
     for _ in range(3):
@@ -78,7 +80,8 @@ def test_prefill_decode_consistency(arch):
     bm1["labels"] = batch["labels"][:, :-1]
     bm1["loss_mask"] = batch["loss_mask"][:, :-1]
     lp, state = jax.jit(lambda p, b: m.prefill(p, b, 128))(params, bm1)
-    ld, _ = jax.jit(m.decode_step)(params, state, batch["tokens"][:, -1:])
+    ld, _ = jax.jit(m.decode_step)(params, qcache.to_pages(state),
+                                   batch["tokens"][:, -1:])
     np.testing.assert_allclose(
         np.asarray(lf[:, 0]), np.asarray(ld[:, 0]), rtol=2e-2, atol=3e-1
     )

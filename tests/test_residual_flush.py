@@ -1,10 +1,12 @@
 """Residual-flush kernel family: parity, boundary fills, and hot-path gating.
 
-The contract under test (ISSUE 2 / paper §V-B): `append_decode` must produce
-caches identical to the old speculative path, the Pallas flush must match the
-select-based XLA oracle bitwise, and — the point of the fusion — a non-full
-residual append must perform **no** quantize/pack work (the flush runs only
-under the `lax.cond` taken when some sequence's residual just filled).
+The contract under test (paper §V-B): ``qcache.paged_append_decode`` over a
+dense cache's pages (the identity page table of ``qcache.to_pages``) holds
+exactly the token history appended to it — committed blocks bitwise as the
+quantization of that history, the rest in the residual; the Pallas flush
+matches the select-based XLA oracle bitwise; and — the point of the fusion —
+a non-full residual append performs **no** quantize/pack work (it sits
+behind the flush's per-row gate).
 """
 import dataclasses
 import functools
@@ -16,6 +18,7 @@ import pytest
 
 from repro.core import attention as catt
 from repro.core import qcache
+from repro.kernels.kv_quant import ref as kq_ref
 from repro.kernels.residual_flush import ops as rf_ops
 
 jax.config.update("jax_platform_name", "cpu")
@@ -23,8 +26,7 @@ jax.config.update("jax_platform_name", "cpu")
 B, H, D, BLOCK = 2, 2, 64, 32
 MAXSEQ = 4 * BLOCK
 
-_CACHE_FIELDS = ("kw", "k_scale", "k_zero", "vw", "v_scale", "v_zero",
-                 "k_res", "v_res", "pack_blocks", "res_len")
+_POOLS = ("kw", "k_scale", "k_zero", "vw", "v_scale", "v_zero")
 
 
 def _tokens(n, d=D, key=0):
@@ -34,13 +36,10 @@ def _tokens(n, d=D, key=0):
     return k, v
 
 
-def _assert_caches_equal(a, b):
-    for f in _CACHE_FIELDS:
-        x, y = getattr(a, f), getattr(b, f)
-        if x is None:
-            assert y is None
-            continue
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y), err_msg=f)
+def _pages(**kw):
+    """An empty dense cache of B sequences under its identity page table."""
+    kw = {"bits": 4, "block_n": BLOCK, **kw}
+    return qcache.to_pages(qcache.init_cache(B, H, D, MAXSEQ, **kw))
 
 
 def _append_n(cache, k, v, n, fn):
@@ -50,6 +49,34 @@ def _append_n(cache, k, v, n, fn):
     return cache
 
 
+def _assert_holds_history(cache, k_hist, v_hist):
+    """The per-token history oracle: sequence b's history ``k_hist[b]``
+    (``[H, n_b, d]``) sits in ``cache`` as its first ``n_b // BLOCK`` blocks,
+    quantized bitwise as quantizing the history directly, in the pages its
+    table names, and its last ``n_b % BLOCK`` tokens in the residual."""
+    table = np.asarray(cache.page_table)
+    sides = [("kw", "k_scale", "k_zero", "k_res", cache.k_gran, k_hist)]
+    if not cache.shared_kv:
+        sides.append(("vw", "v_scale", "v_zero", "v_res", "tensor", v_hist))
+    for b in range(B):
+        n = k_hist[b].shape[1]
+        nblk, rl = n // BLOCK, n % BLOCK
+        assert int(cache.pack_blocks[b]) == nblk and int(cache.res_len[b]) == rl
+        assert int(cache.length[b]) == n
+        for w_f, s_f, z_f, r_f, gran, hist in sides:
+            x = hist[b]
+            if nblk:
+                want = kq_ref.quantize_kv_ref(x[None, :, : nblk * BLOCK], cache.bits,
+                                              gran, block_n=BLOCK)
+                for f, w in zip((w_f, s_f, z_f), want):
+                    got = np.asarray(getattr(cache, f))[table[b, :nblk]]
+                    np.testing.assert_array_equal(
+                        np.moveaxis(got, 0, 1), np.asarray(w)[0], err_msg=f)
+            np.testing.assert_array_equal(
+                np.asarray(getattr(cache, r_f))[b, :, :rl],
+                np.asarray(x[:, nblk * BLOCK :]), err_msg=r_f)
+
+
 # ---------------------------------------------------------------- op parity
 
 
@@ -57,31 +84,30 @@ def _append_n(cache, k, v, n, fn):
 @pytest.mark.parametrize("k_gran", ["channel", "tensor"])
 @pytest.mark.parametrize("shared_kv", [False, True])
 def test_flush_op_pallas_matches_xla(bits, k_gran, shared_kv):
-    """residual_flush: Pallas (interpret) == select-based XLA oracle, with a
-    mixed full/not-full batch so both kernel branches execute."""
-    cache = qcache.init_cache(
-        B, H, D, MAXSEQ, bits=bits, block_n=BLOCK, k_gran=k_gran,
-        shared_kv=shared_kv,
-    )
+    """paged_residual_flush on an identity page table: Pallas (interpret) ==
+    select-based XLA oracle, with a mixed full/not-full batch so both kernel
+    branches execute."""
+    cache = _pages(bits=bits, k_gran=k_gran, shared_kv=shared_kv)
     k, v = _tokens(BLOCK, key=bits)
-    kres = k
     vres = None if shared_kv else v
     full = jnp.array([1, 0], jnp.int32)
-    dest = jnp.array([1, 2], jnp.int32)
-    args = (cache.kw, cache.k_scale, cache.k_zero, cache.vw, cache.v_scale,
-            cache.v_zero, kres, vres, full, dest)
+    page = int(cache.page_table[0, 1])  # sequence 0's block 1
+    dest = jnp.array([page, 1], jnp.int32)  # sequence 1 parks on its scratch page
+    pools, _ = qcache.split_pools(cache)
     kw = dict(bits=bits, block_n=BLOCK, k_gran=k_gran, shared_kv=shared_kv)
-    ref = rf_ops.residual_flush(*args, impl="xla", **kw)
-    out = rf_ops.residual_flush(*args, impl="pallas", **kw)
-    for r, o, name in zip(ref, out, ("kw", "ks", "kz", "vw", "vs", "vz")):
+    ref = rf_ops.paged_residual_flush(*pools, k, vres, full, dest, impl="xla", **kw)
+    out = rf_ops.paged_residual_flush(*pools, k, vres, full, dest, impl="pallas", **kw)
+    for r, o, name in zip(ref, out, _POOLS):
         if r is None:
             assert o is None
             continue
         np.testing.assert_array_equal(np.asarray(r), np.asarray(o), err_msg=name)
-    # the not-full sequence's cache must be untouched
-    np.testing.assert_array_equal(np.asarray(out[0][1]), np.asarray(cache.kw[1]))
+    # every page but the full sequence's destination is untouched
+    others = np.arange(cache.n_pages) != page
+    np.testing.assert_array_equal(np.asarray(out[0])[others],
+                                  np.asarray(cache.kw)[others])
     # the full sequence committed a non-trivial block at dest
-    assert np.asarray(out[0][0, :, 1]).any()
+    assert np.asarray(out[0][page]).any()
 
 
 # ------------------------------------------------------- append boundaries
@@ -91,22 +117,12 @@ def test_flush_op_pallas_matches_xla(bits, k_gran, shared_kv):
 @pytest.mark.parametrize("n", [0, 1, BLOCK - 1, BLOCK, BLOCK + 1, 2 * BLOCK])
 def test_append_fill_boundaries(n, quant_impl):
     """Fill counts {0, 1, N_r-1, N_r} plus flush-immediately-followed-by-
-    append: gated append == speculative oracle, field for field."""
+    append: the gated paged append holds exactly the appended history."""
     k, v = _tokens(max(n, 1), key=7 * n + 1)
-    gated = jax.jit(functools.partial(qcache.append_decode, quant_impl=quant_impl))
-    spec = jax.jit(
-        functools.partial(qcache.append_decode_speculative, quant_impl="xla")
-    )
-    c_g = _append_n(
-        qcache.init_cache(B, H, D, MAXSEQ, bits=4, block_n=BLOCK), k, v, n, gated
-    )
-    c_s = _append_n(
-        qcache.init_cache(B, H, D, MAXSEQ, bits=4, block_n=BLOCK), k, v, n, spec
-    )
-    _assert_caches_equal(c_g, c_s)
-    assert int(c_g.pack_blocks[0]) == n // BLOCK
-    assert int(c_g.res_len[0]) == n % BLOCK
-    np.testing.assert_array_equal(np.asarray(c_g.length), n)
+    gated = jax.jit(functools.partial(qcache.paged_append_decode,
+                                      quant_impl=quant_impl))
+    c_g = _append_n(_pages(), k, v, n, gated)
+    _assert_holds_history(c_g, k[:, :, :n], v[:, :, :n])
 
 
 def test_flush_then_append_attention_parity():
@@ -114,9 +130,9 @@ def test_flush_then_append_attention_parity():
     the fp16 history oracle."""
     n = BLOCK + 3
     k, v = _tokens(n, key=11)
-    cache = qcache.init_cache(B, H, D, MAXSEQ, bits=8, block_n=BLOCK)
     cache = _append_n(
-        cache, k, v, n, functools.partial(qcache.append_decode, quant_impl="xla")
+        _pages(bits=8), k, v, n,
+        functools.partial(qcache.paged_append_decode, quant_impl="xla"),
     )
     q = jax.random.normal(jax.random.PRNGKey(3), (B, 1, H * 2, D)).astype(jnp.bfloat16)
     out = catt.decode_attention(q, cache, impl="xla")
@@ -131,49 +147,42 @@ def test_flush_then_append_attention_parity():
 
 def test_append_2bit_channel_shared_kv():
     """2-bit channel-wise latent (shared_kv) cache across a flush boundary:
-    gated == speculative and occupancy invariants hold."""
+    the gated append holds the history and occupancy invariants hold."""
     n = BLOCK + 2
     k, _ = _tokens(n, d=D, key=13)
-    gated = jax.jit(functools.partial(qcache.append_decode, quant_impl="pallas"))
-    spec = jax.jit(
-        functools.partial(qcache.append_decode_speculative, quant_impl="xla")
-    )
-    mk = functools.partial(
-        qcache.init_cache, B, H, D, MAXSEQ, bits=2, block_n=BLOCK,
-        k_gran="channel", shared_kv=True,
-    )
-    c_g = _append_n(mk(), k, None, n, gated)
-    c_s = _append_n(mk(), k, None, n, spec)
-    _assert_caches_equal(c_g, c_s)
+    gated = jax.jit(functools.partial(qcache.paged_append_decode,
+                                      quant_impl="pallas"))
+    c_g = _append_n(_pages(bits=2, k_gran="channel", shared_kv=True), k, None, n,
+                    gated)
+    assert c_g.vw is None and c_g.v_res is None
+    _assert_holds_history(c_g, k, None)
     assert int(c_g.pack_blocks[0]) == 1 and int(c_g.res_len[0]) == 2
 
 
 def test_staggered_flush_across_batch():
-    """Sequences flushing on different steps (per-sequence res_len) stay
-    consistent with the speculative oracle."""
+    """Sequences flushing on different steps (per-sequence res_len) each
+    hold their own history."""
     k, v = _tokens(BLOCK, key=17)
     pre_k, pre_v = _tokens(3, key=19)
-    base = qcache.init_cache(B, H, D, MAXSEQ, bits=4, block_n=BLOCK)
     # stagger: sequence 0 starts 3 tokens ahead (per-row prefill splice)
-    def stagger(c):
-        filled = qcache.prefill(
-            qcache.init_cache(B, H, D, MAXSEQ, bits=4, block_n=BLOCK),
-            pre_k, pre_v, quant_impl="xla",
-        )
-        return dataclasses.replace(
-            c,
-            k_res=c.k_res.at[0].set(filled.k_res[0]),
-            v_res=c.v_res.at[0].set(filled.v_res[0]),
-            res_len=c.res_len.at[0].set(3),
-        )
-
-    gated = jax.jit(functools.partial(qcache.append_decode, quant_impl="xla"))
-    spec = jax.jit(
-        functools.partial(qcache.append_decode_speculative, quant_impl="xla")
+    filled = qcache.to_pages(qcache.prefill(
+        qcache.init_cache(B, H, D, MAXSEQ, bits=4, block_n=BLOCK),
+        pre_k, pre_v, quant_impl="xla",
+    ))
+    base = _pages()
+    base = dataclasses.replace(
+        base,
+        k_res=base.k_res.at[0].set(filled.k_res[0]),
+        v_res=base.v_res.at[0].set(filled.v_res[0]),
+        res_len=base.res_len.at[0].set(3),
     )
-    c_g = _append_n(stagger(base), k, v, BLOCK, gated)
-    c_s = _append_n(stagger(base), k, v, BLOCK, spec)
-    _assert_caches_equal(c_g, c_s)
+    gated = jax.jit(functools.partial(qcache.paged_append_decode, quant_impl="xla"))
+    c_g = _append_n(base, k, v, BLOCK, gated)
+
+    def history(pre, x):  # per sequence: [H, n_b, d]
+        return [jnp.concatenate([pre[0], x[0]], axis=1), x[1]]
+
+    _assert_holds_history(c_g, history(pre_k, k), history(pre_v, v))
     # sequence 0 flushed 3 tokens earlier
     assert int(c_g.pack_blocks[0]) == 1 and int(c_g.res_len[0]) == 3
     assert int(c_g.pack_blocks[1]) == 1 and int(c_g.res_len[1]) == 0
@@ -195,24 +204,51 @@ def _collect_prims(jaxpr, into):
     return into
 
 
+def _find_eqns(jaxpr, name):
+    """The equations named ``name`` in ``jaxpr`` and its sub-jaxprs."""
+    from jax.extend import core as jc
+
+    for e in jaxpr.eqns:
+        if e.primitive.name == name:
+            yield e
+        for val in e.params.values():
+            for w in val if isinstance(val, (tuple, list)) else (val,):
+                if isinstance(w, jc.ClosedJaxpr):
+                    yield from _find_eqns(w.jaxpr, name)
+
+
 @pytest.mark.parametrize("quant_impl", ["xla", "pallas"])
 def test_hot_path_does_no_quant_work(quant_impl):
-    """The acceptance criterion: quantize/pack work lives exclusively inside
-    the flush branch of a single `cond`; the per-token path traced at the
-    top level carries none of it."""
-    cache = qcache.init_cache(B, H, D, MAXSEQ, bits=4, block_n=BLOCK)
+    """The acceptance criterion, through the model-facing hot path
+    (``decode_append_attention``: append + flush + attention): quantize/pack
+    work lives exclusively inside the flush's gate — one branch of the XLA
+    reference's ``cond``, or the Pallas flush kernel's per-row
+    ``pl.when(full)`` — and the attention carries none of it."""
+    cache = _pages()
     k, v = _tokens(1)
-    jaxpr = jax.make_jaxpr(
-        functools.partial(qcache.append_decode, quant_impl=quant_impl)
-    )(cache, k, v)
-    quant_marker = "pallas_call" if quant_impl == "pallas" else "shift_left"
+    q = jnp.ones((B, 1, H * 2, D), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        catt.decode_append_attention, quant_impl=quant_impl, impl=quant_impl,
+    ))(q, cache, k, v)
     top = [e.primitive.name for e in jaxpr.jaxpr.eqns]
-    assert "cond" in top
-    assert quant_marker not in top and "round" not in top
-    (cond_eqn,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "cond"]
+    assert "shift_left" not in top and "round" not in top
+    if quant_impl == "pallas":
+        # the kernels sit inside their jitted wrappers; _collect_prims walks
+        # those but never into a kernel body
+        assert "shift_left" not in _collect_prims(jaxpr.jaxpr, set())
+        kernels = {e.params["name"]: e
+                   for e in _find_eqns(jaxpr.jaxpr, "pallas_call")}
+        assert set(kernels) == {"paged_residual_flush", "paged_bitdecode"}
+        assert "shift_left" not in _collect_prims(
+            kernels["paged_bitdecode"].params["jaxpr"], set())
+        body = kernels["paged_residual_flush"].params["jaxpr"]
+    else:
+        assert "pallas_call" not in _collect_prims(jaxpr.jaxpr, set())
+        body = jaxpr.jaxpr
+    conds = [e for e in body.eqns if e.primitive.name == "cond"]
     branch_has_quant = [
-        quant_marker in _collect_prims(br.jaxpr, set())
-        for br in cond_eqn.params["branches"]
+        "shift_left" in _collect_prims(br.jaxpr, set())
+        for c in conds for br in c.params["branches"]
     ]
     assert sum(branch_has_quant) == 1, branch_has_quant
 

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import smoke_config
+from repro.core import qcache
 from repro.models.zoo import build_model
 from repro.serve.engine import Request, ServeEngine
 
@@ -36,9 +37,11 @@ def hybrid_model():
 
 
 def _oracle(model, params, prompt, max_new, max_seq=128):
-    """Dense-slot reference: exact-length prefill + jitted decode loop."""
+    """Dense-slot reference: exact-length prefill, its caches handed to the
+    identity page table (``qcache.to_pages``), jitted decode loop."""
     logits, st = model.prefill(params, {"tokens": jnp.asarray(prompt[None])},
                                max_seq)
+    st = qcache.to_pages(st)
     tok = int(np.argmax(np.asarray(logits)[0, -1]))
     step = jax.jit(functools.partial(model.decode_step, impl="auto",
                                      quant_impl="auto"))
@@ -112,16 +115,14 @@ def test_hybrid_exact_prefill_grouping(hybrid_model):
 
 def test_unserveable_family_refused_at_construction():
     """paged_spec() is None (enc-dec: prefill needs frame embeddings the
-    Request cannot carry) -> the engine refuses at __init__, for the forced
-    shim too — not with an obscure error mid-prefill."""
+    Request cannot carry) -> the engine refuses at __init__ — not with an
+    obscure error mid-prefill."""
     cfg = smoke_config("seamless-m4t-medium")
     model = build_model(cfg)
     assert model.paged_spec() is None
     params = model.init(jax.random.PRNGKey(0))
     with pytest.raises(ValueError, match="serveable cache family"):
         ServeEngine(model, params, slots=2, max_seq=64)
-    with pytest.raises(ValueError, match="serveable cache family"):
-        ServeEngine(model, params, slots=2, max_seq=64, paged=False)
 
 
 # --------------------------------------------------------------------------

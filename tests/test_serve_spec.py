@@ -6,8 +6,8 @@ identical to ``spec_k = 1`` — asserted here across cache families, bit
 widths, granularities, pool pressure, faults, and prefix sharing.
 
 Also covers the kernel-level ``draft_bits`` truncated-read contract
-(kernels/bitdecode, kernels/paged_bitdecode) and the speculative counter
-conservation the invariant auditor enforces.
+(kernels/paged_bitdecode) and the speculative counter conservation the
+invariant auditor enforces.
 """
 import functools
 
@@ -16,9 +16,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from test_kernels_bitdecode import paged_args
+
 from repro.configs.base import smoke_config
-from repro.kernels.bitdecode import ops as bd_ops
 from repro.kernels.kv_quant import ref as kq_ref
+from repro.kernels.paged_bitdecode import ops as pg_ops
 from repro.models.zoo import build_model
 from repro.serve import FaultPlan, Request, ServeEngine
 from repro.serve.audit import audit_engine
@@ -268,21 +270,22 @@ def _bd_case(key, *, bits, res_len, pack_blocks, k_gran="channel"):
                                           k_gran, block_n=block_n)
     vw, vsc, vzp = kq_ref.quantize_kv_ref(v_full.astype(jnp.bfloat16), bits,
                                           "tensor", block_n=block_n)
-    return dict(q=q, kw=kw, k_scale=ksc, k_zero=kzp, vw=vw, v_scale=vsc,
+    case = dict(q=q, kw=kw, k_scale=ksc, k_zero=kzp, vw=vw, v_scale=vsc,
                 v_zero=vzp, k_res=k_res, v_res=v_res,
                 pack_blocks=jnp.asarray(pack_blocks, jnp.int32),
-                res_len=jnp.asarray(res_len, jnp.int32)), block_n
+                res_len=jnp.asarray(res_len, jnp.int32))
+    return paged_args(case, bits=bits, block_n=block_n, k_gran=k_gran), block_n
 
 
 def test_draft_bits_noop_when_not_truncating():
     """draft_bits >= bits reads full fidelity: bitwise the normal path."""
     case, block_n = _bd_case(jax.random.PRNGKey(0), bits=4,
                              pack_blocks=[2], res_len=[17])
-    fn = functools.partial(bd_ops.bitdecode_attention, bits=4,
+    fn = functools.partial(pg_ops.paged_bitdecode_attention, bits=4,
                            block_n=block_n, impl="xla")
-    full = np.asarray(fn(**case))
+    full = np.asarray(fn(*case))
     for db in (4, 8):
-        np.testing.assert_array_equal(np.asarray(fn(**case, draft_bits=db)),
+        np.testing.assert_array_equal(np.asarray(fn(*case, draft_bits=db)),
                                       full)
 
 
@@ -292,16 +295,16 @@ def test_draft_bits_truncates_committed_read_only():
     with committed blocks present it must actually differ."""
     res_only, block_n = _bd_case(jax.random.PRNGKey(1), bits=4,
                                  pack_blocks=[0], res_len=[33])
-    fn = functools.partial(bd_ops.bitdecode_attention, bits=4,
+    fn = functools.partial(pg_ops.paged_bitdecode_attention, bits=4,
                            block_n=block_n, impl="xla")
     np.testing.assert_array_equal(
-        np.asarray(fn(**res_only, draft_bits=2)),
-        np.asarray(fn(**res_only)),
+        np.asarray(fn(*res_only, draft_bits=2)),
+        np.asarray(fn(*res_only)),
     )
     packed, _ = _bd_case(jax.random.PRNGKey(1), bits=4,
                          pack_blocks=[2], res_len=[33])
-    full = np.asarray(fn(**packed))
-    draft = np.asarray(fn(**packed, draft_bits=2))
+    full = np.asarray(fn(*packed))
+    draft = np.asarray(fn(*packed, draft_bits=2))
     assert draft.shape == full.shape and np.isfinite(draft).all()
     assert not np.array_equal(draft, full)
     # coarser, not broken: still an attention output in the same range
@@ -311,9 +314,9 @@ def test_draft_bits_truncates_committed_read_only():
 def test_draft_bits_validation():
     case, block_n = _bd_case(jax.random.PRNGKey(2), bits=4,
                              pack_blocks=[1], res_len=[5])
-    fn = functools.partial(bd_ops.bitdecode_attention, bits=4,
+    fn = functools.partial(pg_ops.paged_bitdecode_attention, bits=4,
                            block_n=block_n)
     with pytest.raises(ValueError):
-        fn(**case, impl="xla", draft_bits=0)
+        fn(*case, impl="xla", draft_bits=0)
     with pytest.raises(ValueError, match="Pallas"):
-        fn(**case, impl="pallas", draft_bits=2)
+        fn(*case, impl="pallas", draft_bits=2)

@@ -2,16 +2,18 @@
 kernel is policy-equivalent to the unsplit kernel and the ref oracle for any
 num_splits — across bits, K-param granularity, shared-KV (MLA) mode, splits
 that cover zero valid blocks (finalize's l=0 / lse=-inf guard), and a
-partially filled residual."""
+partially filled residual.  Dense blocks walk through the identity page
+table of ``qcache.to_pages``; shuffled tables are covered too."""
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from test_kernels_bitdecode import paged_args
 
-from repro.kernels.bitdecode import ops as bd_ops
 from repro.kernels.kv_quant import ref as kq_ref
+from repro.kernels.paged_bitdecode import ops as pg_ops
 
 
 def _make_case(key, *, b, h, g, d_k, d_v, nb, block_n, bits, k_gran,
@@ -52,11 +54,12 @@ def test_split_matches_unsplit_and_ref(bits, k_gran, num_splits):
         block_n=block_n, bits=bits, k_gran=k_gran,
         pack_blocks=[nb, nb - 2], res_len=[37, 0],
     )
-    fn = functools.partial(bd_ops.bitdecode_attention, bits=bits,
+    args = paged_args(case, bits=bits, block_n=block_n, k_gran=k_gran)
+    fn = functools.partial(pg_ops.paged_bitdecode_attention, bits=bits,
                            block_n=block_n, k_gran=k_gran, return_lse=True)
-    out_1, lse_1 = fn(**case, impl="pallas", num_splits=1)
-    out_s, lse_s = fn(**case, impl="pallas", num_splits=num_splits)
-    out_r, lse_r = fn(**case, impl="xla", num_splits=1)
+    out_1, lse_1 = fn(*args, impl="pallas", num_splits=1)
+    out_s, lse_s = fn(*args, impl="pallas", num_splits=num_splits)
+    out_r, lse_r = fn(*args, impl="xla", num_splits=1)
     # split vs unsplit: same policy, only fp reassociation differs
     np.testing.assert_allclose(np.asarray(out_s), np.asarray(out_1),
                                rtol=1e-2, atol=1e-2)
@@ -76,11 +79,12 @@ def test_split_ref_oracle_matches_unsplit_ref():
         jax.random.PRNGKey(1), b=1, h=2, g=4, d_k=128, d_v=128, nb=6,
         block_n=128, bits=4, k_gran="channel", pack_blocks=[5], res_len=[19],
     )
-    fn = functools.partial(bd_ops.bitdecode_attention, bits=4, block_n=128,
+    args = paged_args(case, bits=4, block_n=128)
+    fn = functools.partial(pg_ops.paged_bitdecode_attention, bits=4, block_n=128,
                            k_gran="channel", impl="xla", return_lse=True)
-    out_1, lse_1 = fn(**case, num_splits=1)
+    out_1, lse_1 = fn(*args, num_splits=1)
     for s in (2, 3, 6):
-        out_s, lse_s = fn(**case, num_splits=s)
+        out_s, lse_s = fn(*args, num_splits=s)
         # bf16 PV matmuls run per split, so reassociation noise is the bound
         np.testing.assert_allclose(np.asarray(out_s), np.asarray(out_1),
                                    rtol=1e-2, atol=1e-3)
@@ -96,10 +100,11 @@ def test_split_all_splits_empty_but_residual():
         jax.random.PRNGKey(2), b=1, h=1, g=4, d_k=128, d_v=128, nb=4,
         block_n=128, bits=4, k_gran="channel", pack_blocks=[0], res_len=[7],
     )
-    fn = functools.partial(bd_ops.bitdecode_attention, bits=4, block_n=128,
+    args = paged_args(case, bits=4, block_n=128)
+    fn = functools.partial(pg_ops.paged_bitdecode_attention, bits=4, block_n=128,
                            k_gran="channel", return_lse=True)
-    out_1, lse_1 = fn(**case, impl="pallas", num_splits=1)
-    out_4, lse_4 = fn(**case, impl="pallas", num_splits=4)
+    out_1, lse_1 = fn(*args, impl="pallas", num_splits=1)
+    out_4, lse_4 = fn(*args, impl="pallas", num_splits=4)
     np.testing.assert_allclose(np.asarray(out_4), np.asarray(out_1),
                                rtol=1e-3, atol=1e-3)
     np.testing.assert_allclose(np.asarray(lse_4), np.asarray(lse_1),
@@ -117,11 +122,12 @@ def test_split_shared_kv_mla_mode():
     )
     case["vw"] = case["v_scale"] = case["v_zero"] = None
     case["v_res"] = None
-    fn = functools.partial(bd_ops.bitdecode_attention, bits=4, block_n=block_n,
-                           k_gran="channel", shared_kv=True, d_v=d_v,
-                           return_lse=True)
-    out_1, lse_1 = fn(**case, impl="pallas", num_splits=1)
-    out_s, lse_s = fn(**case, impl="pallas", num_splits=2)
+    args = paged_args(case, bits=4, block_n=block_n, shared_kv=True)
+    fn = functools.partial(pg_ops.paged_bitdecode_attention, bits=4,
+                           block_n=block_n, k_gran="channel", shared_kv=True,
+                           d_v=d_v, return_lse=True)
+    out_1, lse_1 = fn(*args, impl="pallas", num_splits=1)
+    out_s, lse_s = fn(*args, impl="pallas", num_splits=2)
     np.testing.assert_allclose(np.asarray(out_s), np.asarray(out_1),
                                rtol=1e-2, atol=1e-2)
     np.testing.assert_allclose(np.asarray(lse_s), np.asarray(lse_1),
@@ -131,8 +137,6 @@ def test_split_shared_kv_mla_mode():
 def test_paged_split_matches_unsplit():
     """Paged kernel: num_splits walks page-table ranges; parity with the
     unsplit paged kernel on a shuffled page table."""
-    from repro.kernels.paged_bitdecode import ops as pg_ops
-
     b, h, g, d, nb, block_n, bits = 2, 2, 4, 128, 4, 128, 4
     n_pages = b * nb + 3
     ks = jax.random.split(jax.random.PRNGKey(4), 6)
@@ -167,11 +171,11 @@ def test_paged_split_matches_unsplit():
 def test_auto_heuristic_targets_small_batch_long_context():
     """auto splits exactly when B*H_kv underfills the cores and the packed
     sequence is long; each split must own >= 2 blocks."""
-    assert bd_ops.auto_num_splits(8, 8, 64) == 1      # batch-heavy: never
-    assert bd_ops.auto_num_splits(1, 2, 2) == 1       # too short
-    s = bd_ops.auto_num_splits(1, 2, 64)              # B=1 GQA at 8K
+    assert pg_ops.auto_num_splits(8, 8, 64) == 1      # batch-heavy: never
+    assert pg_ops.auto_num_splits(1, 2, 2) == 1       # too short
+    s = pg_ops.auto_num_splits(1, 2, 64)              # B=1 GQA at 8K
     assert s > 1 and s * 2 <= 64
-    assert bd_ops.auto_num_splits(1, 1, 6) <= 3       # >= 2 blocks per split
-    assert bd_ops.resolve_num_splits("auto", 1, 2, 64) == s
-    assert bd_ops.resolve_num_splits(3, 1, 2, 64) == 3
-    assert bd_ops.resolve_num_splits(100, 1, 1, 4) == 4  # clamped to nb
+    assert pg_ops.auto_num_splits(1, 1, 6) <= 3       # >= 2 blocks per split
+    assert pg_ops.resolve_num_splits("auto", 1, 2, 64) == s
+    assert pg_ops.resolve_num_splits(3, 1, 2, 64) == 3
+    assert pg_ops.resolve_num_splits(100, 1, 1, 4) == 4  # clamped to nb

@@ -14,7 +14,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import layout, qcache
-from repro.kernels.bitdecode import kernel as bd_kernel
 from repro.kernels.flash_prefill import kernel as fp_kernel
 from repro.kernels.kv_quant import kernel as kq_kernel
 from repro.kernels.paged_bitdecode import kernel as pg_kernel
@@ -142,6 +141,38 @@ def test_qwen_stacked_paged_residual_flush(shape):
         shape((QB,), I32), shape((QB,), I32), shape((), I32))
 
 
+@pytest.mark.parametrize("op", ["paged_bitdecode", "paged_residual_flush", "kv_quant"])
+def test_qwen_cell_ops(shape, monkeypatch, op):
+    """The ops the qwen2-7b.reason cell's programs call, as the decode step
+    and the prefill call them on the chip (``impl="auto"``, which picks the
+    kernel on a TPU): the 4-bit channel walk over the stacked pools with the
+    cell's g=7 query heads per KV head (h=4; the op pads g to 8), the flush
+    at a layer offset, and the prefill's quantization of 32 slots x the
+    cell's longest bucket (256)."""
+    from repro.kernels.kv_quant import ops as kq_ops
+    from repro.kernels.paged_bitdecode import ops as pg_ops
+    from repro.kernels.residual_flush import ops as rf_ops
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pools = _pools(shape, layers=QL, pages=QP, h=QH, d=D)
+    res = [shape((QB, QH, BLOCK, D), BF16)] * 2
+    if op == "paged_bitdecode":
+        _compiles_to_mosaic(
+            lambda q, *a: pg_ops.paged_bitdecode_attention(
+                q, *a[:-1], layer=a[-1], bits=4, block_n=BLOCK, k_gran="channel"),
+            shape((QB, QH, 7, D), BF16), *pools, *res, shape((QB, QNB), I32),
+            shape((QB,), I32), shape((QB,), I32), shape((), I32))
+    elif op == "paged_residual_flush":
+        _compiles_aliased(
+            lambda *a: rf_ops.paged_residual_flush(
+                *a[:-1], layer=a[-1], bits=4, block_n=BLOCK, k_gran="channel"),
+            pools, *res, shape((QB,), I32), shape((QB,), I32), shape((), I32))
+    else:
+        _compiles_to_mosaic(
+            lambda x: kq_ops.quantize_kv(x, 4, "channel", block_n=BLOCK),
+            shape((QB, QH, 256, D), BF16))
+
+
 @pytest.mark.parametrize("k_gran", ["channel", "tensor"])
 @pytest.mark.parametrize("bits", [4, 2])
 def test_kv_quant(shape, bits, k_gran):
@@ -151,57 +182,23 @@ def test_kv_quant(shape, bits, k_gran):
         shape((B, H, 4096, D), BF16))
 
 
-@pytest.mark.parametrize("k_gran", ["channel", "tensor"])
-def test_dense_bitdecode(shape, k_gran):
-    npr, p = layout.words_per_block(BLOCK, 4), _param_width(k_gran)
-    args = [shape((B, H, G, D), BF16),
-            shape((B, H, NB, npr, D), I32), shape((B, H, NB, p), BF16),
-            shape((B, H, NB, p), BF16),
-            shape((B, H, NB, npr, D), I32), shape((B, H, NB, BLOCK), BF16),
-            shape((B, H, NB, BLOCK), BF16),
-            shape((B, H, BLOCK, D), BF16), shape((B, H, BLOCK, D), BF16),
-            shape((B,), I32), shape((B,), I32)]
-    _compiles_to_mosaic(
-        lambda *a: bd_kernel.bitdecode_attention_pallas(
-            *a, bits=4, block_n=BLOCK, sm_scale=D**-0.5, k_gran=k_gran,
-            shared_kv=False, d_v=D, num_splits=2, interpret=False),
-        *args)
-
-
-def test_dense_residual_flush(shape):
-    npr = layout.words_per_block(BLOCK, 4)
-    args = [shape((B, H, NB, npr, D), I32), shape((B, H, NB, D), BF16),
-            shape((B, H, NB, D), BF16),
-            shape((B, H, NB, npr, D), I32), shape((B, H, NB, BLOCK), BF16),
-            shape((B, H, NB, BLOCK), BF16),
-            shape((B, H, BLOCK, D), BF16), shape((B, H, BLOCK, D), BF16),
-            shape((B,), I32), shape((B,), I32)]
-    _compiles_to_mosaic(
-        lambda *a: rf_kernel.residual_flush_pallas(
-            *a, bits=4, block_n=BLOCK, k_gran="channel", shared_kv=False,
-            interpret=False),
-        *args)
-
-
-@pytest.mark.parametrize("paged", [True, False])
-def test_flush_auto_raises_on_misaligned_lanes(monkeypatch, paged):
+def test_flush_auto_raises_on_misaligned_lanes(monkeypatch):
     """On TPU ``auto`` picks the kernel and never falls back to the XLA
     reference: a head dim that is no lane multiple raises."""
     from repro.kernels.residual_flush import ops as rf_ops
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     d, npr = 64, layout.words_per_block(BLOCK, 4)
-    lead = (PAGES, H) if paged else (B, H, NB)
+    lead = (PAGES, H)
     sds = jax.ShapeDtypeStruct
     pools = [sds((*lead, npr, d), I32), sds((*lead, d), BF16), sds((*lead, d), BF16),
              sds((*lead, npr, d), I32), sds((*lead, BLOCK), BF16),
              sds((*lead, BLOCK), BF16)]
     res = [sds((B, H, BLOCK, d), BF16)] * 2
-    flush = rf_ops.paged_residual_flush if paged else rf_ops.residual_flush
     with pytest.raises(ValueError, match="multiples of 128"):
         jax.eval_shape(
-            lambda *a: flush(*a, bits=4, block_n=BLOCK, k_gran="channel",
-                             shared_kv=False),
+            lambda *a: rf_ops.paged_residual_flush(
+                *a, bits=4, block_n=BLOCK, k_gran="channel", shared_kv=False),
             *pools, *res, sds((B,), I32), sds((B,), I32))
 
 
