@@ -5,13 +5,15 @@ quantized latent stream (V-side pools and residual are ``None``), the kernel
 reads each page once and slices the V tile out of the dequantized K tile.
 
 Split-KV (FlashDecoding) dispatch lives here: ``num_splits`` partitions the
-page-table walk into contiguous ranges that the Pallas grid processes as an
-extra parallel dimension (kernel.py phase 1), combined by the logsumexp merge
-epilogue (kernel.merge_partials, phase 2).  ``num_splits="auto"`` applies the
-serving heuristic: split only when the natural ``B x H_kv`` parallelism
-underfills the chip's cores AND the sequence is long enough that each split
-still amortizes its setup over >= 2 pages — i.e. exactly the paper's
-headline long-context small-batch decode regime.
+page-table columns into contiguous ranges, an extra parallel grid dimension;
+grid step (row, split) walks its range in an in-kernel loop that stops at
+the row's own ``pack_blocks`` (kernel.py phase 1), and the logsumexp merge
+epilogue combines the splits (kernel.merge_partials, phase 2).
+``num_splits="auto"`` applies the serving heuristic: split only when the
+natural ``B x H_kv`` parallelism underfills the chip's cores AND the
+sequence is long enough that each split still amortizes its setup over
+>= 2 pages — i.e. exactly the paper's headline long-context small-batch
+decode regime.
 
 ``layer`` makes the pools layer-stacked (``[L, P, H, ...]``, the decode
 layer scan's layout) and names the layer the walk reads; without it the

@@ -232,3 +232,76 @@ def test_stacked_pool_read_equals_layer_pool(shared_kv, num_splits, layer, impl)
              layer=jnp.int32(layer))
     for w, o in zip(want, got):
         np.testing.assert_array_equal(np.asarray(o), np.asarray(w))
+
+
+# --------------------------------------------------------------------------
+# the walk is bounded by each row's own pages: every page no row holds is
+# poisoned, and the kernel never reads it
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("res_len", [0, 17])
+@pytest.mark.parametrize("num_splits", [1, 3])
+@pytest.mark.parametrize("shared_kv", [False, True])
+def test_walk_reads_only_rows_own_pages(shared_kv, num_splits, res_len):
+    """Rows hold 0, 1, P-1, P, P+1 and nb_max pages (P: pages per DMA
+    chunk).  Every pool page outside the rows' [0, pack_blocks) ranges has
+    NaN scale and zero: the slots' scratch pages, which alternate columns
+    past pack_blocks point at, and the other pages those columns name; the
+    walk reads layer 1 of a 2-layer stack whose layer 0 is NaN throughout.
+    The kernel's output is finite and matches the reference over the clean
+    pools."""
+    from repro.kernels.paged_bitdecode import kernel as pg_kernel
+
+    g, block_n = 8, 128
+    if shared_kv:
+        h, d, extra = 1, 256, dict(shared_kv=True, d_v=128)
+    else:
+        h, d, extra = 2, 128, {}
+    npr = block_n * 4 // 32
+    p_shapes = [(h, npr, d), (h, d), (h, d)] + ([] if shared_kv else [
+        (h, npr, d), (h, block_n), (h, block_n)])
+    probe = [jax.ShapeDtypeStruct((2, 1, *s), jnp.int32 if len(s) == 3 else jnp.bfloat16)
+             for s in p_shapes]
+    chunk = pg_kernel.chunk_pages(probe, 64)
+    nb = chunk + 3
+    pb = np.asarray([0, 1, max(chunk - 1, 0), chunk, chunk + 1, nb], np.int32)
+    b = len(pb)
+    n_pages = b * (nb + 1) + 2
+    if shared_kv:
+        q, *pools, k_res, _ = _make_shared(
+            jax.random.PRNGKey(7), b=b, h=h, g=g, d=d, n_pages=n_pages, nb=nb,
+            block_n=block_n, bits=4, k_gran="channel")
+        pools, v_res = pools + [None] * 3, None
+    else:
+        q, *pools, k_res, v_res, _ = _make(
+            jax.random.PRNGKey(7), b=b, h=h, g=g, d=d, n_pages=n_pages, nb=nb,
+            block_n=block_n, bits=4, k_gran="channel")
+    # slot i's scratch page is page i; the rows' pages come after them
+    order = np.asarray(jax.random.permutation(jax.random.PRNGKey(8), n_pages - b)) + b
+    table = order[: b * nb].reshape(b, nb).astype(np.int32)
+    for i in range(b):
+        table[i, pb[i]::2] = i
+    held = {int(table[i, j]) for i in range(b) for j in range(pb[i])}
+    unheld = np.asarray(sorted(set(range(n_pages)) - held))
+    assert unheld.size > b
+
+    def poison(p, pages):
+        if p is None or p.dtype == jnp.int32:
+            return p
+        return p.at[pages].set(jnp.nan)
+
+    poisoned = [poison(p, unheld) for p in pools]
+    stacked = [None if p is None else jnp.stack([poison(p, np.arange(n_pages)), p])
+               for p in poisoned]
+    rl = np.full((b,), res_len, np.int32)
+    rl[0] = max(res_len, 5)  # the row with no page still attends something
+    fn = functools.partial(
+        pg_ops.paged_bitdecode_attention, bits=4, block_n=block_n,
+        k_gran="channel", return_lse=True, num_splits=num_splits, **extra)
+    args = (jnp.asarray(table), jnp.asarray(pb), jnp.asarray(rl))
+    out, lse = fn(q, *stacked, k_res, v_res, *args, layer=jnp.int32(1),
+                  impl="pallas")
+    assert np.isfinite(np.asarray(out)).all() and np.isfinite(np.asarray(lse)).all()
+    out_r, lse_r = fn(q, *pools, k_res, v_res, *args, impl="xla")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(out_r), rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_r), rtol=1e-3, atol=1e-3)

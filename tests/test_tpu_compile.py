@@ -223,7 +223,7 @@ def _latent_pools(shape, d=LD):
     return _pools(shape, layers=LL, pages=LP, h=1, d=d, v=False)
 
 
-def test_latent_paged_bitdecode(shape):
+def _latent_paged_bitdecode(shape, num_splits):
     args = [shape((LB, 1, LG, LD), BF16), *_latent_pools(shape),
             shape((LB, 1, BLOCK, LD), BF16), shape((LB, LNB), I32), shape((LB,), I32),
             shape((LB,), I32), shape((), I32)]
@@ -231,8 +231,17 @@ def test_latent_paged_bitdecode(shape):
         lambda q, kw, ks, kz, kr, pt, pb, rl, ly: pg_kernel.paged_bitdecode_attention_pallas(
             q, kw, ks, kz, None, None, None, kr, None, pt, pb, rl, ly, bits=4,
             block_n=BLOCK, sm_scale=0.1, k_gran="channel", shared_kv=True, d_v=LDV,
-            num_splits=1, interpret=False),
+            num_splits=num_splits, interpret=False),
         *args)
+
+
+def test_latent_paged_bitdecode(shape):
+    _latent_paged_bitdecode(shape, 1)
+
+
+def test_latent_paged_bitdecode_split(shape):
+    """The latent walk split over 4 column ranges of each row."""
+    _latent_paged_bitdecode(shape, 4)
 
 
 def test_latent_paged_residual_flush(shape):
